@@ -17,8 +17,8 @@ propagation into a no-overflow certificate:
   (an unclamped ``k = (max - x) >> 5`` on a masked row reaches 2^27,
   which is UB for the lowered shift).
 
-Structured control flow is walked, not approximated away: ``pjit`` and
-custom-derivative calls recurse; ``cond`` evaluates the taken branch
+Structured control flow is walked, not approximated away: nested ``jit``
+and custom-derivative calls recurse; ``cond`` evaluates the taken branch
 when the predicate interval is a point and joins all branches
 otherwise; ``scan``/``while`` unroll up to a budget and then widen the
 carry to the dtype range; ``pallas_call`` maps operand intervals onto
@@ -417,7 +417,7 @@ class Interp:
 # path) -> list of out values
 # ---------------------------------------------------------------------------
 
-def _h_pjit(self: Interp, eqn, env, path):
+def _h_jit(self: Interp, eqn, env, path):
     invals = [self.read(env, a) for a in eqn.invars]
     inner = eqn.params["jaxpr"]
     name = eqn.params.get("name", "")
@@ -553,7 +553,7 @@ def _h_num_programs(self: Interp, eqn, env, path):
 
 
 _STRUCTURAL = {
-    "pjit": _h_pjit,
+    "jit": _h_jit,
     "closed_call": _h_custom_call,
     "custom_jvp_call": _h_custom_call,
     "custom_vjp_call": _h_custom_call,
@@ -934,6 +934,7 @@ _TRANSFER = {
     "reduce_and": _t_reduce_bool,
     "reduce_or": _t_reduce_bool,
     "broadcast_in_dim": _t_identity,
+    "tile": _t_identity,
     "reshape": _t_identity,
     "transpose": _t_identity,
     "squeeze": _t_identity,

@@ -172,7 +172,7 @@ def _attention_case(name, backend, spec, g: Geometry, *, desc,
         k = v = _sds((g.b, g.skv, g.hkv, g.d), jnp.int8)
     else:                                           # bhsd_paged
         q = _sds((g.b, g.hq, qlen, g.d), jnp.int8)
-        k = v = _sds((npages, g.page, g.hkv, g.d), jnp.int8)
+        k = v = _sds((npages, g.hkv, g.page, g.d), jnp.int8)
     if spec.impl == "float":
         q = _sds(q.shape, jnp.float32)
         k = v = _sds(k.shape, jnp.float32)

@@ -25,7 +25,8 @@ Pieces:
 - ``KVCacheState``: typed int8 KV ring-buffer state (replaces the plain
   cache dicts).
 - ``PagedKVState``: the continuous-batching allocator — one shared
-  ``(num_pages, page_size, G, hd)`` arena, per-sequence page tables, an
+  head-major ``(num_pages, G, page_size, hd)`` arena, per-sequence page
+  tables, an
   on-device free stack and per-page refcounts (prefix sharing +
   copy-on-write); logical ring semantics, O(live tokens) memory.
   Served by the fused kernels through the ``bhsd_paged`` layout +

@@ -290,21 +290,17 @@ def _fused_run(kind, q, k, v, spec, scales, q_offset, kv_len, opts):
     q_lens = opts.get("q_lens")
     if spec.layout == "bshd":
         q8 = jnp.swapaxes(_quantize(q, scales.s_q, 2), 1, 2)
-        k8 = _quantize(k, scales.s_k, 2)
-        v8 = _quantize(v, scales.s_v, 2)
-        kv_native = True
     else:             # bhsd / bhsd_bsgd / bhsd_paged: q already (B,H,S,D)
         q8 = _quantize(q, scales.s_q, 1)
-        kv_native = spec.layout == "bhsd_bsgd"
-        kv_axis = 1 if spec.layout == "bhsd" else 2
-        k8 = _quantize(k, scales.s_k, kv_axis)
-        v8 = _quantize(v, scales.s_v, kv_axis)
-    if kv_native and kind == "twopass":
-        # twopass consumes kernel-layout KV; one transpose (decode and
-        # onepass read the (B,S,G,hd) buffers via cache-native index maps)
+    kv_axis = 1 if spec.layout in ("bhsd", "bhsd_paged") else 2
+    k8 = _quantize(k, scales.s_k, kv_axis)
+    v8 = _quantize(v, scales.s_v, kv_axis)
+    if kv_axis == 2:
+        # (B, S, G, hd) model/ring buffers -> the kernels' head-major
+        # (B, G, S, hd): Mosaic tiles a (seq, hd) block per kv head, not a
+        # (seq, 1, hd) slice of the seq-major buffer
         k8 = k8.transpose(0, 2, 1, 3)
         v8 = v8.transpose(0, 2, 1, 3)
-        kv_native = False
     dbq, dbkv = default_blocks(f"ita_{kind}_pallas")
     out = fused_attention(
         q8, k8, v8, scales.s_q, scales.s_k, scales.s_v, scales.s_out,
@@ -312,7 +308,7 @@ def _fused_run(kind, q, k, v, spec, scales, q_offset, kv_len, opts):
         window=spec.window, kind=kind, adaptive=spec.softmax == "adaptive",
         block_q=opts.get("block_q", dbq or 128),
         block_kv=opts.get("block_kv", dbkv),
-        kv_native=kv_native, page_table=page_table,
+        page_table=page_table,
         interpret=opts.get("interpret"))
     if spec.layout == "bshd":
         out = jnp.swapaxes(out, 1, 2)                    # back to (B,S,H,D)
@@ -343,8 +339,8 @@ def _decode_run(q, k, v, spec, scales, *, q_offset=0, kv_len=None, **opts):
 register_backend(Backend(
     name="ita_decode_pallas", family="ita_fused",
     supports=_decode_supports, run=_decode_run,
-    description="fused decode kernel over int8 KV ring buffers "
-                "(cache-native index maps, skips invalid KV tiles)"))
+    description="fused decode kernel over int8 KV ring buffers and the "
+                "paged pool (skips invalid KV tiles)"))
 register_backend(Backend(
     name="ita_chunked_xla", family="ita_stream_xla",
     supports=_chunked_supports, run=_chunked_run,

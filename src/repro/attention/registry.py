@@ -84,9 +84,9 @@ def _shapes(q, k, spec: AttentionSpec):
     elif spec.layout == "bhsd":
         hq, sq = q.shape[1], q.shape[2]
         hkv, skv = k.shape[1], k.shape[2]
-    elif spec.layout == "bhsd_paged":           # kv = (P, page, G, hd) pool
+    elif spec.layout == "bhsd_paged":           # kv = (P, G, page, hd) pool
         hq, sq = q.shape[1], q.shape[2]
-        skv, hkv = k.shape[1], k.shape[2]       # skv = one page here
+        hkv, skv = k.shape[1], k.shape[2]       # skv = one page here
     else:                                       # bhsd_bsgd: q bhsd, kv bsgd
         hq, sq = q.shape[1], q.shape[2]
         skv, hkv = k.shape[1], k.shape[2]

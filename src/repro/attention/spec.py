@@ -22,10 +22,9 @@ IMPLS = ("float", "ita", "ibert")
 SOFTMAXES = ("adaptive", "paper")
 # q-layout[_kv-layout]: "bshd" (model: batch, seq, heads, dim), "bhsd"
 # (kernel: batch, heads, seq, dim), "bhsd_bsgd" (decode engine: q in
-# kernel layout, K/V consumed cache-natively as (B, C, G, hd) ring
-# buffers via kernel index maps — no per-step transpose copies),
-# "bhsd_paged" (continuous batching: q in kernel layout, K/V a shared
-# (num_pages, page_size, G, hd) pool consumed through per-sequence page
+# kernel layout, K/V the (B, C, G, hd) ring buffers), "bhsd_paged"
+# (continuous batching: q in kernel layout, K/V a shared head-major
+# (num_pages, G, page_size, hd) pool consumed through per-sequence page
 # tables — dispatch requires the ``page_table=`` operand).
 LAYOUTS = ("bshd", "bhsd", "bhsd_bsgd", "bhsd_paged")
 SCALE_KINDS = ("per_tensor", "per_head")
@@ -62,7 +61,7 @@ class AttentionSpec:
     softcap: float = 0.0             # tanh logit softcap; 0 = off
     query_scale: float = 0.0         # 0 -> head_dim ** -0.5
     softmax: str = "adaptive"        # adaptive | paper (ITA §III DI)
-    layout: str = "bshd"             # bshd | bhsd | bhsd_bsgd
+    layout: str = "bshd"             # one of LAYOUTS
     scale_kind: str = "per_tensor"   # per_tensor | per_head
     out_dtype: str = "float"         # float | int8 (on the s_out grid)
     has_s_out: bool = True

@@ -17,7 +17,9 @@ scales ``(G,)`` (the decode engine's finer-than-QAT grid); ``None`` when
 the cache rides the model's per-tensor QAT scales.
 
 ``PagedKVState`` is the continuous-batching allocator: **one** shared
-``(num_pages, page_size, G, hd)`` int8 arena for the whole batch, a
+head-major ``(num_pages, G, page_size, hd)`` int8 arena for the whole
+batch (each kv head's page is one contiguous ``(page_size, hd)`` tile,
+the block the fused kernels DMA), a
 per-sequence page table translating logical KV pages to physical arena
 pages, and an on-device free stack. Logical semantics are *identical* to
 a ring of capacity ``n_pages * page_size`` (slot ``t % C``, same
@@ -207,8 +209,9 @@ PARKING_PAGE = 0        # physical page 0: write sink / unassigned entries
 class PagedKVState:
     """Shared paged int8 KV pool + per-sequence page tables + free stack.
 
-    ``k``/``v``: ``(num_pages, page_size, G, hd)`` arena shared by every
-    sequence (and, at the model level, one arena per layer).
+    ``k``/``v``: head-major ``(num_pages, G, page_size, hd)`` arena
+    shared by every sequence (and, at the model level, one arena per
+    layer).
     ``page_table``: ``(B, n_pages)`` int32 — logical KV page ``j`` of
     sequence ``b`` lives in physical page ``page_table[b, j]``
     (``PARKING_PAGE`` = unassigned). ``pos``: per-sequence stream length,
@@ -229,8 +232,8 @@ class PagedKVState:
     page-table references plus pins.
     """
 
-    k: Any                      # (P, page, G, hd)
-    v: Any                      # (P, page, G, hd)
+    k: Any                      # (P, G, page, hd)
+    v: Any                      # (P, G, page, hd)
     page_table: Any             # (B, n_pages) int32
     pos: Any                    # (B,) int32
     free_stack: Any             # (P,) int32
@@ -258,7 +261,7 @@ class PagedKVState:
         if num_pages < 2:
             raise ValueError("num_pages must cover the parking page plus "
                              "at least one allocatable page")
-        shape = (num_pages, page_size, n_kv_heads, head_dim)
+        shape = (num_pages, n_kv_heads, page_size, head_dim)
         scales = (jnp.ones((n_kv_heads,), jnp.float32)
                   if per_head_scales else None)
         # free pages are 1..P-1 (0 is parking); stack[:free_top] free,
@@ -281,7 +284,7 @@ class PagedKVState:
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[2]
 
     @property
     def num_pages(self) -> int:
@@ -578,8 +581,10 @@ class PagedKVState:
         # rather than relying on an unspecified duplicate winner
         phys = jnp.where(real, phys, self.num_pages)
         slot = jnp.broadcast_to((t % ps)[None, :], (n, s))
-        k_t = new.k.at[phys, slot].set(k_q, mode="drop")
-        v_t = new.v.at[phys, slot].set(v_q, mode="drop")
+        # (page, head, slot) scatter: the separated advanced indices put
+        # the (n, s) index dims first, matching k_q's (n, s, G, hd)
+        k_t = new.k.at[phys, :, slot].set(k_q, mode="drop")
+        v_t = new.v.at[phys, :, slot].set(v_q, mode="drop")
         pos = self.pos.at[rows].set(new_pos, mode="drop")
         return dataclasses.replace(new, k=k_t, v=v_t, pos=pos)
 
@@ -614,8 +619,8 @@ class PagedKVState:
         bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
         phys = new.page_table[bidx, toks // ps]            # (B, n_eff)
         phys = jnp.where(live[:, None], phys, self.num_pages)  # drop dead
-        k_t = new.k.at[phys, toks % ps].set(k_q[:, start:], mode="drop")
-        v_t = new.v.at[phys, toks % ps].set(v_q[:, start:], mode="drop")
+        k_t = new.k.at[phys, :, toks % ps].set(k_q[:, start:], mode="drop")
+        v_t = new.v.at[phys, :, toks % ps].set(v_q[:, start:], mode="drop")
         return dataclasses.replace(new, k=k_t, v=v_t,
                                    pos=state.pos + s_new * live_i)
 
@@ -651,8 +656,8 @@ class PagedKVState:
         real = cols < n_new[:, None]
         phys = jnp.where(real, new.page_table[bidx, toks // ps],
                          self.num_pages)                   # pad -> drop
-        k_t = new.k.at[phys, toks % ps].set(k_q, mode="drop")
-        v_t = new.v.at[phys, toks % ps].set(v_q, mode="drop")
+        k_t = new.k.at[phys, :, toks % ps].set(k_q, mode="drop")
+        v_t = new.v.at[phys, :, toks % ps].set(v_q, mode="drop")
         return dataclasses.replace(new, k=k_t, v=v_t,
                                    pos=state.pos + n_new)
 

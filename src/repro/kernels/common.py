@@ -165,6 +165,23 @@ def da_update(m_ref, sigma_ref, logits_i32: jax.Array, valid: jax.Array):
     return u, delta
 
 
+def pow2_neg(n: jax.Array) -> jax.Array:
+    """``2^-n`` in float32, exactly, for integer ``n`` in [0, 40] (the DA
+    correction shift ``delta <= 31`` and the DI scale ``e_r + 8 <= 38``).
+    Built from integer shifts because ``jnp.exp2`` rounds: XLA lowers it
+    to ``exp(n * ln 2)``, which is off by an ulp from ``n = 13`` on the
+    CPU, and a compiled kernel need not round like the interpreter.
+    ``2^(20-a) * 2^(20-b) * 2^-40`` with ``a + b = n`` is a product of
+    powers of two — exact in f32."""
+    n = n.astype(jnp.int32)
+    a = jnp.clip(n, 0, 20)
+    b = jnp.clip(n - a, 0, 20)
+    one = jnp.int32(1)
+    return (jax.lax.shift_left(one, 20 - a).astype(jnp.float32)
+            * jax.lax.shift_left(one, 20 - b).astype(jnp.float32)
+            * jnp.float32(2.0 ** -40))
+
+
 def adaptive_inverse(sigma: jax.Array):
     """DI with per-row power-of-two scaling: returns (sigma_inv, e_r) with
     ``sigma_inv ~= 2^(e_r+8)/sigma`` in (128, 256] and ``e_r = floor(log2

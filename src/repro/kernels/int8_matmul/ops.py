@@ -8,7 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_matmul_blocks
+from repro.kernels.common import default_matmul_blocks, resolve_interpret
 from repro.kernels.int8_matmul.kernel import int8_matmul_pallas
 from repro.kernels.int8_matmul.ref import int8_matmul_ref
 
@@ -22,15 +22,11 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "schedule", "use_pallas",
-                     "interpret"))
 def int8_matmul(x_q: jax.Array, w_q: jax.Array, bias: jax.Array | None = None,
                 mult: jax.Array | float = 1.0, *, block_m: int | None = None,
                 block_n: int | None = None, block_k: int | None = None,
                 schedule: str = "tpu", use_pallas: bool = True,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """Quantized linear: int8 x int8 -> int32 -> requant int8.
 
     ``x_q``: (..., K) int8; ``w_q``: (K, N) int8; ``bias``: (N,) int32 in
@@ -38,8 +34,21 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, bias: jax.Array | None = None,
     multiplier. Leading dims are flattened for the kernel. Block sizes
     default to ``kernels.common.BLOCK_DEFAULTS["int8_matmul"]`` — the
     grid the ``bench_kernels.py --sweep`` run records; explicit
-    ``block_*=`` arguments override per call.
+    ``block_*=`` arguments override per call. ``interpret=None`` resolves
+    through ``kernels.common.resolve_interpret``.
     """
+    return _int8_matmul(x_q, w_q, bias, mult, block_m=block_m,
+                        block_n=block_n, block_k=block_k, schedule=schedule,
+                        use_pallas=use_pallas,
+                        interpret=resolve_interpret(interpret))
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_m", "block_n", "block_k", "schedule", "use_pallas",
+                     "interpret"))
+def _int8_matmul(x_q, w_q, bias, mult, *, block_m, block_n, block_k,
+                 schedule, use_pallas, interpret):
     dm, dn, dk = default_matmul_blocks()
     block_m = dm if block_m is None else block_m
     block_n = dn if block_n is None else block_n

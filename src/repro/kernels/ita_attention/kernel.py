@@ -22,8 +22,17 @@ Integer semantics notes:
 - ``Σ p ≤ 2^(e_r)``... for paper mode (e_r = 8): ``Σ p ≤ 256`` so the A·V
   accumulator is bounded by 2^15 — f32 scratch holds it exactly (ints are
   exact in f32 below 2^24), so paper mode remains bit-exact integer.
-- onepass uses ``u = 128 >> k`` so the numerator operand fits int8 for the
-  MXU; the missing factor 2 folds into the output requant.
+- onepass uses ``u = 128 >> k`` (the missing factor 2 folds into the
+  output requant). The numerator·V matmuls (onepass ``u``, twopass ``p``)
+  run as bf16 x bf16 -> f32 on the MXU, which is exact: ``u, p <= 256``
+  and ``|v| <= 128`` are exact in bf16, every product is exact in f32,
+  and a tile's sum stays below 2^24 (``256 * 128 * bkv`` for bkv <= 256
+  on live rows), where f32 integers are exact. Mosaic has no int32
+  matmul, and ``u`` reaches 128, which int8 cannot hold.
+- per-row scalars (the requant multipliers and the ``[kv_len, q_offset,
+  q_len]`` meta) are whole 1-D arrays in SMEM, indexed by the kernel row
+  ``pl.program_id(0)`` — Mosaic refuses ``(1, 1)`` VMEM blocks of a
+  ``(bh, 1)`` array.
 
 - ``decode`` (serving): the onepass dataflow specialised to incremental
   decode against a KV-cache ring buffer. The q grid dimension disappears
@@ -43,10 +52,12 @@ mixed serve call carries decode rows (q_len 1) next to chunked-prefill
 rows (q_len = chunk). Scalars broadcast to all rows (the dense case).
 
 Paged KV pool: the ``*_paged`` entry points consume one shared
-``(num_pages, page_size, G, hd)`` int8 arena through a **page table**
-delivered as a scalar-prefetch operand — the KV BlockSpec index map reads
-``page_table[b, j]`` to translate logical KV tile ``j`` of sequence ``b``
-into a physical arena page, so scattered pages stream through the very
+head-major ``(num_pages, G, page_size, hd)`` int8 arena (each block is
+one kv head's ``(page_size, hd)`` page, a layout Mosaic tiles) through a
+**page table** delivered as a flat scalar-prefetch operand — the KV
+BlockSpec index map reads ``page_table[b * n_pages + j]`` to translate
+logical KV tile ``j`` of sequence ``b`` into a physical arena page, so
+scattered pages stream through the very
 same kernel bodies (``decode_kernel``/``onepass_kernel``) tile-for-tile.
 With ``block_kv == page_size`` the DA tile schedule is identical to the
 contiguous ring path, which is what keeps paged decode bit-identical to
@@ -64,7 +75,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import INT8_MAX, INT8_MIN, SOFTMAX_SHIFT
 from repro.kernels.common import (MASK_K, NEG_SENTINEL, adaptive_inverse,
-                                  da_update, paper_inverse, tile_mask)
+                                  da_update, paper_inverse, pow2_neg,
+                                  tile_mask)
 
 
 def _qk_logits(q_tile, k_tile, mult):
@@ -76,13 +88,61 @@ def _qk_logits(q_tile, k_tile, mult):
     return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int32)
 
 
+def _row_scalars(lmult_ref, omult_ref, meta_ref):
+    """This kernel row's (logit_mult, out_mult, kv_len, q_offset, q_len)
+    from the whole-array SMEM operands (meta is flat, 3 entries per
+    row)."""
+    r = pl.program_id(0)
+    return (lmult_ref[r], omult_ref[r], meta_ref[3 * r], meta_ref[3 * r + 1],
+            meta_ref[3 * r + 2])
+
+
+def _pv(p, v_tile):
+    """Numerator tile (int32, <= 256) x int8 V tile on the MXU as bf16 x
+    bf16 -> f32 — exact, see the module notes."""
+    return jax.lax.dot_general(p.astype(jnp.bfloat16),
+                               v_tile.astype(jnp.bfloat16),
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _stream_tile(q_tile, k_tile, v_tile, lmult, m_ref, sigma_ref, acc_ref,
+                 valid):
+    """One onepass DA step: logits -> (max, Σ) update -> shift-corrected
+    A·V accumulate (the correction multiplies by 2^-delta, exact in f32,
+    unlike the integer Σ shift)."""
+    logits = _qk_logits(q_tile, k_tile, lmult)
+    u, delta = da_update(m_ref, sigma_ref, logits, valid)
+    corr = pow2_neg(delta)
+    acc_ref[...] = acc_ref[...] * corr + _pv(u, v_tile)
+
+
+def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive):
+    if adaptive:
+        inv, e_r = adaptive_inverse(sigma_ref[...])
+    else:
+        inv = paper_inverse(sigma_ref[...])
+        e_r = jnp.full_like(inv, 8)
+    # out = acc * 2 * inv * 2^-(e_r+8) * (s_v/s_out); the 2 restores the
+    # halved numerator unit (u = 128>>k vs the paper's 256>>k).
+    scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
+    y = jnp.round(acc_ref[...] * scale)
+    o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+
+
+def _kv_tile(ref, paged):
+    """(bkv, d) tile of a K/V block: (1, bkv, d) rows of the 3-D kernel
+    layout, or (1, 1, page, d) of the head-major paged pool."""
+    return ref[0, 0] if paged else ref[0]
+
+
 def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
                    o_ref, m_ref, sigma_ref, acc_ref,
                    *, causal: bool, window: int, adaptive: bool,
-                   bq: int, bkv: int, kv_4d: bool = False):
+                   bq: int, bkv: int, paged: bool = False):
     i, j = pl.program_id(1), pl.program_id(2)
-    last_j = pl.num_programs(2) - 1
-    kv_len = meta_ref[0, 0]
+    lmult, omult, kv_len, q_off, q_len = _row_scalars(lmult_ref, omult_ref,
+                                                      meta_ref)
 
     @pl.when(j == 0)
     def _init():
@@ -95,39 +155,13 @@ def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     # only their occupied pages, not the whole pool.
     @pl.when(j * bkv < kv_len)
     def _tile():
-        # kv_4d: cache-native (1, bkv, 1, d) blocks sliced straight out of
-        # a (B, S, G, hd) buffer by the index map — no host-side transpose.
-        k_tile = k_ref[0, :, 0] if kv_4d else k_ref[0]
-        v_tile = v_ref[0, :, 0] if kv_4d else v_ref[0]
-        logits = _qk_logits(q_ref[0], k_tile, lmult_ref[0, 0])
-        valid = tile_mask(i, j, bq, bkv, causal, window, kv_len,
-                          meta_ref[0, 1], meta_ref[0, 2])
-        u, delta = da_update(m_ref, sigma_ref, logits, valid)
-        # Correct the running A·V accumulator for the max update (exact in
-        # f32: multiplying by 2^-delta loses nothing, unlike the integer Σ
-        # shift).
-        corr = jnp.exp2(-delta.astype(jnp.float32))
-        # u in [0, 128] — packs into uint8 on the MXU (int32 here:
-        # interpret mode validates semantics; XLA emits the s8/u8 MXU path
-        # on TPU).
-        pv = jax.lax.dot_general(u, v_tile.astype(jnp.int32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
-        acc_ref[...] = acc_ref[...] * corr + pv.astype(jnp.float32)
+        valid = tile_mask(i, j, bq, bkv, causal, window, kv_len, q_off, q_len)
+        _stream_tile(q_ref[0], _kv_tile(k_ref, paged), _kv_tile(v_ref, paged),
+                     lmult, m_ref, sigma_ref, acc_ref, valid)
 
-    @pl.when(j == last_j)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        if adaptive:
-            inv, e_r = adaptive_inverse(sigma_ref[...])
-        else:
-            inv = paper_inverse(sigma_ref[...])
-            e_r = jnp.full_like(inv, 8)
-        # out = acc * 2 * inv * 2^-(e_r+8) * (s_v/s_out); the 2 restores the
-        # halved numerator unit (u = 128>>k vs the paper's 256>>k).
-        scale = 2.0 * inv.astype(jnp.float32) * jnp.exp2(
-            -(e_r + 8).astype(jnp.float32)) * omult_ref[0, 0]
-        y = jnp.round(acc_ref[...] * scale)
-        o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive)
 
 
 def qk_da_kernel(q_ref, k_ref, lmult_ref, meta_ref, a_ref, max_o_ref,
@@ -135,22 +169,23 @@ def qk_da_kernel(q_ref, k_ref, lmult_ref, meta_ref, a_ref, max_o_ref,
                  *, causal: bool, window: int, bq: int, bkv: int):
     """Two-pass, pass 1: logits to HBM once + DA stats."""
     i, j = pl.program_id(1), pl.program_id(2)
+    r = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_SENTINEL)
         sigma_ref[...] = jnp.zeros_like(sigma_ref)
 
-    logits = _qk_logits(q_ref[0], k_ref[0], lmult_ref[0, 0])
-    valid = tile_mask(i, j, bq, bkv, causal, window, meta_ref[0, 0],
-                      meta_ref[0, 1], meta_ref[0, 2])
+    logits = _qk_logits(q_ref[0], k_ref[0], lmult_ref[r])
+    valid = tile_mask(i, j, bq, bkv, causal, window, meta_ref[3 * r],
+                      meta_ref[3 * r + 1], meta_ref[3 * r + 2])
     da_update(m_ref, sigma_ref, logits, valid)
     a_ref[0] = logits.astype(jnp.int8)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _emit_stats():
-        max_o_ref[0] = m_ref[...][:, 0]
-        sigma_o_ref[0] = sigma_ref[...][:, 0]
+        max_o_ref[0] = m_ref[...]
+        sigma_o_ref[0] = sigma_ref[...]
 
 
 def av_en_kernel(a_ref, inv_ref, er_ref, max_ref, v_ref, omult_ref,
@@ -158,43 +193,34 @@ def av_en_kernel(a_ref, inv_ref, er_ref, max_ref, v_ref, omult_ref,
                  *, causal: bool, window: int, bq: int, bkv: int):
     """Two-pass, pass 2: re-stream A, EN by pure shifts, A·V on the MXU."""
     i, j = pl.program_id(1), pl.program_id(2)
+    r = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[0].astype(jnp.int32)
-    row_max = max_ref[0][:, None]
-    valid = tile_mask(i, j, bq, bkv, causal, window, meta_ref[0, 0],
-                      meta_ref[0, 1], meta_ref[0, 2])
-    k = jax.lax.shift_right_logical(row_max - a, SOFTMAX_SHIFT)
+    valid = tile_mask(i, j, bq, bkv, causal, window, meta_ref[3 * r],
+                      meta_ref[3 * r + 1], meta_ref[3 * r + 2])
+    k = jax.lax.shift_right_logical(max_ref[0] - a, SOFTMAX_SHIFT)
     k = jnp.where(valid, jnp.minimum(k, 31), MASK_K)
-    p = jax.lax.shift_right_logical(inv_ref[0][:, None], k)   # EN: p ≤ 256
-    pv = jax.lax.dot_general(p, v_ref[0].astype(jnp.int32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.int32)
-    acc_ref[...] += pv.astype(jnp.float32)       # exact: |acc| < 2^24
+    p = jax.lax.shift_right_logical(inv_ref[0], k)   # EN: p <= 256 live
+    acc_ref[...] += _pv(p, v_ref[0])                 # exact: |acc| < 2^24
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        e_r = er_ref[0][:, None].astype(jnp.float32)
-        y = jnp.round(acc_ref[...] * jnp.exp2(-e_r) * omult_ref[0, 0])
+        y = jnp.round(acc_ref[...] * pow2_neg(er_ref[0]) * omult_ref[r])
         o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
 
 
 def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
                   o_ref, m_ref, sigma_ref, acc_ref,
                   *, causal: bool, window: int, adaptive: bool,
-                  bq: int, bkv: int, kv_4d: bool):
-    """Onepass dataflow without a q grid axis (decode: sq <= one tile).
-
-    ``kv_4d``: K/V refs carry cache-native (1, bkv, 1, d) blocks sliced
-    straight out of a (B, C, G, hd) ring buffer — no host-side transpose
-    or GQA head broadcast ever materializes.
-    """
+                  bq: int, bkv: int, paged: bool = False):
+    """Onepass dataflow without a q grid axis (decode: sq <= one tile)."""
     j = pl.program_id(1)
-    last_j = pl.num_programs(1) - 1
-    kv_len = meta_ref[0, 0]
+    lmult, omult, kv_len, q_off, q_len = _row_scalars(lmult_ref, omult_ref,
+                                                      meta_ref)
 
     @pl.when(j == 0)
     def _init():
@@ -206,51 +232,35 @@ def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     # prefix are fully masked (max/sigma/acc all no-ops) — skip the MXU work.
     @pl.when(j * bkv < kv_len)
     def _tile():
-        k_tile = k_ref[0, :, 0] if kv_4d else k_ref[0]
-        v_tile = v_ref[0, :, 0] if kv_4d else v_ref[0]
-        logits = _qk_logits(q_ref[0], k_tile, lmult_ref[0, 0])
-        valid = tile_mask(0, j, bq, bkv, causal, window, kv_len,
-                          meta_ref[0, 1], meta_ref[0, 2])
-        u, delta = da_update(m_ref, sigma_ref, logits, valid)
-        corr = jnp.exp2(-delta.astype(jnp.float32))
-        pv = jax.lax.dot_general(u, v_tile.astype(jnp.int32),
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
-        acc_ref[...] = acc_ref[...] * corr + pv.astype(jnp.float32)
+        valid = tile_mask(0, j, bq, bkv, causal, window, kv_len, q_off, q_len)
+        _stream_tile(q_ref[0], _kv_tile(k_ref, paged), _kv_tile(v_ref, paged),
+                     lmult, m_ref, sigma_ref, acc_ref, valid)
 
-    @pl.when(j == last_j)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        if adaptive:
-            inv, e_r = adaptive_inverse(sigma_ref[...])
-        else:
-            inv = paper_inverse(sigma_ref[...])
-            e_r = jnp.full_like(inv, 8)
-        scale = 2.0 * inv.astype(jnp.float32) * jnp.exp2(
-            -(e_r + 8).astype(jnp.float32)) * omult_ref[0, 0]
-        y = jnp.round(acc_ref[...] * scale)
-        o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive)
 
 
-def _specs_bh(block, index):
-    return pl.BlockSpec(block, index)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _row_mults(logit_mult, out_mult, bh):
-    """Broadcast scalar or per-row requant multipliers to (bh, 1) f32."""
+    """Broadcast scalar or per-row requant multipliers to (bh,) f32."""
     lm = jnp.broadcast_to(jnp.asarray(logit_mult, jnp.float32).reshape(-1),
-                          (bh,)).reshape(bh, 1)
+                          (bh,))
     om = jnp.broadcast_to(jnp.asarray(out_mult, jnp.float32).reshape(-1),
-                          (bh,)).reshape(bh, 1)
+                          (bh,))
     return lm, om
 
 
 def _row_meta(kv_len, q_offset, q_len, bh):
-    """Per-row ``[kv_len, q_offset, q_len]`` meta (bh, 3) int32. Scalars
-    (the dense case) broadcast to every row; (bh,) vectors pass through —
-    the ragged path, one valid KV prefix / query position / query count
-    per (batch·head) row. ``q_len`` is the row's count of *valid query
-    rows* (ragged q_len: a mixed chunked-prefill/decode call); pass the
-    static query width for the dense case."""
+    """Per-row ``[kv_len, q_offset, q_len]`` meta, flat (bh * 3,) int32
+    (row-major: row r's triple at ``3r``). Scalars (the dense case)
+    broadcast to every row; (bh,) vectors pass through — the ragged path,
+    one valid KV prefix / query position / query count per (batch·head)
+    row. ``q_len`` is the row's count of *valid query rows* (ragged
+    q_len: a mixed chunked-prefill/decode call); pass the static query
+    width for the dense case."""
     kv = jnp.asarray(kv_len, jnp.int32).reshape(-1)
     off = jnp.asarray(q_offset, jnp.int32).reshape(-1)
     qn = jnp.asarray(q_len, jnp.int32).reshape(-1)
@@ -259,7 +269,7 @@ def _row_meta(kv_len, q_offset, q_len, bh):
     assert qn.shape[0] in (1, bh), (qn.shape, bh)
     return jnp.stack([jnp.broadcast_to(kv, (bh,)),
                       jnp.broadcast_to(off, (bh,)),
-                      jnp.broadcast_to(qn, (bh,))], axis=1)
+                      jnp.broadcast_to(qn, (bh,))], axis=1).reshape(-1)
 
 
 def ita_attention_onepass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
@@ -267,46 +277,26 @@ def ita_attention_onepass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                           window: int = 0,
                           adaptive: bool = True, block_q: int = 128,
                           block_kv: int = 128, kv_rep: int = 1,
-                          hq: int | None = None, interpret: bool = True):
+                          interpret: bool = True):
     """q (BH, Sq, D) int8; k/v (BH/kv_rep, Skv, D) int8; returns (BH, Sq, D)
     int8. GQA: q row r reads kv row r // kv_rep via the index map — the KV
-    head broadcast never materializes.
-
-    K/V layouts (chosen by shape, as in ``ita_attention_decode``):
-    - 3D ``(BH/kv_rep, Skv, D)``: kernel layout.
-    - 4D ``(B, Skv, G, D)``: cache-native layout (requires ``hq``) —
-      prefill straight out of a KV ring buffer, no host-side transpose.
-    """
+    head broadcast never materializes."""
     bh, sq, d = q_q.shape
-    kv_4d = k_q.ndim == 4
     skv = k_q.shape[1]
     bq, bkv = min(block_q, sq), min(block_kv, skv)
     assert sq % bq == 0 and skv % bkv == 0
+    assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
     kern = functools.partial(onepass_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=bq, bkv=bkv, kv_4d=kv_4d)
+                             adaptive=adaptive, bq=bq, bkv=bkv)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    if kv_4d:
-        assert hq is not None and bh % hq == 0
-        # q row r = batch * hq + head  ->  (batch, kv tile, kv head)
-        kv_spec = _specs_bh(
-            (1, bkv, 1, d),
-            lambda r, i, j: (r // hq, j, (r % hq) // kv_rep, 0))
-    else:
-        assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
-        kv_spec = _specs_bh((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0))
+    kv_spec = pl.BlockSpec((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0))
     return pl.pallas_call(
         kern,
         grid=(bh, sq // bq, skv // bkv),
-        in_specs=[
-            _specs_bh((1, bq, d), lambda b, i, j: (b, i, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, i, j: (b, 0)),
-        ],
-        out_specs=_specs_bh((1, bq, d), lambda b, i, j: (b, i, 0)),
+        in_specs=[pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                  kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
+        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32),
@@ -322,7 +312,8 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                           interpret: bool = True):
     """Paper-faithful dataflow. Returns (out int8, a_mat int8) — A is the
     materialized int8 attention matrix (written once, read once).
-    GQA via ``kv_rep`` index maps as in onepass."""
+    GQA via ``kv_rep`` index maps as in onepass. Row stats travel as
+    (bh, sq, 1) columns, the layout the (bq, 1) DA scratch already has."""
     bh, sq, d = q_q.shape
     skv = k_q.shape[1]
     bq, bkv = min(block_q, sq), min(block_kv, skv)
@@ -330,26 +321,21 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
     assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq, bh)
+    grid = (bh, sq // bq, skv // bkv)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0))
+    a_spec = pl.BlockSpec((1, bq, bkv), lambda b, i, j: (b, i, j))
+    col_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    col = jax.ShapeDtypeStruct((bh, sq, 1), jnp.int32)
 
     k1 = functools.partial(qk_da_kernel, causal=causal, window=window,
                            bq=bq, bkv=bkv)
     a_mat, row_max, sigma = pl.pallas_call(
         k1,
-        grid=(bh, sq // bq, skv // bkv),
-        in_specs=[
-            _specs_bh((1, bq, d), lambda b, i, j: (b, i, 0)),
-            _specs_bh((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, i, j: (b, 0)),
-        ],
-        out_specs=[
-            _specs_bh((1, bq, bkv), lambda b, i, j: (b, i, j)),
-            _specs_bh((1, bq), lambda b, i, j: (b, i)),
-            _specs_bh((1, bq), lambda b, i, j: (b, i)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((bh, sq, skv), jnp.int8),
-                   jax.ShapeDtypeStruct((bh, sq), jnp.int32),
-                   jax.ShapeDtypeStruct((bh, sq), jnp.int32)],
+        grid=grid,
+        in_specs=[q_spec, kv_spec, _SMEM, _SMEM],
+        out_specs=[a_spec, col_spec, col_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, skv), jnp.int8), col, col],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32)],
         interpret=interpret,
@@ -367,17 +353,10 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                            bq=bq, bkv=bkv)
     out = pl.pallas_call(
         k2,
-        grid=(bh, sq // bq, skv // bkv),
-        in_specs=[
-            _specs_bh((1, bq, bkv), lambda b, i, j: (b, i, j)),
-            _specs_bh((1, bq), lambda b, i, j: (b, i)),
-            _specs_bh((1, bq), lambda b, i, j: (b, i)),
-            _specs_bh((1, bq), lambda b, i, j: (b, i)),
-            _specs_bh((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, i, j: (b, 0)),
-        ],
-        out_specs=_specs_bh((1, bq, d), lambda b, i, j: (b, i, 0)),
+        grid=grid,
+        in_specs=[a_spec, col_spec, col_spec, col_spec, kv_spec, _SMEM,
+                  _SMEM],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
@@ -389,53 +368,32 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                          q_offset=0, q_len=None, causal: bool = True,
                          window: int = 0,
                          adaptive: bool = True, block_kv: int = 128,
-                         kv_rep: int = 1, hq: int | None = None,
-                         interpret: bool = True):
+                         kv_rep: int = 1, interpret: bool = True):
     """Fused decode step: q (BH, Sq<=8, D) int8 against an int8 KV ring
-    buffer with ``kv_len`` valid entries. Single q tile (no q grid axis);
-    KV tiles past ``kv_len`` are skipped inside the kernel, so cost scales
-    with the *occupied* prefix, not the ring capacity — per row:
-    ``kv_len``/``q_offset`` may be (BH,) vectors (ragged batch), each row
-    masking and tile-skipping against its own prefix. Streaming DA
-    semantics are identical to ``onepass`` at equal ``block_kv`` — decode
-    outputs are bit-identical to the matching prefill rows.
-
-    K/V layouts (chosen by shape):
-    - 3D ``(BH/kv_rep, C, D)``: kernel layout; GQA via row index map.
-    - 4D ``(B, C, G, D)``: cache-native ring-buffer layout (requires
-      ``hq``); blocks are gathered by index map — the per-step transpose
-      and head broadcast a host-side relayout would cost never happen.
-    """
+    buffer ``(BH/kv_rep, C, D)`` with ``kv_len`` valid entries. Single q
+    tile (no q grid axis); KV tiles past ``kv_len`` are skipped inside
+    the kernel, so cost scales with the *occupied* prefix, not the ring
+    capacity — per row: ``kv_len``/``q_offset`` may be (BH,) vectors
+    (ragged batch), each row masking and tile-skipping against its own
+    prefix. Streaming DA semantics are identical to ``onepass`` at equal
+    ``block_kv`` — decode outputs are bit-identical to the matching
+    prefill rows. GQA via the ``kv_rep`` row index map."""
     bh, sq, d = q_q.shape
-    kv_4d = k_q.ndim == 4
-    skv = k_q.shape[1]                      # seq axis in both layouts
+    skv = k_q.shape[1]
     bkv = min(block_kv, skv)
     assert skv % bkv == 0, (skv, bkv)
+    assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
     kern = functools.partial(decode_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=sq, bkv=bkv, kv_4d=kv_4d)
+                             adaptive=adaptive, bq=sq, bkv=bkv)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    if kv_4d:
-        assert hq is not None and bh % hq == 0
-        # q row r = batch * hq + head  ->  (batch, kv tile, kv head)
-        kv_spec = _specs_bh(
-            (1, bkv, 1, d),
-            lambda r, j: (r // hq, j, (r % hq) // kv_rep, 0))
-    else:
-        assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
-        kv_spec = _specs_bh((1, bkv, d), lambda r, j: (r // kv_rep, j, 0))
+    kv_spec = pl.BlockSpec((1, bkv, d), lambda r, j: (r // kv_rep, j, 0))
     return pl.pallas_call(
         kern,
         grid=(bh, skv // bkv),
-        in_specs=[
-            _specs_bh((1, sq, d), lambda b, j: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, j: (b, 0)),
-        ],
-        out_specs=_specs_bh((1, sq, d), lambda b, j: (b, 0, 0)),
+        in_specs=[pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
+                  kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
+        out_specs=pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         scratch_shapes=[pltpu.VMEM((sq, 1), jnp.int32),
                         pltpu.VMEM((sq, 1), jnp.int32),
@@ -458,6 +416,39 @@ def _swallow_pt(kern):
     return wrapped
 
 
+def _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis):
+    """K/V BlockSpec of the head-major pool: kernel row ``r`` (batch
+    ``r // hq``, head ``r % hq``) reads its kv head's page
+    ``pt[(r // hq) * n_pages + j]`` for logical KV tile ``j``."""
+    def page_of(r, j, pt):
+        return (pt[(r // hq) * n_pages + j], (r % hq) // kv_rep, 0, 0)
+    if with_q_axis:
+        return pl.BlockSpec((1, 1, page, d),
+                            lambda r, i, j, pt: page_of(r, j, pt))
+    return pl.BlockSpec((1, 1, page, d), page_of)
+
+
+def _paged_call(kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
+                page_table, lmult, omult, meta, bq, interpret):
+    """Shared pallas_call of the paged kernels: the flat page table is
+    the scalar-prefetch operand the K/V index maps read."""
+    bh, sq, d = q_q.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
+                        pltpu.VMEM((bq, 1), jnp.int32),
+                        pltpu.VMEM((bq, d), jnp.float32)])
+    return pl.pallas_call(
+        _swallow_pt(kern),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
+        interpret=interpret,
+    )(page_table.reshape(-1), q_q, k_pool, v_pool, lmult, omult, meta)
+
+
 def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
                                out_mult, kv_len, *, q_offset=0, q_len=None,
                                causal: bool = True, window: int = 0,
@@ -466,51 +457,32 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     """Fused decode step over a paged KV pool.
 
     ``q_q`` (BH, Sq<=8, D) int8; ``k_pool``/``v_pool``
-    ``(num_pages, page_size, G, D)`` int8 shared arena; ``page_table``
-    ``(B, n_pages)`` int32 maps each sequence's logical KV page to a
-    physical arena page (entries beyond the valid prefix may point
-    anywhere — those tiles are skipped/masked via ``kv_len``).
+    ``(num_pages, G, page_size, D)`` int8 shared head-major arena;
+    ``page_table`` ``(B, n_pages)`` int32 maps each sequence's logical KV
+    page to a physical arena page (entries beyond the valid prefix may
+    point anywhere — those tiles are skipped/masked via ``kv_len``).
 
     ``block_kv`` is the page size: logical tile ``j`` of kernel row ``r``
-    is DMA'd from ``pool[page_table[r // hq, j]]`` by a scalar-prefetch
-    index map, and the DA streaming schedule is identical to
-    ``ita_attention_decode`` at ``block_kv == page_size`` — paged decode
-    is bit-identical to the contiguous ring path (family ``ita_fused``).
+    is DMA'd from ``pool[page_table[r // hq, j], kv head]`` by a
+    scalar-prefetch index map, and the DA streaming schedule is identical
+    to ``ita_attention_decode`` at ``block_kv == page_size`` — paged
+    decode is bit-identical to the contiguous ring path (family
+    ``ita_fused``).
     """
     bh, sq, d = q_q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     n_pages = page_table.shape[1]
     assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
         (bh, hq, page_table.shape)
     kern = functools.partial(decode_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=sq, bkv=page, kv_4d=True)
+                             adaptive=adaptive, bq=sq, bkv=page, paged=True)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    kv_spec = pl.BlockSpec(
-        (1, page, 1, d),
-        lambda r, j, pt: (pt[r // hq, j], 0, (r % hq) // kv_rep, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, j, pt: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, 1), lambda b, j, pt: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, pt: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, j, pt: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, sq, d), lambda b, j, pt: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((sq, 1), jnp.int32),
-                        pltpu.VMEM((sq, 1), jnp.int32),
-                        pltpu.VMEM((sq, d), jnp.float32)],
-    )
-    return pl.pallas_call(
-        _swallow_pt(kern),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
-        interpret=interpret,
-    )(page_table, q_q, k_pool, v_pool, lmult, omult, meta)
+    return _paged_call(
+        kern, (bh, n_pages),
+        pl.BlockSpec((1, sq, d), lambda b, j, pt: (b, 0, 0)),
+        _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=False),
+        q_q, k_pool, v_pool, page_table, lmult, omult, meta, sq, interpret)
 
 
 def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
@@ -527,38 +499,18 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     marks each row's count of valid query rows — ragged q_len: one call
     serves rows with q widths in {1, chunk} (pad rows emit zeros)."""
     bh, sq, d = q_q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     n_pages = page_table.shape[1]
     bq = min(block_q, sq)
     assert sq % bq == 0, (sq, bq)
     assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
         (bh, hq, page_table.shape)
     kern = functools.partial(onepass_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=bq, bkv=page, kv_4d=True)
+                             adaptive=adaptive, bq=bq, bkv=page, paged=True)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    kv_spec = pl.BlockSpec(
-        (1, page, 1, d),
-        lambda r, i, j, pt: (pt[r // hq, j], 0, (r % hq) // kv_rep, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, sq // bq, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j, pt: (b, i, 0)),
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, 1), lambda b, i, j, pt: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, j, pt: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, i, j, pt: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j, pt: (b, i, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
-    )
-    return pl.pallas_call(
-        _swallow_pt(kern),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
-        interpret=interpret,
-    )(page_table, q_q, k_pool, v_pool, lmult, omult, meta)
+    return _paged_call(
+        kern, (bh, sq // bq, n_pages),
+        pl.BlockSpec((1, bq, d), lambda b, i, j, pt: (b, i, 0)),
+        _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=True),
+        q_q, k_pool, v_pool, page_table, lmult, omult, meta, bq, interpret)

@@ -87,17 +87,13 @@ def _per_row(x, b, h):
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "kind", "adaptive", "block_q", "block_kv",
-    "kv_native", "interpret"))
+    "interpret"))
 def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
-           causal, window, kind, adaptive, block_q, block_kv, kv_native,
+           causal, window, kind, adaptive, block_q, block_kv,
            interpret, page_table=None, q_lens=None):
     b, hq, sq, d = q_q.shape
-    if page_table is not None:                  # paged pool (P, page, G, hd)
-        hkv = k_q.shape[2]
-    elif kv_native:
-        skv, hkv = k_q.shape[1], k_q.shape[2]
-    else:
-        hkv, skv = k_q.shape[1], k_q.shape[2]
+    # (B, Hkv, Skv, D), or the paged pool (P, Hkv, page, D): heads at 1
+    hkv, skv = k_q.shape[1], k_q.shape[2]
     assert hq % hkv == 0, (hq, hkv)
     rep = hq // hkv
 
@@ -115,7 +111,7 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
         # arena through the page-table index maps.
         bq = min(block_q, max(8, sq))
         qf = _pad_seq(q_q.reshape(b * hq, sq, d), bq)
-        skv = page_table.shape[1] * k_q.shape[1]
+        skv = page_table.shape[1] * k_q.shape[2]
         kv_len = _per_row(skv if kv_len is None else kv_len, b, hq)
         q_offset = _per_row(q_offset, b, hq)
         q_len = None if q_lens is None else _per_row(q_lens, b, hq)
@@ -134,14 +130,8 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
     bq = min(block_q, max(8, sq))
     bkv = min(block_kv, max(128, skv)) if skv >= 128 else skv
     qf = _pad_seq(q_q.reshape(b * hq, sq, d), bq)
-    if kv_native:
-        kf = _pad_seq(k_q, bkv, hot=kind == "decode")
-        vf = _pad_seq(v_q, bkv, hot=kind == "decode")
-    else:
-        kf = _pad_seq(k_q.reshape(b * hkv, skv, d), bkv,
-                      hot=kind == "decode")
-        vf = _pad_seq(v_q.reshape(b * hkv, skv, d), bkv,
-                      hot=kind == "decode")
+    kf = _pad_seq(k_q.reshape(b * hkv, skv, d), bkv, hot=kind == "decode")
+    vf = _pad_seq(v_q.reshape(b * hkv, skv, d), bkv, hot=kind == "decode")
 
     kv_len = _per_row(skv if kv_len is None else kv_len, b, hq)
     q_offset = _per_row(q_offset, b, hq)
@@ -150,14 +140,12 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
         out = ita_attention_decode(
             qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
             q_len=q_len, causal=causal, window=window, adaptive=adaptive,
-            block_kv=bkv, kv_rep=rep,
-            hq=hq if kv_native else None, interpret=interpret)
+            block_kv=bkv, kv_rep=rep, interpret=interpret)
     elif kind == "onepass":
         out = ita_attention_onepass(
             qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
             q_len=q_len, causal=causal, window=window, adaptive=adaptive,
-            block_q=bq, block_kv=bkv, kv_rep=rep,
-            hq=hq if kv_native else None, interpret=interpret)
+            block_q=bq, block_kv=bkv, kv_rep=rep, interpret=interpret)
     else:
         out, _ = ita_attention_twopass(
             qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
@@ -174,19 +162,15 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
                     causal: bool = True, window: int = 0,
                     kind: str = "onepass", adaptive: bool = True,
                     block_q: int = 128, block_kv: int = 128,
-                    kv_native: bool = False,
                     page_table: jax.Array | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """Quantized multi-head attention with the ITA integer softmax.
 
-    ``q_q``: (B, Hq, Sq, D) int8; ``k_q``/``v_q``: (B, Hkv, Skv, D) int8
-    or, with ``kv_native=True`` (``kind`` decode or onepass), cache-native
-    (B, Skv, Hkv, D) ring buffers (consumed in place via kernel index
-    maps, no transpose/broadcast copies). GQA: Hkv must divide Hq; KV
-    heads are shared per group via index maps — the broadcast never
-    materializes.
-    ``page_table`` (B, n_pages) int32 switches K/V to a shared **paged
-    pool** ``(num_pages, page_size, Hkv, D)``: logical KV tile ``j`` of
+    ``q_q``: (B, Hq, Sq, D) int8; ``k_q``/``v_q``: (B, Hkv, Skv, D) int8.
+    GQA: Hkv must divide Hq; KV heads are shared per group via index
+    maps — the broadcast never materializes.
+    ``page_table`` (B, n_pages) int32 switches K/V to a shared head-major
+    **paged pool** ``(num_pages, Hkv, page_size, D)``: logical KV tile ``j`` of
     sequence ``b`` streams from physical page ``page_table[b, j]``
     (scalar-prefetch index maps; ``block_kv`` is the page size — the
     ``block_kv`` argument is ignored). Bit-identical to the contiguous
@@ -202,8 +186,6 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     Returns (B, Hq, Sq, D) int8 at scale ``s_out``.
     """
     assert kind in KINDS, kind
-    assert not (kv_native and kind == "twopass"), \
-        "cache-native KV layout serves the onepass/decode kernels only"
     assert not (page_table is not None and kind == "twopass"), \
         "the paged pool serves the onepass/decode kernels only"
     assert not (q_lens is not None and kind == "twopass"), \
@@ -211,5 +193,5 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     return _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, q_offset=q_offset,
                   kv_len=kv_len, causal=causal, window=window, kind=kind,
                   adaptive=adaptive, block_q=block_q, block_kv=block_kv,
-                  kv_native=kv_native, page_table=page_table,
+                  page_table=page_table,
                   q_lens=q_lens, interpret=resolve_interpret(interpret))

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import INT8_MAX, INT8_MIN, SOFTMAX_SHIFT
-from repro.kernels.common import MASK_K, NEG_SENTINEL
+from repro.kernels.common import MASK_K, NEG_SENTINEL, pow2_neg
 
 
 def _full_mask(sq, skv, causal, window, kv_len, q_offset=0):
@@ -77,7 +77,7 @@ def ita_attention_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     p = jax.lax.shift_right_logical(inv, k)                       # EN
     acc = jnp.einsum("bqk,bkd->bqd", p, v_q.astype(jnp.int32))
     y = jnp.round(acc.astype(jnp.float32)
-                  * jnp.exp2(-e_r.astype(jnp.float32)) * omult)
+                  * pow2_neg(e_r) * omult)
     out = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
     return out, logits.astype(jnp.int8)
 
@@ -93,8 +93,7 @@ def ita_attention_fused_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     u = jax.lax.shift_right_logical(jnp.int32(128), k)
     acc = jnp.einsum("bqk,bkd->bqd", u, v_q.astype(jnp.int32)
                      ).astype(jnp.float32)
-    scale = 2.0 * inv.astype(jnp.float32) * jnp.exp2(
-        -(e_r + 8).astype(jnp.float32)) * omult
+    scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
     y = jnp.round(acc * scale)
     return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
 
@@ -128,13 +127,12 @@ def ita_attention_stream_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
         run_max = new_max
         if kind == "onepass":
             pv = jnp.einsum("bqk,bkd->bqd", u, v_q[:, sl].astype(jnp.int32))
-            acc = acc * jnp.exp2(-delta.astype(jnp.float32)) \
+            acc = acc * pow2_neg(delta) \
                 + pv.astype(jnp.float32)
 
     inv, e_r = _inverse(run_sigma, adaptive)
     if kind == "onepass":
-        scale = 2.0 * inv.astype(jnp.float32) * jnp.exp2(
-            -(e_r + 8).astype(jnp.float32)) * omult
+        scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
         y = jnp.round(acc * scale)
         return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
 
@@ -144,7 +142,7 @@ def ita_attention_stream_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     p = jax.lax.shift_right_logical(inv, k)
     acc2 = jnp.einsum("bqk,bkd->bqd", p, v_q.astype(jnp.int32))
     y = jnp.round(acc2.astype(jnp.float32)
-                  * jnp.exp2(-e_r.astype(jnp.float32)) * omult)
+                  * pow2_neg(e_r) * omult)
     return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
 
 

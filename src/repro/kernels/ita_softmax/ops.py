@@ -7,19 +7,28 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import resolve_interpret
 from repro.kernels.ita_softmax.kernel import ita_softmax_pallas
+
+
+def ita_softmax(x_q: jax.Array, mask: jax.Array | None = None, *,
+                block_r: int = 128, block_c: int = 128,
+                adaptive: bool = False,
+                interpret: bool | None = None) -> jax.Array:
+    """Streaming integer softmax over the last axis of int8 logits.
+
+    Accepts any leading shape; pads rows/cols to block multiples (padded
+    columns are masked out and return probability 0). ``interpret=None``
+    resolves through ``kernels.common.resolve_interpret``.
+    """
+    return _ita_softmax(x_q, mask, block_r=block_r, block_c=block_c,
+                        adaptive=adaptive,
+                        interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "block_c", "adaptive",
                                              "interpret"))
-def ita_softmax(x_q: jax.Array, mask: jax.Array | None = None, *,
-                block_r: int = 128, block_c: int = 128,
-                adaptive: bool = False, interpret: bool = True) -> jax.Array:
-    """Streaming integer softmax over the last axis of int8 logits.
-
-    Accepts any leading shape; pads rows/cols to block multiples (padded
-    columns are masked out and return probability 0).
-    """
+def _ita_softmax(x_q, mask, *, block_r, block_c, adaptive, interpret):
     *lead, n = x_q.shape
     x2 = x_q.reshape(-1, n)
     r = x2.shape[0]

@@ -13,6 +13,7 @@ devices *before* any jax import (see dryrun.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 DATA_AXIS = 16
 MODEL_AXIS = 16
@@ -21,14 +22,21 @@ MODEL_AXIS = 16
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, DATA_AXIS, MODEL_AXIS) if multi_pod else (DATA_AXIS, MODEL_AXIS)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the layers place activations
+    through ``with_sharding_constraint`` hints (``launch/hints.py``),
+    which JAX accepts only on Auto axes (its default is now Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int | None = None):
-    """Small mesh over whatever devices exist (tests / examples)."""
+    """Mesh over whatever devices exist (serving, tests, examples)."""
     n = len(jax.devices())
     m = model_axis or (2 if n % 2 == 0 and n > 1 else 1)
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return _auto_mesh((n // m, m), ("data", "model"))
 
 
 def fsdp_axes(mesh) -> tuple:

@@ -31,9 +31,10 @@ import numpy as np
 
 from repro import attention as ATT
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.hints import use_hints
-from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.models import init_model
+from repro.launch.mesh import make_host_mesh
+from repro.models import init_serving_params
 from repro.models.attention import make_spec
 from repro.runtime.generate import ServeRequest, generate, serve_continuous
 
@@ -165,13 +166,16 @@ def main():
             mark = "eligible" if verdict is True else f"no — {verdict}"
             print(f"[serve]   {name:20s} {mark}")
         return
-    mesh = make_host_mesh() if args.smoke else make_production_mesh()
+    use_compile_cache()
+    # serving runs on the devices present (one v5e chip serves phi3-mini
+    # whole); weights are created in the compute dtype (bf16), never f32
+    mesh = make_host_mesh()
     key = jax.random.PRNGKey(args.seed)
 
     if args.continuous:
         rng = np.random.default_rng(args.seed)
         with mesh, use_hints(mesh):
-            params = init_model(key, cfg)
+            params = init_serving_params(key, cfg)
             rate = max(args.rate, 1e-6) * max(args.overload, 1e-6)
             arrivals = np.cumsum(rng.exponential(1.0 / rate,
                                                  args.requests)).astype(int)
@@ -253,7 +257,7 @@ def main():
         return
 
     with mesh, use_hints(mesh):
-        params = init_model(key, cfg)
+        params = init_serving_params(key, cfg)
         prompts = jax.random.randint(key, (args.batch, args.prompt_len), 0,
                                      cfg.vocab_size)
         lengths = None

@@ -1,3 +1,3 @@
 """Model zoo: build any assigned architecture from its config."""
 from repro.models.transformer import (forward, init_caches, init_model,  # noqa: F401
-                                      loss_fn)
+                                      init_serving_params, loss_fn)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from repro import attention as ATT
 from repro.attention.xla import quantize_to_int8
 from repro.launch import hints
-from repro.models.layers import _normal, rope
+from repro.models.layers import _normal, dense, rope
 
 
 def init_attention(key, cfg, cross: bool = False):
@@ -115,7 +115,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
               "swa": cfg.window}[kind]
     causal = not cross and cfg.causal
 
-    q = x @ params["wq"].astype(dt)
+    q = dense(x, params["wq"].astype(dt))
     if cfg.qkv_bias:
         q = q + params["bq"].astype(dt)
     q = _split_heads(q, h, hd)
@@ -124,8 +124,8 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
     if cross and cache is not None and "k8" in cache and mode == "decode":
         k = v = None                               # static cross KV cached
     else:
-        k = kv_src @ params["wk"].astype(dt)
-        v = kv_src @ params["wv"].astype(dt)
+        k = dense(kv_src, params["wk"].astype(dt))
+        v = dense(kv_src, params["wv"].astype(dt))
         if cfg.qkv_bias:
             k = k + params["bk"].astype(dt)
             v = v + params["bv"].astype(dt)
@@ -220,6 +220,6 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
                     q_offset=new_cache.q_offset(s_new),
                     kv_len=new_cache.valid_len())
 
-    y = y.reshape(*y.shape[:-2], h * hd) @ params["wo"].astype(dt)
+    y = dense(y.reshape(*y.shape[:-2], h * hd), params["wo"].astype(dt))
     y = hints.constrain(y, "batch", "seq", None)
     return y, new_cache

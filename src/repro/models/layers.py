@@ -4,6 +4,8 @@ functions driven by a threaded PRNG key."""
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,20 @@ import numpy as np
 
 def _normal(key, shape, scale, dtype=jnp.float32):
     return scale * jax.random.normal(key, shape, dtype)
+
+
+def dense(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` whose every row comes out the same however many rows
+    share the call. XLA's TPU backend lowers a one-row matmul to a vector
+    multiply-reduce instead of the MXU, which sums in another order, so a
+    lone row is padded to one sublane tile (8 rows). Without this, greedy
+    serving (a batch of slots) drifts from solo ``generate()`` (one row
+    per decode step) in bf16."""
+    rows = math.prod(x.shape[:-1])
+    if rows != 1:
+        return x @ w
+    y = jnp.pad(x.reshape(1, -1), ((0, 7), (0, 0))) @ w
+    return y[:1].reshape(*x.shape[:-1], w.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -24,14 +40,34 @@ def init_norm(key, d, norm_type="rmsnorm"):
             "bias": jnp.zeros((d,), jnp.float32)}
 
 
+def _row_mean(x: jax.Array) -> jax.Array:
+    """Mean over the last axis, summed in one fixed order: halve the row
+    while its width is even, then add the odd remainder left to right.
+    XLA's TPU backend picks a reduction's tiling, and so its summation
+    order, from the row count, so ``jnp.mean`` of the same row can round
+    differently in a 1-row decode step, a 32-token chunk and a whole
+    prompt. Elementwise adds are never reassociated, so this order holds
+    in every program — what keeps served tokens bit-identical to solo
+    ``generate()`` in bf16."""
+    n = x.shape[-1]
+    d = n
+    while d % 2 == 0 and d > 1:
+        d //= 2
+        x = x[..., :d] + x[..., d:]
+    total = x[..., 0]
+    for i in range(1, d):
+        total = total + x[..., i]
+    return total[..., None] / n
+
+
 def apply_norm(p, x, norm_type="rmsnorm", eps=1e-6):
     xf = x.astype(jnp.float32)
     if norm_type == "rmsnorm":
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        var = _row_mean(xf * xf)
         y = xf * jax.lax.rsqrt(var + eps) * (1.0 + p["scale"])
     else:
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
+        mu = _row_mean(xf)
+        var = _row_mean((xf - mu) ** 2)
         y = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
     return y.astype(x.dtype)
 
@@ -85,11 +121,12 @@ def apply_mlp(p, x, mlp_type="swiglu"):
     if mlp_type in ("swiglu", "geglu"):
         act = jax.nn.silu if mlp_type == "swiglu" else \
             lambda v: jax.nn.gelu(v, approximate=True)
-        h = act(x @ p["w_gate"].astype(dt)) * (x @ p["w_up"].astype(dt))
-        return h @ p["w_down"].astype(dt)
-    h = jax.nn.gelu(x @ p["w_up"].astype(dt) + p["b_up"].astype(dt),
+        h = act(dense(x, p["w_gate"].astype(dt))) \
+            * dense(x, p["w_up"].astype(dt))
+        return dense(h, p["w_down"].astype(dt))
+    h = jax.nn.gelu(dense(x, p["w_up"].astype(dt)) + p["b_up"].astype(dt),
                     approximate=True)
-    return h @ p["w_down"].astype(dt) + p["b_down"].astype(dt)
+    return dense(h, p["w_down"].astype(dt)) + p["b_down"].astype(dt)
 
 
 # ---------------------------------------------------------------------------
@@ -110,9 +147,9 @@ def embed(p, ids, dtype):
 
 def unembed(p, x, softcap=0.0):
     if "unembed" in p:
-        logits = x @ p["unembed"].astype(x.dtype)
+        logits = dense(x, p["unembed"].astype(x.dtype))
     else:
-        logits = x @ p["table"].T.astype(x.dtype)
+        logits = dense(x, p["table"].T.astype(x.dtype))
     logits = logits.astype(jnp.float32)
     if softcap > 0:
         logits = jnp.tanh(logits / softcap) * softcap

@@ -228,12 +228,16 @@ def init_group_cache(cfg, pattern, n_periods, batch, max_len, paged=False,
 
 def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
                 lengths=None, live=None, q_lens=None):
-    """Scan the group over its periods. Returns (x, new_caches, aux_sum)."""
+    """Scan the group over its periods. Returns (x, new_caches, aux_sum).
 
-    def body(carry, xs):
-        xc, aux = carry
+    Caches ride the scan *carry*, each period reading its slice and
+    writing it back in place (``dynamic_update_index_in_dim``): as scan
+    ``xs -> ys`` they would be a second full copy of every layer's KV
+    (``ys`` cannot alias ``xs``), which a paged pool at full width cannot
+    afford on one chip."""
+
+    def blocks(xc, aux, pparams, pcache):
         xc = hints.constrain(xc, "batch", "seq", None)   # seq-parallel
-        pparams, pcache = xs
         new_caches = []
         for i, kind in enumerate(pattern):
             blk_cache = None if pcache is None else pcache[i]
@@ -244,13 +248,7 @@ def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
                                     q_lens=q_lens)
             new_caches.append(nc)
             aux = aux + a
-        ys = None if pcache is None else tuple(new_caches)
-        return (xc, aux), ys
-
-    if cfg.remat and mode == "train":
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots" else None)
-        body = jax.checkpoint(body, prevent_cse=False, policy=policy)
+        return xc, aux, tuple(new_caches)
 
     # scan_unroll: full unroll (scan semantics preserved) — used by the
     # dry-run so XLA cost analysis sees every layer (HloCostAnalysis does
@@ -258,14 +256,33 @@ def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
     # cross-layer collective pipelining.
     n_periods = jax.tree.leaves(params)[0].shape[0]
     unroll = n_periods if getattr(cfg, "scan_unroll", False) else 1
-
     aux0 = jnp.zeros((), jnp.float32)
+
     if caches is None:
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), (params, None),
-                                   unroll=unroll)
+        def body(carry, pparams):
+            xc, aux, _ = blocks(*carry, pparams, None)
+            return (xc, aux), None
+
+        if cfg.remat and mode == "train":
+            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                      if cfg.remat_policy == "dots" else None)
+            body = jax.checkpoint(body, prevent_cse=False, policy=policy)
+        (x, aux), _ = jax.lax.scan(body, (x, aux0), params, unroll=unroll)
         return x, None, aux
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), (params, caches),
-                                        unroll=unroll)
+
+    def body_cached(carry, xs):
+        xc, aux, all_caches = carry
+        pparams, i = xs
+        pcache = jax.tree.map(lambda c: c[i], all_caches)
+        xc, aux, new = blocks(xc, aux, pparams, pcache)
+        all_caches = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+            all_caches, new)
+        return (xc, aux, all_caches), None
+
+    (x, aux, new_caches), _ = jax.lax.scan(
+        body_cached, (x, aux0, caches),
+        (params, jnp.arange(n_periods, dtype=jnp.int32)), unroll=unroll)
     return x, new_caches, aux
 
 
@@ -296,6 +313,17 @@ def init_model(key, cfg):
     if cfg.param_dtype == "bfloat16":
         p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
     return p
+
+
+def init_serving_params(key, cfg):
+    """``init_model``'s weights for inference, created directly in the
+    config's compute dtype under one ``jit``: the float32 tree is never
+    materialised (phi3-mini-3.8b: 7.6 GB of bf16 on a 16 GB chip, where
+    the eager f32 tree alone would take 15.3 GB). Same values as casting
+    ``init_model(key, cfg)`` — the PRNG draws are identical under jit."""
+    dt = cfg.compute_dtype()
+    return jax.jit(lambda k: jax.tree.map(lambda a: a.astype(dt),
+                                          init_model(k, cfg)))(key)
 
 
 def _encode(params, cfg, frontend, mode):

@@ -52,14 +52,23 @@ import numpy as np
 
 LOOPS = ("fused", "stepwise")
 
+# Every jit that runs the model. XLA may keep a bf16 value at f32 inside
+# one fusion and round it in another ("excess precision"); which it does
+# depends on the program, so a served token could round unlike the same
+# token generated alone. Off, every bf16 rounding the model states
+# happens in every program.
+MODEL_JIT_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 @functools.lru_cache(maxsize=32)
 def _steps(cfg):
     """Jitted prefill/decode steps, cached per (hashable, frozen) config so
     repeated generate() calls reuse compilations."""
     from repro.launch.steps import make_decode_step, make_prefill_step
-    prefill = jax.jit(make_prefill_step(cfg))
-    decode = jax.jit(make_decode_step(cfg), donate_argnums=(2,))
+    prefill = jax.jit(make_prefill_step(cfg),
+                      compiler_options=MODEL_JIT_OPTIONS)
+    decode = jax.jit(make_decode_step(cfg), donate_argnums=(2,),
+                     compiler_options=MODEL_JIT_OPTIONS)
     return prefill, decode
 
 
@@ -71,7 +80,8 @@ def _gen_loop(cfg, gen, sample, eos_id, pad_id, early_exit):
     from repro.launch.steps import make_generate_loop
     loop = make_generate_loop(cfg, gen=gen, sample=sample, eos_id=eos_id,
                               pad_id=pad_id, early_exit=early_exit)
-    return jax.jit(loop, donate_argnums=(2,))
+    return jax.jit(loop, donate_argnums=(2,),
+                   compiler_options=MODEL_JIT_OPTIONS)
 
 
 @dataclasses.dataclass
@@ -100,7 +110,7 @@ def _first_paged(caches):
 
 def _paged_geometry(paged):
     """(batch, num_pages, page_size) of a period-stacked PagedKVState."""
-    return (paged.page_table.shape[1], paged.k.shape[1], paged.k.shape[2])
+    return (paged.page_table.shape[1], paged.k.shape[1], paged.k.shape[3])
 
 
 def _validate_pool_provision(caches, batch: int, tokens_per_seq: int):
@@ -113,7 +123,7 @@ def _validate_pool_provision(caches, batch: int, tokens_per_seq: int):
             caches, is_leaf=lambda x: isinstance(x, PagedKVState)):
         if not isinstance(node, PagedKVState):
             continue
-        num_pages, page = node.k.shape[1], node.k.shape[2]
+        num_pages, page = node.k.shape[1], node.k.shape[3]
         npps = node.page_table.shape[2]
         per_seq = min(-(-min(tokens_per_seq, npps * page) // page), npps)
         if batch * per_seq > num_pages - 1:
@@ -445,7 +455,8 @@ def _serve_segment_fn(cfg, segment, sample, eos_id, pad_id, chunk=None,
     seg = make_serve_segment(cfg, segment=segment, sample=sample,
                              eos_id=eos_id, pad_id=pad_id, chunk=chunk,
                              budget=budget, mixed_steps=mixed_steps)
-    return jax.jit(seg, donate_argnums=(1, 2))
+    return jax.jit(seg, donate_argnums=(1, 2),
+                   compiler_options=MODEL_JIT_OPTIONS)
 
 
 def _is_kv_state(x):
@@ -930,7 +941,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
             raise ValueError(
                 "prefix_sharing requires admission='chunked' (stall-mode "
                 "prefill bypasses the page-native write path)")
-        geos = {(n.k.shape[1], n.k.shape[2], n.page_table.shape[2])
+        geos = {(n.k.shape[1], n.k.shape[3], n.page_table.shape[2])
                 for n in jax.tree.leaves(caches, is_leaf=_is_kv_state)
                 if isinstance(n, PagedKVState)}
         if len(geos) > 1:

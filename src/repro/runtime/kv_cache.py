@@ -11,9 +11,9 @@ the typed states in ``repro.attention.state``; this module adds the
 *engine*: per-head symmetric quantization of the KV stream and the
 prefill/decode attend steps, dispatched through the attention backend
 registry (layout capabilities select the fused Pallas kernels — the
-decode step consumes ring buffers cache-natively via ``bhsd_bsgd`` and
-paged pools via ``bhsd_paged`` page-table index maps, no per-step
-transpose, broadcast or gather copies).
+decode step reads ring buffers via ``bhsd_bsgd``, relaid out head-major
+per call, and paged pools via ``bhsd_paged`` page-table index maps with
+no relayout, broadcast or gather copies).
 
 Per-head scales are finer than the per-tensor QAT grid; the model path
 (``repro.models.attention``) passes the QAT per-tensor scales instead, so
@@ -90,19 +90,16 @@ def prefill_attend(cache: KVCacheState, q_q: jax.Array, k_new: jax.Array,
     prefixes; causal masking keeps each row's valid outputs exact).
     Returns ``(out int8 at s_out, new_cache)``.
 
-    Dispatch note: the cache-native ``bhsd_bsgd`` layout + per-head
-    scales make the streaming XLA backend ineligible, so the registry
-    lands on ``ita_onepass_pallas``, which consumes the (B, S, G, D)
-    K/V buffers in place through kernel index maps — the per-call
-    ``transpose(0, 2, 1, 3)`` relayout copies this module used to make
-    are gone, capability-driven like the decode layout.
+    Dispatch note: the ``bhsd_bsgd`` layout + per-head scales make the
+    streaming XLA backend ineligible, so the registry lands on
+    ``ita_onepass_pallas``, capability-driven like the decode layout.
     """
     k_q, k_scale = quantize_per_head(k_new)
     v_q, v_scale = quantize_per_head(v_new)
     cache = cache.prefill_write(k_q, v_q, lengths=lengths) \
                  .with_scales(k_scale, v_scale)
     # Paged or ring, the *prefill attention* streams the freshly projected
-    # (B, S, G, D) tensors cache-natively — only decode re-reads the pool.
+    # (B, S, G, D) tensors — only decode re-reads the pool.
     spec = AttentionSpec(mode="prefill", impl="ita", causal=causal,
                          window=window, layout="bhsd_bsgd",
                          scale_kind="per_head", out_dtype="int8",
@@ -123,11 +120,11 @@ def decode_attend(cache: KVCacheState, q_q: jax.Array, k_new: jax.Array,
     Appends the new token's K/V (quantized onto the cache's standing
     per-head scales — the scales are frozen after prefill so cached bytes
     never need rescaling) and attends the single query over the valid
-    prefix via the fused decode-shaped kernel, consuming the ring buffers
-    cache-natively (``bhsd_bsgd`` layout — no per-step transpose or head
-    broadcast). The cache's per-sequence ``q_offset``/``valid_len``
-    vectors ride into the kernel's per-row meta, so a ragged batch
-    (mixed prompt lengths) decodes in this one call. ``q_q``
+    prefix via the fused decode-shaped kernel (``bhsd_bsgd`` ring or
+    ``bhsd_paged`` pool layout). The cache's per-sequence
+    ``q_offset``/``valid_len`` vectors ride into the kernel's per-row
+    meta, so a ragged batch (mixed prompt lengths) decodes in this one
+    call. ``q_q``
     (B, Hq, 1, D) int8; ``k_new``/``v_new`` (B, 1, G, D) float. Returns
     ``(out, new_cache)``.
     """

@@ -1,0 +1,114 @@
+"""TPU v5e compile rehearsals of the serve path's attention kernels.
+
+Each case lowers and compiles one fused kernel call for a *described*
+v5e chip (no chip attached): the compiler refuses what the Pallas TPU
+lowering cannot tile — blocks off the (8, 128) grid, unsupported matmul
+operand types, too much VMEM — all of which interpret mode accepts.
+Shapes are phi3-mini-3.8b's (32 heads of 96, page 128, 8 slots of 2048
+tokens) plus one GQA shape at head_dim 128 (qwen2-7b's 28/4 heads).
+
+The topology is described only inside a fixture: libtpu may be loaded by
+one process at a time, so nothing here touches it while modules are
+imported or tests collected.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ita_attention.ops import fused_attention
+
+SLOTS, PAGE, PAGES_PER_SEQ, CHUNK = 8, 128, 16, 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one — keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kind,hq,hkv,d,sq,ragged", [
+    pytest.param("decode", 32, 32, 96, 1, False, id="decode-paged-phi3"),
+    pytest.param("onepass", 32, 32, 96, CHUNK, True,
+                 id="ragged-onepass-paged-phi3"),
+    pytest.param("decode", 28, 4, 128, 1, False, id="decode-paged-gqa-hd128"),
+])
+def test_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind,
+                                       hq, hkv, d, sq, ragged):
+    pages = SLOTS * PAGES_PER_SEQ + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, k, v, page_table, kv_len, q_offset, q_lens):
+        return fused_attention(q, k, v, 0.05, 0.05, 0.05, 0.02,
+                               q_offset=q_offset, kv_len=kv_len,
+                               q_lens=q_lens if ragged else None, kind=kind,
+                               page_table=page_table, interpret=False)
+
+    pool = sds((pages, hkv, PAGE, d), jnp.int8)
+    rows = sds((SLOTS,), jnp.int32)
+    compiled = jax.jit(call).lower(
+        sds((SLOTS, hq, sq, d), jnp.int8), pool, pool,
+        sds((SLOTS, PAGES_PER_SEQ), jnp.int32), rows, rows, rows).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the device layout pads head_dim to the 128-lane tile
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes == SLOTS * hq * sq * max(d, 128)
+
+
+def test_one_row_dense_runs_on_the_mxu(one_chip, no_persistent_cache):
+    """A one-row ``x @ w`` lowers to a vector multiply-reduce on the TPU,
+    which sums in another order than the MXU path every larger row count
+    takes; ``models.layers.dense`` pads it so decode at batch 1 (solo
+    ``generate()``) rounds like decode at batch 8 (serving)."""
+    from repro.models.layers import dense
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    x, w = sds((1, 1, 3072)), sds((3072, 8192))
+    bare = jax.jit(lambda a, b: a @ b).lower(x, w).compile().as_text()
+    padded = jax.jit(dense).lower(x, w).compile().as_text()
+    assert " convolution(" not in bare
+    assert " convolution(" in padded
+
+
+def test_norm_sums_its_row_without_a_reduce(one_chip, no_persistent_cache):
+    """RMSNorm's sum of squares is elementwise adds in one order, so the
+    v5e compiler has no lane reduction whose tiling (and so summation
+    order) it could choose per row count: the norm rounds alike in a
+    decode step, a prompt chunk and a whole prompt."""
+    from repro.models.layers import apply_norm
+
+    p = {"scale": jax.ShapeDtypeStruct((3072,), jnp.float32,
+                                       sharding=one_chip)}
+    for rows in (1, 32, 909):
+        x = jax.ShapeDtypeStruct((rows, 3072), jnp.bfloat16,
+                                 sharding=one_chip)
+        text = jax.jit(apply_norm).lower(p, x).compile().as_text()
+        sums = [line for line in text.splitlines()
+                if " reduce(" in line and "reduce_sum" in line]
+        assert not sums, sums

@@ -144,3 +144,34 @@ def test_swa_ring_buffer_long_decode():
                        caches=caches, pos0=s - 1)
     np.testing.assert_allclose(np.asarray(ld[:, 0]), np.asarray(full[:, -1]),
                                atol=2e-3)
+
+
+def test_init_serving_params_is_the_cast_init_model():
+    """Serving weights are created in the compute dtype under one jit —
+    the same values as casting the f32 ``init_model`` tree."""
+    from repro.models import init_serving_params
+    cfg = get_config("phi3-mini-3.8b", smoke=True, dtype="bfloat16")
+    got = init_serving_params(KEY, cfg)
+    want = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                        init_model(KEY, cfg))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("width", [3072, 96, 1280, 7])
+def test_row_mean_is_the_mean_summed_in_one_order(width):
+    """``_row_mean`` (the norms' fixed-order sum) is the mean to f32
+    rounding, and a row's value does not depend on the rows beside it."""
+    from repro.models.layers import _row_mean, apply_norm
+    x = jax.random.normal(KEY, (9, width), jnp.float32) * 3.0
+    np.testing.assert_allclose(np.asarray(_row_mean(x))[:, 0],
+                               np.asarray(x).mean(-1), rtol=1e-5, atol=1e-6)
+    p = {"scale": jnp.zeros((width,), jnp.float32)}
+    xb = x.astype(jnp.bfloat16)
+    alone = jax.jit(apply_norm)(p, xb[3:4])
+    np.testing.assert_array_equal(np.asarray(alone, np.float32),
+                                  np.asarray(jax.jit(apply_norm)(p, xb)[3:4],
+                                             np.float32))

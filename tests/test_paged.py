@@ -1,7 +1,7 @@
 """Paged KV pool: ring-equivalence, allocator correctness, kernel parity.
 
 The acceptance property (ISSUE 4): the paged decode path — one shared
-``(num_pages, page_size, G, hd)`` arena consumed through page-table
+``(num_pages, G, page_size, hd)`` arena consumed through page-table
 index maps — is **bit-identical** to the contiguous ring path on the
 ``s_out`` output grid, across every backend that serves the paged spec
 (the ``ita_fused`` family invariant extended to the ``bhsd_paged``
@@ -37,12 +37,14 @@ def _paged_from_logical(k_log, v_log, page, *, shuffle_seed=1):
     perm = np.random.default_rng(shuffle_seed).permutation(
         np.arange(1, total))
     pt = perm.reshape(b, npps).astype(np.int32)
-    k_pool = np.zeros((total, page, g, hd), np.int8)
-    v_pool = np.zeros((total, page, g, hd), np.int8)
+    k_pool = np.zeros((total, g, page, hd), np.int8)
+    v_pool = np.zeros((total, g, page, hd), np.int8)
     for bb in range(b):
         for j in range(npps):
-            k_pool[pt[bb, j]] = k_log[bb, j * page:(j + 1) * page]
-            v_pool[pt[bb, j]] = v_log[bb, j * page:(j + 1) * page]
+            k_pool[pt[bb, j]] = k_log[bb, j * page:(j + 1) * page].swapaxes(
+                0, 1)
+            v_pool[pt[bb, j]] = v_log[bb, j * page:(j + 1) * page].swapaxes(
+                0, 1)
     return k_pool, v_pool, pt
 
 
@@ -115,7 +117,7 @@ def test_paged_layout_capability_matrix():
         if name not in ("ita_decode_pallas", "ita_onepass_pallas"):
             assert isinstance(verdict, str) and verdict, name
     q = jnp.asarray(_i8(1, 2, 1, 32))
-    pool = jnp.asarray(_i8(3, 64, 2, 32))
+    pool = jnp.asarray(_i8(3, 2, 64, 32))
     sc = ATT.QuantScales.per_tensor(S_Q, s_out=S_OUT)
     with pytest.raises(ValueError, match="page_table"):
         ATT.dispatch(q, pool, pool, spec=spec, scales=sc)
@@ -130,8 +132,10 @@ def test_paged_layout_capability_matrix():
 
 def _logical_view(p: PagedKVState):
     pt = np.asarray(p.page_table)
-    g, hd = p.k.shape[2], p.k.shape[3]
-    return np.asarray(p.k)[pt].reshape(p.batch, p.capacity, g, hd)
+    g, hd = p.k.shape[1], p.k.shape[3]
+    # (B, n_pages, G, page, hd) -> (B, n_pages * page, G, hd)
+    return np.asarray(p.k)[pt].swapaxes(2, 3).reshape(p.batch, p.capacity,
+                                                      g, hd)
 
 
 def test_paged_state_matches_ring_through_wrap():

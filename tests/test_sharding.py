@@ -69,3 +69,30 @@ def test_input_specs_shapes():
     # KV cache leaves sized to the 32k context
     kv = [l for l in jax.tree.leaves(sp["caches"]) if l.ndim == 5]
     assert all(l.shape[2] == 32768 for l in kv)
+
+
+def test_host_mesh_spans_the_devices_present():
+    """Serving builds its mesh from the devices present (one chip serves
+    phi3-mini whole), with Auto axes so the layers' sharding hints
+    apply."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.devices.size == N_DEV
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+def test_compile_cache_dir_from_env_else_checkout(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = str(compile_cache.CHECKOUT / ".jax_cache")
+        assert compile_cache.use_compile_cache() == fixed
+        assert (compile_cache.CHECKOUT / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
