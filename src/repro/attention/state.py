@@ -49,6 +49,7 @@ ring path whether or not pages are shared.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -588,6 +589,7 @@ class PagedKVState:
         pos = self.pos.at[rows].set(new_pos, mode="drop")
         return dataclasses.replace(new, k=k_t, v=v_t, pos=pos)
 
+    @functools.partial(jax.named_call, name="kv_write")
     def decode_append(self, k_q: jax.Array, v_q: jax.Array,
                       live: jax.Array | None = None) -> "PagedKVState":
         """Append ``s_new`` decode tokens per sequence — the jit-safe hot
@@ -624,6 +626,7 @@ class PagedKVState:
         return dataclasses.replace(new, k=k_t, v=v_t,
                                    pos=state.pos + s_new * live_i)
 
+    @functools.partial(jax.named_call, name="kv_write")
     def append_chunk(self, k_q: jax.Array, v_q: jax.Array,
                      n_new: jax.Array) -> "PagedKVState":
         """Append a *per-row ragged* chunk: row ``b`` writes its first

@@ -99,6 +99,7 @@ def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, bias: jax.Array,
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
             interpret=interpret,
+            name="int8_matmul",
         )(x_q, w_q, bias2, mult2)
 
     assert schedule == "weight_stationary", schedule
@@ -128,5 +129,6 @@ def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, bias: jax.Array,
                        jax.ShapeDtypeStruct((m, n), jnp.int8)],
             input_output_aliases={4: 0},
             interpret=interpret,
+            name="int8_matmul_ws",
         )(x_sl, w_sl, bias2, mult2, psum)
     return out_q

@@ -302,6 +302,7 @@ def ita_attention_onepass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                         pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="ita_onepass",
     )(q_q, k_q, v_q, lmult, omult, meta)
 
 
@@ -339,6 +340,7 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32)],
         interpret=interpret,
+        name="ita_twopass_qk",
     )(q_q, k_q, lmult, meta)
 
     # DI — one integer inversion per row (two serial dividers in silicon,
@@ -360,6 +362,7 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="ita_twopass_av",
     )(a_mat, sigma_inv, e_r, row_max, v_q, omult, meta)
     return out, a_mat
 
@@ -399,6 +402,7 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                         pltpu.VMEM((sq, 1), jnp.int32),
                         pltpu.VMEM((sq, d), jnp.float32)],
         interpret=interpret,
+        name="ita_decode",
     )(q_q, k_q, v_q, lmult, omult, meta)
 
 
@@ -428,10 +432,10 @@ def _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis):
     return pl.BlockSpec((1, 1, page, d), page_of)
 
 
-def _paged_call(kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
+def _paged_call(name, kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
                 page_table, lmult, omult, meta, bq, interpret):
-    """Shared pallas_call of the paged kernels: the flat page table is
-    the scalar-prefetch operand the K/V index maps read."""
+    """Shared pallas_call of the paged kernels, named ``name``: the flat
+    page table is the scalar-prefetch operand the K/V index maps read."""
     bh, sq, d = q_q.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -446,6 +450,7 @@ def _paged_call(kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         interpret=interpret,
+        name=name,
     )(page_table.reshape(-1), q_q, k_pool, v_pool, lmult, omult, meta)
 
 
@@ -479,7 +484,7 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     return _paged_call(
-        kern, (bh, n_pages),
+        "ita_decode_paged", kern, (bh, n_pages),
         pl.BlockSpec((1, sq, d), lambda b, j, pt: (b, 0, 0)),
         _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=False),
         q_q, k_pool, v_pool, page_table, lmult, omult, meta, sq, interpret)
@@ -510,7 +515,7 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     return _paged_call(
-        kern, (bh, sq // bq, n_pages),
+        "ita_onepass_paged", kern, (bh, sq // bq, n_pages),
         pl.BlockSpec((1, bq, d), lambda b, i, j, pt: (b, i, 0)),
         _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=True),
         q_q, k_pool, v_pool, page_table, lmult, omult, meta, bq, interpret)

@@ -85,4 +85,5 @@ def ita_softmax_pallas(x_q: jax.Array, mask: jax.Array, *, block_r: int = 128,
                         pltpu.VMEM((br, 1), jnp.int32),
                         pltpu.VMEM((br, 1), jnp.int32)],
         interpret=interpret,
+        name="ita_softmax",
     )(x_q, mask)

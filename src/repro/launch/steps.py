@@ -280,6 +280,7 @@ def aged_priority(prio: int, waited: int, aging_steps: int | None,
 
 
 @jax.jit
+@functools.partial(jax.named_call, name="pool")
 def fold_keys(key, ids):
     """One PRNG stream per id: ``fold_in(key, ids[i])`` — request-id
     derived streams make each served request's draws a function of its
@@ -295,6 +296,7 @@ def admit_rows(state, slot_ids):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def admit_chunked(state, slot_ids, prompts, lengths, gens, req_keys,
                   shared=None, prios=None):
     """Chunked admission is *only* this state write (plus the host's page
@@ -327,6 +329,7 @@ def admit_chunked(state, slot_ids, prompts, lengths, gens, req_keys,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
                 req_keys, prios=None):
     """Stall-mode admission state write, after the stop-the-world prefill
@@ -348,6 +351,7 @@ def admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def preempt_rows(state, mask):
     """One-dispatch victim release: evict every slot in ``mask`` (B,)
     bool from the batch. The victims' phase state zeroes and ``done``
@@ -459,6 +463,15 @@ def make_serve_segment(cfg, *, segment: int, sample: bool,
     ``grants`` records per-slot granted token counts (the budget
     invariant ``sum(grants[:, t]) <= budget`` is property-tested). Jit
     with ``donate_argnums=(1, 2)``.
+
+    Device work is named for profiler traces (``jax.named_scope``, in
+    each op's ``op_name``): the whole call is ``serve_segment``; each
+    phase, ``mixed_phase`` and ``decode_phase``, lowers to a ``while``
+    whose ``op_name`` ends in ``<phase>/while`` (a phase of one step may
+    be compiled without a loop). Inside a step: ``grant`` (the mixed
+    budget and token block), ``embed``, ``attn_qkv``, ``kv_write``,
+    ``attn_kernel``, ``attn_out``, ``mlp``, ``layer_carry`` (each
+    layer's cache slice and write-back), ``head`` and ``sample``.
     """
     decode = make_decode_step(cfg)
     if chunk is not None:
@@ -473,82 +486,91 @@ def make_serve_segment(cfg, *, segment: int, sample: bool,
         live = ~st.done & (st.cursor >= st.plen)
         logits, caches = decode(params, st.tok, caches, st.pos, frontend,
                                 live)
-        nxt, keys, done, rem, n = advance_step_rows(
-            logits, st.keys, temperature, st.done, st.rem, n, live,
-            sample=sample, eos_id=eos_id, pad_id=pad_id)
-        pos = st.pos + live.astype(jnp.int32)
-        st = dataclasses.replace(
-            st, tok=jnp.where(live[:, None], nxt, st.tok), pos=pos,
-            keys=keys, done=done, rem=rem)
+        with jax.named_scope("sample"):
+            nxt, keys, done, rem, n = advance_step_rows(
+                logits, st.keys, temperature, st.done, st.rem, n, live,
+                sample=sample, eos_id=eos_id, pad_id=pad_id)
+            pos = st.pos + live.astype(jnp.int32)
+            st = dataclasses.replace(
+                st, tok=jnp.where(live[:, None], nxt, st.tok), pos=pos,
+                keys=keys, done=done, rem=rem)
         return (caches, st, n), (nxt[:, 0], live, live.astype(jnp.int32))
 
     def mixed_body(params, frontend, temperature, carry, _):
         caches, st, n = carry
-        live = ~st.done
-        prefilling = live & (st.cursor < st.plen)
-        decoding = live & (st.cursor >= st.plen)
-        # decode-maximal budget: decode slots first, prompt chunks fill
-        # the leftover greedily in priority order (stable argsort — equal
-        # priorities keep slot order, so an all-class-0 batch grants
-        # exactly as before)
-        want = jnp.where(prefilling,
-                         jnp.minimum(chunk, st.plen - st.cursor), 0)
-        order = jnp.argsort(-st.prio, stable=True)
-        want_o = want[order]
-        cum_o = jnp.cumsum(want_o) - want_o              # exclusive
-        left = budget - jnp.sum(decoding.astype(jnp.int32))
-        grant = jnp.zeros_like(want).at[order].set(
-            jnp.clip(left - cum_o, 0, want_o))
-        n_new = grant + decoding.astype(jnp.int32)
-        # token block: prompt chunk at the cursor, or [tok, pad...]
-        cols = st.cursor[:, None] + jnp.arange(chunk, dtype=jnp.int32)
-        ptoks = jnp.take_along_axis(
-            st.prompt_buf, jnp.clip(cols, 0, st.prompt_buf.shape[1] - 1),
-            axis=1)
-        first = jnp.arange(chunk, dtype=jnp.int32)[None, :] == 0
-        tokens = jnp.where(prefilling[:, None], ptoks,
-                           jnp.where(first, st.tok, pad_id))
+        with jax.named_scope("grant"):
+            live = ~st.done
+            prefilling = live & (st.cursor < st.plen)
+            decoding = live & (st.cursor >= st.plen)
+            # decode-maximal budget: decode slots first, prompt chunks
+            # fill the leftover greedily in priority order (stable argsort
+            # — equal priorities keep slot order, so an all-class-0 batch
+            # grants exactly as before)
+            want = jnp.where(prefilling,
+                             jnp.minimum(chunk, st.plen - st.cursor), 0)
+            order = jnp.argsort(-st.prio, stable=True)
+            want_o = want[order]
+            cum_o = jnp.cumsum(want_o) - want_o          # exclusive
+            left = budget - jnp.sum(decoding.astype(jnp.int32))
+            grant = jnp.zeros_like(want).at[order].set(
+                jnp.clip(left - cum_o, 0, want_o))
+            n_new = grant + decoding.astype(jnp.int32)
+            # token block: prompt chunk at the cursor, or [tok, pad...]
+            cols = st.cursor[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+            ptoks = jnp.take_along_axis(
+                st.prompt_buf,
+                jnp.clip(cols, 0, st.prompt_buf.shape[1] - 1), axis=1)
+            first = jnp.arange(chunk, dtype=jnp.int32)[None, :] == 0
+            tokens = jnp.where(prefilling[:, None], ptoks,
+                               jnp.where(first, st.tok, pad_id))
         x, caches, _ = forward(params, tokens, cfg, mode="decode",
                                frontend=frontend, caches=caches,
                                pos0=st.pos, q_lens=n_new, skip_unembed=True)
-        # next-token logits sit at each row's last granted column; only
-        # that (B, 1, d) slice is unembedded — mid-prompt rows discard it
-        sel = jnp.take_along_axis(
-            x, jnp.maximum(n_new - 1, 0)[:, None, None], axis=1)
-        logits = unembed(params["embed"], sel, cfg.logit_softcap)
-        completes = prefilling & (st.cursor + n_new >= st.plen)
-        emits = decoding | completes
-        nxt, keys, done, rem, n = advance_step_rows(
-            logits, st.keys, temperature, st.done, st.rem, n, emits,
-            sample=sample, eos_id=eos_id, pad_id=pad_id)
-        st = dataclasses.replace(
-            st, tok=jnp.where(emits[:, None], nxt, st.tok),
-            pos=st.pos + n_new, keys=keys, done=done, rem=rem,
-            cursor=st.cursor + jnp.where(prefilling, n_new, 0))
+        with jax.named_scope("head"):
+            # next-token logits sit at each row's last granted column;
+            # only that (B, 1, d) slice is unembedded — mid-prompt rows
+            # discard it
+            sel = jnp.take_along_axis(
+                x, jnp.maximum(n_new - 1, 0)[:, None, None], axis=1)
+            logits = unembed(params["embed"], sel, cfg.logit_softcap)
+        with jax.named_scope("sample"):
+            completes = prefilling & (st.cursor + n_new >= st.plen)
+            emits = decoding | completes
+            nxt, keys, done, rem, n = advance_step_rows(
+                logits, st.keys, temperature, st.done, st.rem, n, emits,
+                sample=sample, eos_id=eos_id, pad_id=pad_id)
+            st = dataclasses.replace(
+                st, tok=jnp.where(emits[:, None], nxt, st.tok),
+                pos=st.pos + n_new, keys=keys, done=done, rem=rem,
+                cursor=st.cursor + jnp.where(prefilling, n_new, 0))
         return (caches, st, n), (nxt[:, 0], emits, n_new)
 
     k = 0 if chunk is None else \
         (segment if mixed_steps is None else min(mixed_steps, segment))
 
     def seg(params, state, caches, temperature, frontend=None):
-        carry = (caches, state, jnp.zeros((), jnp.int32))
-        outs = []
-        if k > 0:
-            carry, out = jax.lax.scan(
-                functools.partial(mixed_body, params, frontend,
-                                  temperature), carry, None, length=k)
-            outs.append(out)
-        if k < segment:
-            carry, out = jax.lax.scan(
-                functools.partial(decode_body, params, frontend,
-                                  temperature), carry, None,
-                length=segment - k)
-            outs.append(out)
-        caches, state, n = carry
-        toks, emits, grants = (
-            jnp.concatenate(parts, axis=0) if len(outs) > 1 else parts[0]
-            for parts in zip(*outs, strict=True))
-        return toks.T, emits.T, grants.T, state, caches, n
+        with jax.named_scope("serve_segment"):
+            carry = (caches, state, jnp.zeros((), jnp.int32))
+            outs = []
+            if k > 0:
+                with jax.named_scope("mixed_phase"):
+                    carry, out = jax.lax.scan(
+                        functools.partial(mixed_body, params, frontend,
+                                          temperature), carry, None,
+                        length=k)
+                outs.append(out)
+            if k < segment:
+                with jax.named_scope("decode_phase"):
+                    carry, out = jax.lax.scan(
+                        functools.partial(decode_body, params, frontend,
+                                          temperature), carry, None,
+                        length=segment - k)
+                outs.append(out)
+            caches, state, n = carry
+            toks, emits, grants = (
+                jnp.concatenate(parts, axis=0) if len(outs) > 1
+                else parts[0] for parts in zip(*outs, strict=True))
+            return toks.T, emits.T, grants.T, state, caches, n
 
     return seg
 

@@ -115,39 +115,49 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
               "swa": cfg.window}[kind]
     causal = not cross and cfg.causal
 
-    q = dense(x, params["wq"].astype(dt))
-    if cfg.qkv_bias:
-        q = q + params["bq"].astype(dt)
-    q = _split_heads(q, h, hd)
-
-    kv_src = mem if cross else x
-    if cross and cache is not None and "k8" in cache and mode == "decode":
-        k = v = None                               # static cross KV cached
-    else:
-        k = dense(kv_src, params["wk"].astype(dt))
-        v = dense(kv_src, params["wv"].astype(dt))
-        if cfg.qkv_bias:
-            k = k + params["bk"].astype(dt)
-            v = v + params["bv"].astype(dt)
-        k, v = _split_heads(k, g, hd), _split_heads(v, g, hd)
-
-    if positions is not None and not cross and cfg.rope_theta > 0:
-        q = rope(q, positions, cfg.rope_theta)
-        if k is not None:
-            k = rope(k, positions, cfg.rope_theta)
-
-    # TP hints: heads over 'model' when divisible, else sequence-parallel
-    # attention (Sq over 'model'); KV heads likewise (replicated if small).
-    if hints.heads_shardable(h):
-        q = hints.constrain(q, "batch", None, "heads", None)
-    else:
-        q = hints.constrain(q, "batch", "seq", None, None)
-    if k is not None:
-        k = hints.constrain(k, "batch", None, "kv_heads", None)
-        v = hints.constrain(v, "batch", None, "kv_heads", None)
-
     scales = ATT.QuantScales.from_params(params)
     quant_cache = cfg.attention_impl != "float"
+
+    def _q(t, s):
+        return quantize_to_int8(t, params[s]) if quant_cache else t
+
+    with jax.named_scope("attn_qkv"):
+        q = dense(x, params["wq"].astype(dt))
+        if cfg.qkv_bias:
+            q = q + params["bq"].astype(dt)
+        q = _split_heads(q, h, hd)
+
+        kv_src = mem if cross else x
+        if cross and cache is not None and "k8" in cache \
+                and mode == "decode":
+            k = v = None                           # static cross KV cached
+        else:
+            k = dense(kv_src, params["wk"].astype(dt))
+            v = dense(kv_src, params["wv"].astype(dt))
+            if cfg.qkv_bias:
+                k = k + params["bk"].astype(dt)
+                v = v + params["bv"].astype(dt)
+            k, v = _split_heads(k, g, hd), _split_heads(v, g, hd)
+
+        if positions is not None and not cross and cfg.rope_theta > 0:
+            q = rope(q, positions, cfg.rope_theta)
+            if k is not None:
+                k = rope(k, positions, cfg.rope_theta)
+
+        # TP hints: heads over 'model' when divisible, else
+        # sequence-parallel attention (Sq over 'model'); KV heads likewise
+        # (replicated if small).
+        if hints.heads_shardable(h):
+            q = hints.constrain(q, "batch", None, "heads", None)
+        else:
+            q = hints.constrain(q, "batch", "seq", None, None)
+        if k is not None:
+            k = hints.constrain(k, "batch", None, "kv_heads", None)
+            v = hints.constrain(v, "batch", None, "kv_heads", None)
+        # the K/V the cache stores (int8 for the quantized impls)
+        k8 = v8 = None
+        if cache is not None and k is not None:
+            k8, v8 = _q(k, "s_k"), _q(v, "s_v")
 
     def run(qq, kk, vv, *, mode, causal=causal, window=window,
             q_offset=0, kv_len=None, layout="bshd", page_table=None,
@@ -163,31 +173,28 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
         if backend is not None \
                 and ATT.get_backend(backend).supports(spec) is not True:
             backend = None
-        out = ATT.dispatch(qq, kk, vv, spec=spec, scales=scales,
-                           q_offset=q_offset, kv_len=kv_len,
-                           page_table=page_table, q_lens=q_lens,
-                           backend=backend, q_chunk=cfg.attn_q_chunk,
-                           kv_chunk=cfg.attn_kv_chunk,
-                           scan_unroll=cfg.scan_unroll)
-        return out.astype(dt)
-
-    def _q(t, s):
-        return quantize_to_int8(t, params[s]) if quant_cache else t
+        with jax.named_scope("attn_kernel"):
+            out = ATT.dispatch(qq, kk, vv, spec=spec, scales=scales,
+                               q_offset=q_offset, kv_len=kv_len,
+                               page_table=page_table, q_lens=q_lens,
+                               backend=backend, q_chunk=cfg.attn_q_chunk,
+                               kv_chunk=cfg.attn_kv_chunk,
+                               scan_unroll=cfg.scan_unroll)
+            return out.astype(dt)
 
     new_cache = cache
     if cache is None:
         y = run(q, k, v, mode=mode)
     elif cross:
         if mode != "decode":                        # (re)compute at prefill
-            cache = dict(cache, k8=_q(k, "s_k"), v8=_q(v, "s_v"))
+            cache = dict(cache, k8=k8, v8=v8)
         new_cache = cache
         y = run(q, cache["k8"], cache["v8"], mode=mode)
     elif mode == "prefill":
         # Full in-layer attention; then write the canonical ring-buffer
         # tail (token t lives at slot t % cache_size) so decode can append.
         y = run(q, k, v, mode=mode)
-        new_cache = cache.prefill_write(_q(k, "s_k"), _q(v, "s_v"),
-                                        lengths=lengths)
+        new_cache = cache.prefill_write(k8, v8, lengths=lengths)
     elif q_lens is not None:                        # mixed chunk append
         # Chunked-prefill serve step: per-row ragged widths, K/V written
         # straight into pool pages (append_chunk), attention through the
@@ -197,7 +204,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
                 "q_lens= (mixed chunked prefill) requires paged KV caches; "
                 "ring caches serve uniform decode/prefill only")
         n_new = jnp.asarray(q_lens, jnp.int32)
-        new_cache = cache.append_chunk(_q(k, "s_k"), _q(v, "s_v"), n_new)
+        new_cache = cache.append_chunk(k8, v8, n_new)
         y = run(jnp.swapaxes(q, 1, 2), new_cache.k, new_cache.v,
                 mode=mode, q_offset=new_cache.q_offset(n_new),
                 kv_len=new_cache.valid_len(), layout="bhsd_paged",
@@ -205,8 +212,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
         y = jnp.swapaxes(y, 1, 2)
     else:                                           # decode append
         s_new = q.shape[1]
-        new_cache = cache.decode_append(_q(k, "s_k"), _q(v, "s_v"),
-                                        live=live)
+        new_cache = cache.decode_append(k8, v8, live=live)
         if isinstance(new_cache, ATT.PagedKVState):
             # paged pool: q in kernel layout, K/V = the shared arena read
             # through this layer's page table (bhsd_paged capability)
@@ -220,6 +226,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
                     q_offset=new_cache.q_offset(s_new),
                     kv_len=new_cache.valid_len())
 
-    y = dense(y.reshape(*y.shape[:-2], h * hd), params["wo"].astype(dt))
-    y = hints.constrain(y, "batch", "seq", None)
+    with jax.named_scope("attn_out"):
+        y = dense(y.reshape(*y.shape[:-2], h * hd), params["wo"].astype(dt))
+        y = hints.constrain(y, "batch", "seq", None)
     return y, new_cache

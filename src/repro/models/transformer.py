@@ -135,25 +135,29 @@ def apply_block(p, x, kind, cfg, *, positions, mem, cache, mode,
     if kind in ATTN_KINDS or kind == "cross":
         akind = {"attn": "global", "enc": "global", "local": "local",
                  "swa": "swa", "cross": "cross"}[kind]
-        h = apply_norm(p["norm1"], x, cfg.norm_type)
+        with jax.named_scope("attn_qkv"):
+            h = apply_norm(p["norm1"], x, cfg.norm_type)
         y, new_mix = A.apply_attention(p["attn"], h, cfg=cfg, kind=akind,
                                        positions=positions, mem=mem,
                                        cache=cm, mode=mode, lengths=lengths,
                                        live=live, q_lens=q_lens)
-        if kind == "cross":
-            y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
-        x = residual(y, "post_norm1")
-        h = apply_norm(p["norm2"], x, cfg.norm_type)
-        if cfg.mlp_type == "moe" and kind != "cross":
-            y = MOE.apply_moe(p["mlp"], h, cfg)
-            aux = MOE.moe_aux_loss(p["mlp"], h, cfg) if mode == "train" else aux
-        else:
-            y = apply_mlp(p["mlp"], h,
-                          "swiglu" if cfg.mlp_type in ("moe", "rwkv")
-                          else cfg.mlp_type)
-        if kind == "cross":
-            y = y * jnp.tanh(p["gate_mlp"]).astype(y.dtype)
-        x = residual(y, "post_norm2")
+        with jax.named_scope("attn_out"):
+            if kind == "cross":
+                y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
+            x = residual(y, "post_norm1")
+        with jax.named_scope("mlp"):
+            h = apply_norm(p["norm2"], x, cfg.norm_type)
+            if cfg.mlp_type == "moe" and kind != "cross":
+                y = MOE.apply_moe(p["mlp"], h, cfg)
+                aux = MOE.moe_aux_loss(p["mlp"], h, cfg) \
+                    if mode == "train" else aux
+            else:
+                y = apply_mlp(p["mlp"], h,
+                              "swiglu" if cfg.mlp_type in ("moe", "rwkv")
+                              else cfg.mlp_type)
+            if kind == "cross":
+                y = y * jnp.tanh(p["gate_mlp"]).astype(y.dtype)
+            x = residual(y, "post_norm2")
         return x, (None if cache is None else dict(cache, mix=new_mix)), aux
 
     if kind == "attn_cross":                       # whisper decoder layer
@@ -273,11 +277,13 @@ def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
     def body_cached(carry, xs):
         xc, aux, all_caches = carry
         pparams, i = xs
-        pcache = jax.tree.map(lambda c: c[i], all_caches)
+        with jax.named_scope("layer_carry"):
+            pcache = jax.tree.map(lambda c: c[i], all_caches)
         xc, aux, new = blocks(xc, aux, pparams, pcache)
-        all_caches = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
-            all_caches, new)
+        with jax.named_scope("layer_carry"):
+            all_caches = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                all_caches, new)
         return (xc, aux, all_caches), None
 
     (x, aux, new_caches), _ = jax.lax.scan(
@@ -369,27 +375,28 @@ def forward(params, tokens, cfg, *, mode="train", frontend=None, caches=None,
     through the ragged-q kernel alongside 1-token decode rows.
     """
     dt = cfg.compute_dtype()
-    x = embed(params["embed"], tokens, dt)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(np.sqrt(cfg.d_model), dt)
-    s = tokens.shape[1]
-    if pos0 is None:
-        positions = jnp.arange(s, dtype=jnp.int32)
-    else:
-        pos0 = jnp.asarray(pos0, jnp.int32)
-        # (s,) lockstep, or (B, s) per-sequence (ragged decode)
-        positions = pos0[..., None] + jnp.arange(s, dtype=jnp.int32) \
-            if pos0.ndim else pos0 + jnp.arange(s, dtype=jnp.int32)
-    if cfg.sinusoidal_pos:
-        # computed from (possibly dynamic, possibly batched) positions so
-        # decode works
-        d = cfg.d_model
-        dim = jnp.arange(0, d, 2, dtype=jnp.float32) / d
-        ang = positions[..., None].astype(jnp.float32) / (10000.0 ** dim)
-        pe = jnp.zeros(ang.shape[:-1] + (d,), jnp.float32) \
-            .at[..., 0::2].set(jnp.sin(ang)) \
-            .at[..., 1::2].set(jnp.cos(ang))
-        x = x + (pe if pe.ndim == 3 else pe[None]).astype(dt)
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], tokens, dt)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(np.sqrt(cfg.d_model), dt)
+        s = tokens.shape[1]
+        if pos0 is None:
+            positions = jnp.arange(s, dtype=jnp.int32)
+        else:
+            pos0 = jnp.asarray(pos0, jnp.int32)
+            # (s,) lockstep, or (B, s) per-sequence (ragged decode)
+            positions = pos0[..., None] + jnp.arange(s, dtype=jnp.int32) \
+                if pos0.ndim else pos0 + jnp.arange(s, dtype=jnp.int32)
+        if cfg.sinusoidal_pos:
+            # computed from (possibly dynamic, possibly batched) positions
+            # so decode works
+            d = cfg.d_model
+            dim = jnp.arange(0, d, 2, dtype=jnp.float32) / d
+            ang = positions[..., None].astype(jnp.float32) / (10000.0 ** dim)
+            pe = jnp.zeros(ang.shape[:-1] + (d,), jnp.float32) \
+                .at[..., 0::2].set(jnp.sin(ang)) \
+                .at[..., 1::2].set(jnp.cos(ang))
+            x = x + (pe if pe.ndim == 3 else pe[None]).astype(dt)
 
     mem = _encode(params, cfg, frontend, mode)
 
@@ -404,13 +411,14 @@ def forward(params, tokens, cfg, *, mode="train", frontend=None, caches=None,
         aux_total = aux_total + aux
         if new_caches is not None:
             new_caches.append(nc)
-    x = apply_norm(params["final_norm"], x, cfg.norm_type)
-    x = hints.constrain(x, "batch", None, None)
-    if skip_unembed:
-        return x, (tuple(new_caches) if new_caches is not None else None), \
-            aux_total
-    logits = unembed(params["embed"], x, cfg.logit_softcap)
-    logits = hints.constrain(logits, "batch", None, "vocab")
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm_type)
+        x = hints.constrain(x, "batch", None, None)
+        if skip_unembed:
+            return x, (tuple(new_caches) if new_caches is not None
+                       else None), aux_total
+        logits = unembed(params["embed"], x, cfg.logit_softcap)
+        logits = hints.constrain(logits, "batch", None, "vocab")
     return logits, (tuple(new_caches) if new_caches is not None else None), \
         aux_total
 

@@ -465,6 +465,7 @@ def _is_kv_state(x):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def _release_slots(caches, finished):
     """Return every finished slot's pages (all layers) to the free
     stacks."""
@@ -503,6 +504,7 @@ def _admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def _adopt_prefix_slots(caches, slot_ids, pages, n_pages, n_tokens):
     """Point freshly admitted slots' leading page-table entries at the
     shared prefix pages (every layer's pool — the allocators run in
@@ -520,6 +522,7 @@ def _adopt_prefix_slots(caches, slot_ids, pages, n_pages, n_tokens):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def _pin_pages(caches, pages):
     """+1 refcount on ``pages`` (flat, -1 padded) in every layer's pool —
     the prefix index's registration pin."""
@@ -534,6 +537,7 @@ def _pin_pages(caches, pages):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.named_call, name="pool")
 def _unpin_pages(caches, pages):
     """Drop the index pin on ``pages`` (flat, -1 padded); pages reaching
     refcount zero return to every layer's free stack."""
@@ -763,6 +767,35 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     admission interleaving and co-scheduled traffic.
     Returns ``ServeResult`` with per-request latency/TTFT and page-pool
     utilization samples.
+
+    **Tracing**: the loop writes host spans into the JAX profiler's own
+    trace (``jax.profiler.TraceAnnotation``), on the clock it puts the
+    device's ops on. Each admission round is one ``serve.round`` (args
+    ``segment``, the index of the segment it dispatches, and ``step``);
+    a round that dispatches a segment holds, in order:
+
+    - ``serve.schedule``: arrivals, candidate order, prefix lookup, LRU
+      eviction, victim choice (host only);
+    - ``serve.pool``: the round's preempt, release, unpin, adopt and
+      admit dispatches;
+    - ``serve.dispatch`` (args ``segment``, ``step``, ``mixed``: the
+      segment's chunk-wide steps, ``steps``: its length): the segment
+      call;
+    - ``serve.wait``: the host waiting for the device to finish it;
+    - ``serve.readback``: the one ``device_get`` and the per-slot token
+      bookkeeping;
+    - ``serve.register`` (with ``prefix_sharing``): page registration
+      and pins; ``serve.journal`` (with ``journal_dir``): progress,
+      flush and snapshot.
+
+    A round that finds nothing to run holds ``serve.schedule`` and
+    ``serve.pool`` only. On the device, ``make_serve_segment``'s scopes
+    and the kernels' ``pallas_call`` names mark the work in each op's
+    ``op_name``. To trace a serve, run it under
+    ``with jax.profiler.trace(trace_dir):`` and read the ``.xplane.pb``
+    written under ``trace_dir`` with ``jax.profiler.ProfileData``: the
+    spans are events of the ``/host:`` planes, their args the events'
+    stats; the ops are the ``XLA Ops`` line of each device plane.
     """
     from repro.launch.steps import ServeSlotState, aged_priority, \
         fold_keys, sample_token_rows
@@ -965,15 +998,13 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     prefill, _ = _steps(cfg)
     seg_decode = _serve_segment_fn(cfg, segment, sample, eos_id, pad_id)
 
-    def seg_mixed(n_steps):
-        # two-phase segment: chunk-wide mixed steps sized to the prompt
-        # chunks actually outstanding (rounded up to a power of two to
-        # bound compilation count), then 1-token decode steps for the
-        # rest — one dispatch, one host round-trip per `segment` steps,
-        # chunk-wide q width paid only where prefill happens
+    def seg_mixed(k):
+        # two-phase segment: `k` chunk-wide mixed steps, sized to the
+        # prompt chunks actually outstanding, then 1-token decode steps
+        # for the rest — one dispatch, one host round-trip per `segment`
+        # steps, chunk-wide q width paid only where prefill happens
         return _serve_segment_fn(
-            cfg, segment, sample, eos_id, pad_id, chunk, budget,
-            min(segment, _next_pow2(n_steps)))
+            cfg, segment, sample, eos_id, pad_id, chunk, budget, k)
 
     def pages_for(req):
         n = int(np.asarray(req.prompt).size) + req.gen
@@ -1225,352 +1256,382 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                 # timeout: stop here — in-flight progress is journaled
                 # through the last boundary, a resume picks it up
                 break
-        for i in queue:
-            if requests[i].arrival <= step:
-                arrived_wall.setdefault(i, now_s)
-        victims_round = []
-        if injector is not None and injector.want_kill(step):
-            # forced slot kill: seeded pick among live resumable slots,
-            # evicted through the exact preemption recovery path (and a
-            # candidate for re-admission this very round)
-            live = [s for s in range(slots)
-                    if slot_req[s] is not None and resumable[slot_req[s]]]
-            if live:
-                s = live[int(injector.rng.integers(len(live)))]
-                preempt_slot(s)
-                victims_round.append(s)
-        # -- admission: arrived requests into free, page-backed slots ----
-        # budget: reservations + index pins both count against the pool.
-        # A pinned page inside an active donor's reservation is counted
-        # twice — conservative, never overdrawn; the win comes from
-        # adopters reserving `need - shared` pages. Fault-injected
-        # pressure spikes subtract phantom pages for one round.
-        free_slots = [s for s in range(slots) if slot_req[s] is None]
-        phantom = injector.phantom_pages(step) if injector is not None \
-            else 0
-        page_budget = pool_pages - sum(reserved) - len(pins) - phantom
-        adm = []
-        adm_shared = {}                            # slot -> adopted pages
-        evict_batch = []
-        # candidate order = admission order: effective SLO class first
-        # (aging-adjusted, so a starved low-class request eventually
-        # outranks fresh high-class arrivals), then arrival, then trace
-        # position (a snapshot — this round's victims re-enter the queue
-        # but only become candidates next round, so preemption can never
-        # livelock within a round). Draining: admit nothing.
-        cand = [] if draining else sorted(
-            (i for i in queue if requests[i].arrival <= step),
-            key=lambda j: (-eff_prio(j, step), requests[j].arrival, j))
-        for i in cand:
-            if not free_slots and not preemption:
-                break
-            prompt_i, gen_i = pending[i]
-            plen_i = int(prompt_i.size)
-            sh_pages = []
-            if index is not None and plen_i + gen_i <= capacity:
-                # cap at plen-1: >= 1 prompt token must prefill live (the
-                # first sampled token needs this request's last-position
-                # logits); no sharing for window-wrapping requests (their
-                # COW pops would need headroom the reservation lacks)
-                sh_pages = index.lookup(prompt_i, max_tokens=plen_i - 1)
-            need = min(-(-(plen_i + gen_i) // page_size),
-                       pages_per_seq) - len(sh_pages)
-            if need > page_budget and index is not None and len(index):
-                # evict idle pinned prefixes (LRU) before preempting or
-                # stalling the head; pages adopted by active slots (or
-                # about to be, by this request) keep their pin
-                protected = {p for lst in slot_shared for p in lst}
-                protected |= set(sh_pages)
-                evicted = index.evict_lru(need - page_budget, protected)
-                for p in evicted:
-                    pins.pop(p, None)
-                evict_batch.extend(evicted)
-                page_budget += len(evicted)
-            if preemption and (need > page_budget or not free_slots):
-                # page-pressure preemption: evict strictly-lower-class
-                # victims — lowest class first, then most reserved pages
-                # — until this candidate fits. All-or-nothing: a
-                # candidate that still wouldn't fit evicts nobody.
-                cast = sorted(
-                    (s for s in range(slots)
-                     if slot_req[s] is not None
-                     and eff_prio(slot_req[s], step) < eff_prio(i, step)
-                     and resumable[slot_req[s]]),
-                    key=lambda s: (eff_prio(slot_req[s], step),
-                                   -reserved[s], s))
-                gain, picked = 0, []
-                for s in cast:
-                    if need <= page_budget + gain \
-                            and (free_slots or picked):
-                        break
-                    picked.append(s)
-                    gain += reserved[s]
-                if need <= page_budget + gain and (free_slots or picked):
-                    for s in picked:
-                        preempt_slot(s)            # reserved[s] -> 0
+        with jax.profiler.TraceAnnotation("serve.round",
+                                          segment=segments, step=step):
+            with jax.profiler.TraceAnnotation("serve.schedule"):
+                for i in queue:
+                    if requests[i].arrival <= step:
+                        arrived_wall.setdefault(i, now_s)
+                victims_round = []
+                if injector is not None and injector.want_kill(step):
+                    # forced slot kill: seeded pick among live resumable slots,
+                    # evicted through the exact preemption recovery path (and a
+                    # candidate for re-admission this very round)
+                    live = [s for s in range(slots)
+                            if slot_req[s] is not None
+                            and resumable[slot_req[s]]]
+                    if live:
+                        s = live[int(injector.rng.integers(len(live)))]
+                        preempt_slot(s)
                         victims_round.append(s)
-                        free_slots.append(s)
-                    page_budget += gain
-            if not free_slots or need > page_budget:
-                break                              # head-of-line: keep order
-            slot = free_slots.pop(0)
-            queue.remove(i)
-            slot_req[slot] = i
-            reserved[slot] = need
-            page_budget -= need
-            admitted_step.setdefault(i, step)      # first admission: TTFT
-            adm.append((slot, i))
-            adm_shared[slot] = sh_pages
-            slot_prompt[slot] = prompt_i
-            slot_shared[slot] = list(sh_pages)
-            slot_shareable[slot] = (index is not None
-                                    and plen_i + gen_i <= capacity)
-            reg_done[slot] = len(sh_pages)         # adopted = already indexed
-            sh_toks = len(sh_pages) * page_size
-            prefill_tokens += plen_i - sh_toks
-            shared_tokens += sh_toks
-            prefix_hits += bool(sh_pages)
-        if victims_round:
-            # one-dispatch device-row clear: the victims' done flag
-            # raises before any release/adopt/admit dispatch and before
-            # the next segment, so the scan never touches freed pages
-            vmask = np.zeros((slots,), bool)
-            vmask[victims_round] = True
-            state = _preempt_rows(state, jnp.asarray(vmask))
-        if adm and to_release:
-            # deferred page hand-back: freed slots accumulate across
-            # segment boundaries and release in one dispatch right before
-            # the pages are actually needed (host `reserved` accounting
-            # keeps the budget exact in between)
-            mask = np.zeros((slots,), bool)
-            mask[to_release] = True
-            caches = _release_slots(caches, jnp.asarray(mask))
-            to_release = []
-        if evict_batch:
-            # unpin evicted index entries (dispatched even when the head
-            # still didn't fit, so the host pin ledger and the device
-            # refcounts never diverge); pages reaching refcount zero are
-            # free the moment this lands
-            pad = np.full((slots * pages_per_seq,), -1, np.int32)
-            pad[:len(evict_batch)] = evict_batch
-            caches = _unpin_pages(caches, jnp.asarray(pad))
-        if adm:
-            rounds += 1
-            prompts = np.zeros((slots, prompt_pad), np.int32)
-            lengths = np.ones((slots,), np.int32)
-            gens = np.zeros((slots,), np.int32)
-            prios = np.zeros((slots,), np.int32)
-            slot_ids = np.full((slots,), -1, np.int32)
-            row_req = np.zeros((slots,), np.int32)
-            for row, (slot, i) in enumerate(adm):
-                p, g = pending[i]
-                prompts[row, :p.size] = p
-                lengths[row] = p.size
-                gens[row] = g
-                prios[row] = eff_prio(i, step)
-                slot_ids[row] = slot
-                row_req[row] = i
-                plen_host[slot] = p.size
-            req_keys = fold_keys(base_key, jnp.asarray(row_req))
-            if resume_keys:
-                # resumed rows restore the PRNG snapshot taken at their
-                # eviction instead of restarting the fold_in stream — the
-                # draws continue exactly where the victim left off
-                rk = np.asarray(req_keys).copy()
-                for row, (slot, i) in enumerate(adm):
-                    if i in resume_keys:
-                        rk[row] = resume_keys.pop(i)
-                req_keys = jnp.asarray(rk)
-            lengths_d = jnp.asarray(lengths)
-            slot_ids_d = jnp.asarray(slot_ids)
-            if admission == "chunked":
-                shared_rows = np.zeros((slots,), np.int32)
-                if index is not None:
-                    adopt_pages = np.zeros((slots, pages_per_seq), np.int32)
-                    adopt_n = np.zeros((slots,), np.int32)
-                    for row, (slot, i) in enumerate(adm):
-                        sh = adm_shared.get(slot, [])
-                        adopt_pages[row, :len(sh)] = sh
-                        adopt_n[row] = len(sh)
-                        shared_rows[row] = len(sh) * page_size
-                    if adopt_n.any():
-                        # point the new slots' leading table entries at
-                        # the shared pages (+1 refcount, every layer)
-                        caches = _adopt_prefix_slots(
-                            caches, slot_ids_d, jnp.asarray(adopt_pages),
-                            jnp.asarray(adopt_n),
-                            jnp.asarray(shared_rows))
-                # enqueue-only admission: prompt ids + phase state; the
-                # segments do the prefill, page-native, starting at the
-                # first unshared token
-                state = _admit_chunked(state, slot_ids_d,
-                                       jnp.asarray(prompts), lengths_d,
-                                       jnp.asarray(gens), req_keys,
-                                       jnp.asarray(shared_rows),
-                                       jnp.asarray(prios))
-                for row, (slot, i) in enumerate(adm):
-                    prefilling[slot] = True
-                    cursor_host[slot] = int(shared_rows[row])
-            else:
-                # stall admission: stop-the-world ragged prefill over the
-                # ring scratch, bytes-copied into pool pages (no sharing:
-                # every prompt token forwards)
-                t_stall = time.perf_counter()
-                logits, scratch = prefill(params, jnp.asarray(prompts),
-                                          scratch, None, lengths_d)
-                tok0, req_keys = sample_token_rows(
-                    logits, req_keys, temp_arr, sample=sample)
-                caches = _adopt_prompts(caches, scratch, slot_ids_d,
-                                        lengths_d)
-                tok0_np = np.asarray(tok0)
-                new_done = np.zeros((slots,), bool)
-                new_rem = np.zeros((slots,), np.int32)
-                now_s = time.perf_counter() - t0
-                for row, (slot, i) in enumerate(adm):
-                    t0_tok = int(tok0_np[row, 0])
-                    emitted[i].append(t0_tok)
-                    first_tok.setdefault(i, now_s)
-                    new_rem[row] = requests[i].gen - 1
-                    new_done[row] = (requests[i].gen <= 1
-                                     or (eos_id is not None
-                                         and t0_tok == eos_id))
-                state = _admit_stall(
-                    state, slot_ids_d, lengths_d, tok0,
-                    jnp.asarray(new_done), jnp.asarray(new_rem), req_keys,
-                    jnp.asarray(prios))
-                jax.block_until_ready(state.tok)
-                stall_s += time.perf_counter() - t_stall
-            if audit is not None:
-                audit(caches, list(slot_req), dict(pins))
-            if debug:
-                _check_paged_invariants(caches, pins=dict(pins))
-        if admission == "stall" and adm:
-            # freshly admitted gen-1/EOS requests finish without decoding
-            just_done = np.asarray(state.done)
-            fin = [s for s in range(slots)
-                   if slot_req[s] is not None and just_done[s]]
-            if fin:
-                now_s = time.perf_counter() - t0
-                for s in fin:
-                    finish(s, now_s)
-                to_release.extend(fin)
-                continue
-        if all(s is None for s in slot_req):
-            if not queue:
-                break
-            step += segment                        # idle: nothing admittable
-            continue
-
-        # -- fused segment: mixed while any slot is mid-prompt (sized to
-        # the chunks actually left), pure decode otherwise — decode-only
-        # phases never pay chunk-wide q width
-        t_seg = time.perf_counter()
-        if injector is not None:
-            pause = injector.straggle(step)
-            if pause > 0.0:
-                time.sleep(pause)                  # injected straggler
-        if admission == "chunked" and any(prefilling):
-            # steps of mixed phase: bounded below by the largest single
-            # prompt (one chunk per slot per step) and by total prefill
-            # work over the per-step prefill token capacity (budget minus
-            # the decoding slots it must keep fed)
-            left = [plen_host[s] - cursor_host[s]
-                    for s in range(slots) if prefilling[s]]
-            n_dec = sum(1 for s in range(slots)
-                        if slot_req[s] is not None and not prefilling[s])
-            per_step = max(budget - n_dec, 1)
-            need = max(-(-max(left) // chunk),
-                       -(-sum(left) // per_step))
-            fn = seg_mixed(max(need, 1))
-        else:
-            fn = seg_decode
-        toks, emits, _, state, caches, _ = fn(params, state, caches,
-                                              temp_arr)
-        segments += 1
-        step += segment
-        # pool utilization from the host-side reservation ledger (exact
-        # upper bound on device-held pages; no extra device sync),
-        # sampled while the segment's occupants still hold their pages
-        page_util.append((step, sum(reserved) / max(pool_pages, 1)))
-        keys_np = None
-        if journal is not None and sample:
-            toks_np, emits_np, done_np, cursor_np, keys_np = \
-                jax.device_get((toks, emits, state.done, state.cursor,
-                                state.keys))               # one sync
-        else:
-            toks_np, emits_np, done_np, cursor_np = jax.device_get(
-                (toks, emits, state.done, state.cursor))   # one sync
-        if injector is not None and injector.want_crash_after(step):
-            # mid-segment death: the device produced this segment's
-            # tokens but the flush below never runs — the torn window.
-            # Recovery resumes from the *previous* boundary and must
-            # regenerate the lost tokens bit-identically
-            if journal is not None:
-                journal.wait()
-            if snap_ckpt is not None:
-                snap_ckpt.wait()
-            raise SimulatedCrash(step, "mid-segment")
-        straggler_segs += watchdog.observe(
-            time.perf_counter() - t_seg).straggler
-        now_s = time.perf_counter() - t0
-        for s in range(slots):
-            if slot_req[s] is None:
-                continue
-            i = slot_req[s]
-            row = toks_np[s][emits_np[s]].tolist()
-            if row:
-                first_tok.setdefault(i, now_s)
-                emitted[i].extend(row)
-            cursor_host[s] = int(cursor_np[s])
-            prefilling[s] = cursor_host[s] < plen_host[s]
-        if index is not None:
-            # register every freshly completed *full* page of prompt
-            # tokens (bytes final: no-wrap donors never rewrite them) so
-            # later arrivals can adopt it; runs before the finish/release
-            # bookkeeping so a request that just completed still donates.
-            # One small device_get of layer 0's page tables serves every
-            # layer — the pools are in lockstep.
-            reg_rows = []
-            for s in range(slots):
-                if slot_req[s] is None or not slot_shareable[s]:
-                    continue
-                full = min(cursor_host[s], plen_host[s]) // page_size
-                if full > reg_done[s]:
-                    reg_rows.append((s, full))
-            if reg_rows:
-                table = np.asarray(jax.device_get(
-                    _first_paged(caches).page_table[0]))
-                new_pins = []
-                for s, full in reg_rows:
-                    # the slot's *pending* stream, not the original
-                    # prompt: a resumed slot prefills prompt + generated
-                    # prefix, and those pages hash under that stream —
-                    # which is also what makes a re-preemption's
-                    # re-admission adopt them back nearly for free
-                    got = index.register(slot_prompt[s],
-                                         table[s, :full])
-                    reg_done[s] = full
-                    new_pins.extend(got)
-                if new_pins:
-                    pins.update((p, 1) for p in new_pins)
+                # -- admission: arrived requests into free, page-backed
+                # slots
+                # budget: reservations + index pins both count against the
+                # pool. A pinned page inside an active donor's reservation is
+                # counted twice — conservative, never overdrawn; the win comes
+                # from adopters reserving `need - shared` pages. Fault-injected
+                # pressure spikes subtract phantom pages for one round.
+                free_slots = [s for s in range(slots) if slot_req[s] is None]
+                phantom = injector.phantom_pages(step) \
+                    if injector is not None else 0
+                page_budget = pool_pages - sum(reserved) - len(pins) - phantom
+                adm = []
+                adm_shared = {}                         # slot -> adopted pages
+                evict_batch = []
+                # candidate order = admission order: effective SLO class first
+                # (aging-adjusted, so a starved low-class request eventually
+                # outranks fresh high-class arrivals), then arrival, then trace
+                # position (a snapshot — this round's victims re-enter the
+                # queue but only become candidates next round, so preemption
+                # can never livelock within a round). Draining: admit nothing.
+                cand = [] if draining else sorted(
+                    (i for i in queue if requests[i].arrival <= step),
+                    key=lambda j: (-eff_prio(j, step), requests[j].arrival, j))
+                for i in cand:
+                    if not free_slots and not preemption:
+                        break
+                    prompt_i, gen_i = pending[i]
+                    plen_i = int(prompt_i.size)
+                    sh_pages = []
+                    if index is not None and plen_i + gen_i <= capacity:
+                        # cap at plen-1: >= 1 prompt token must prefill live
+                        # (the first sampled token needs this request's
+                        # last-position logits); no sharing for window-wrapping
+                        # requests (their COW pops would need headroom the
+                        # reservation lacks)
+                        sh_pages = index.lookup(prompt_i,
+                                                max_tokens=plen_i - 1)
+                    need = min(-(-(plen_i + gen_i) // page_size),
+                               pages_per_seq) - len(sh_pages)
+                    if need > page_budget and index is not None and len(index):
+                        # evict idle pinned prefixes (LRU) before preempting or
+                        # stalling the head; pages adopted by active slots (or
+                        # about to be, by this request) keep their pin
+                        protected = {p for lst in slot_shared for p in lst}
+                        protected |= set(sh_pages)
+                        evicted = index.evict_lru(need - page_budget,
+                                                  protected)
+                        for p in evicted:
+                            pins.pop(p, None)
+                        evict_batch.extend(evicted)
+                        page_budget += len(evicted)
+                    if preemption and (need > page_budget or not free_slots):
+                        # page-pressure preemption: evict strictly-lower-class
+                        # victims — lowest class first, then most reserved
+                        # pages — until this candidate fits. All-or-nothing: a
+                        # candidate that still wouldn't fit evicts nobody.
+                        cast = sorted(
+                            (s for s in range(slots)
+                             if slot_req[s] is not None
+                             and eff_prio(slot_req[s], step)
+                             < eff_prio(i, step)
+                             and resumable[slot_req[s]]),
+                            key=lambda s: (eff_prio(slot_req[s], step),
+                                           -reserved[s], s))
+                        gain, picked = 0, []
+                        for s in cast:
+                            if need <= page_budget + gain \
+                                    and (free_slots or picked):
+                                break
+                            picked.append(s)
+                            gain += reserved[s]
+                        if need <= page_budget + gain \
+                                and (free_slots or picked):
+                            for s in picked:
+                                preempt_slot(s)            # reserved[s] -> 0
+                                victims_round.append(s)
+                                free_slots.append(s)
+                            page_budget += gain
+                    if not free_slots or need > page_budget:
+                        break                        # head-of-line: keep order
+                    slot = free_slots.pop(0)
+                    queue.remove(i)
+                    slot_req[slot] = i
+                    reserved[slot] = need
+                    page_budget -= need
+                    admitted_step.setdefault(i, step)   # first admission: TTFT
+                    adm.append((slot, i))
+                    adm_shared[slot] = sh_pages
+                    slot_prompt[slot] = prompt_i
+                    slot_shared[slot] = list(sh_pages)
+                    slot_shareable[slot] = (index is not None
+                                            and plen_i + gen_i <= capacity)
+                    reg_done[slot] = len(sh_pages)  # adopted = already indexed
+                    sh_toks = len(sh_pages) * page_size
+                    prefill_tokens += plen_i - sh_toks
+                    shared_tokens += sh_toks
+                    prefix_hits += bool(sh_pages)
+            with jax.profiler.TraceAnnotation("serve.pool"):
+                if victims_round:
+                    # one-dispatch device-row clear: the victims' done flag
+                    # raises before any release/adopt/admit dispatch and before
+                    # the next segment, so the scan never touches freed pages
+                    vmask = np.zeros((slots,), bool)
+                    vmask[victims_round] = True
+                    state = _preempt_rows(state, jnp.asarray(vmask))
+                if adm and to_release:
+                    # deferred page hand-back: freed slots accumulate across
+                    # segment boundaries and release in one dispatch right
+                    # before the pages are actually needed (host `reserved`
+                    # accounting keeps the budget exact in between)
+                    mask = np.zeros((slots,), bool)
+                    mask[to_release] = True
+                    caches = _release_slots(caches, jnp.asarray(mask))
+                    to_release = []
+                if evict_batch:
+                    # unpin evicted index entries (dispatched even when the
+                    # head still didn't fit, so the host pin ledger and the
+                    # device refcounts never diverge); pages reaching refcount
+                    # zero are free the moment this lands
                     pad = np.full((slots * pages_per_seq,), -1, np.int32)
-                    pad[:len(new_pins)] = new_pins
-                    caches = _pin_pages(caches, jnp.asarray(pad))
-        fin = [s for s in range(slots)
-               if slot_req[s] is not None and done_np[s]]
-        for s in fin:
-            finish(s, now_s)
-        to_release.extend(fin)
-        if journal is not None:
-            # the boundary's group-commit point: progress deltas + key
-            # snapshots + any completes land in one written batch
-            # (fsynced on the journal's bounded cadence); a crash before
-            # the *next* flush loses at most a bounded suffix of
-            # regenerable work
-            _journal_progress(keys_np)
-            journal.flush()
-            if snap_ckpt is not None and segments % snapshot_every == 0:
-                save_snapshot()
+                    pad[:len(evict_batch)] = evict_batch
+                    caches = _unpin_pages(caches, jnp.asarray(pad))
+                if adm:
+                    rounds += 1
+                    prompts = np.zeros((slots, prompt_pad), np.int32)
+                    lengths = np.ones((slots,), np.int32)
+                    gens = np.zeros((slots,), np.int32)
+                    prios = np.zeros((slots,), np.int32)
+                    slot_ids = np.full((slots,), -1, np.int32)
+                    row_req = np.zeros((slots,), np.int32)
+                    for row, (slot, i) in enumerate(adm):
+                        p, g = pending[i]
+                        prompts[row, :p.size] = p
+                        lengths[row] = p.size
+                        gens[row] = g
+                        prios[row] = eff_prio(i, step)
+                        slot_ids[row] = slot
+                        row_req[row] = i
+                        plen_host[slot] = p.size
+                    req_keys = fold_keys(base_key, jnp.asarray(row_req))
+                    if resume_keys:
+                        # resumed rows restore the PRNG snapshot taken at their
+                        # eviction instead of restarting the fold_in stream —
+                        # the draws continue exactly where the victim left off
+                        rk = np.asarray(req_keys).copy()
+                        for row, (slot, i) in enumerate(adm):
+                            if i in resume_keys:
+                                rk[row] = resume_keys.pop(i)
+                        req_keys = jnp.asarray(rk)
+                    lengths_d = jnp.asarray(lengths)
+                    slot_ids_d = jnp.asarray(slot_ids)
+                    if admission == "chunked":
+                        shared_rows = np.zeros((slots,), np.int32)
+                        if index is not None:
+                            adopt_pages = np.zeros((slots, pages_per_seq),
+                                                   np.int32)
+                            adopt_n = np.zeros((slots,), np.int32)
+                            for row, (slot, i) in enumerate(adm):
+                                sh = adm_shared.get(slot, [])
+                                adopt_pages[row, :len(sh)] = sh
+                                adopt_n[row] = len(sh)
+                                shared_rows[row] = len(sh) * page_size
+                            if adopt_n.any():
+                                # point the new slots' leading table entries at
+                                # the shared pages (+1 refcount, every layer)
+                                caches = _adopt_prefix_slots(
+                                    caches, slot_ids_d,
+                                    jnp.asarray(adopt_pages),
+                                    jnp.asarray(adopt_n),
+                                    jnp.asarray(shared_rows))
+                        # enqueue-only admission: prompt ids + phase state; the
+                        # segments do the prefill, page-native, starting at the
+                        # first unshared token
+                        state = _admit_chunked(state, slot_ids_d,
+                                               jnp.asarray(prompts), lengths_d,
+                                               jnp.asarray(gens), req_keys,
+                                               jnp.asarray(shared_rows),
+                                               jnp.asarray(prios))
+                        for row, (slot, i) in enumerate(adm):
+                            prefilling[slot] = True
+                            cursor_host[slot] = int(shared_rows[row])
+                    else:
+                        # stall admission: stop-the-world ragged prefill over
+                        # the ring scratch, bytes-copied into pool pages (no
+                        # sharing: every prompt token forwards)
+                        t_stall = time.perf_counter()
+                        logits, scratch = prefill(params, jnp.asarray(prompts),
+                                                  scratch, None, lengths_d)
+                        tok0, req_keys = sample_token_rows(
+                            logits, req_keys, temp_arr, sample=sample)
+                        caches = _adopt_prompts(caches, scratch, slot_ids_d,
+                                                lengths_d)
+                        tok0_np = np.asarray(tok0)
+                        new_done = np.zeros((slots,), bool)
+                        new_rem = np.zeros((slots,), np.int32)
+                        now_s = time.perf_counter() - t0
+                        for row, (slot, i) in enumerate(adm):
+                            t0_tok = int(tok0_np[row, 0])
+                            emitted[i].append(t0_tok)
+                            first_tok.setdefault(i, now_s)
+                            new_rem[row] = requests[i].gen - 1
+                            new_done[row] = (requests[i].gen <= 1
+                                             or (eos_id is not None
+                                                 and t0_tok == eos_id))
+                        state = _admit_stall(
+                            state, slot_ids_d, lengths_d, tok0,
+                            jnp.asarray(new_done), jnp.asarray(new_rem),
+                            req_keys, jnp.asarray(prios))
+                        jax.block_until_ready(state.tok)
+                        stall_s += time.perf_counter() - t_stall
+                    if audit is not None:
+                        audit(caches, list(slot_req), dict(pins))
+                    if debug:
+                        _check_paged_invariants(caches, pins=dict(pins))
+                if admission == "stall" and adm:
+                    # freshly admitted gen-1/EOS requests finish without
+                    # decoding
+                    just_done = np.asarray(state.done)
+                    fin = [s for s in range(slots)
+                           if slot_req[s] is not None and just_done[s]]
+                    if fin:
+                        now_s = time.perf_counter() - t0
+                        for s in fin:
+                            finish(s, now_s)
+                        to_release.extend(fin)
+                        continue
+            if all(s is None for s in slot_req):
+                if not queue:
+                    break
+                step += segment                      # idle: nothing admittable
+                continue
+
+            # -- fused segment: mixed while any slot is mid-prompt (sized to
+            # the chunks actually left), pure decode otherwise — decode-only
+            # phases never pay chunk-wide q width
+            t_seg = time.perf_counter()
+            if injector is not None:
+                pause = injector.straggle(step)
+                if pause > 0.0:
+                    time.sleep(pause)                  # injected straggler
+            if admission == "chunked" and any(prefilling):
+                # steps of mixed phase: bounded below by the largest single
+                # prompt (one chunk per slot per step) and by total prefill
+                # work over the per-step prefill token capacity (budget minus
+                # the decoding slots it must keep fed)
+                left = [plen_host[s] - cursor_host[s]
+                        for s in range(slots) if prefilling[s]]
+                n_dec = sum(1 for s in range(slots)
+                            if slot_req[s] is not None and not prefilling[s])
+                per_step = max(budget - n_dec, 1)
+                need = max(-(-max(left) // chunk),
+                           -(-sum(left) // per_step))
+                # rounded up to a power of two to bound compilation count
+                k = min(segment, _next_pow2(max(need, 1)))
+                fn = seg_mixed(k)
+            else:
+                k, fn = 0, seg_decode
+            with jax.profiler.TraceAnnotation("serve.dispatch",
+                                              segment=segments, step=step,
+                                              mixed=k, steps=segment):
+                toks, emits, _, state, caches, _ = fn(params, state, caches,
+                                                      temp_arr)
+            segments += 1
+            step += segment
+            # pool utilization from the host-side reservation ledger (exact
+            # upper bound on device-held pages; no extra device sync),
+            # sampled while the segment's occupants still hold their pages
+            page_util.append((step, sum(reserved) / max(pool_pages, 1)))
+            keys_np = None
+            out = (toks, emits, state.done, state.cursor)
+            if journal is not None and sample:
+                out += (state.keys,)
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(out)
+            with jax.profiler.TraceAnnotation("serve.readback"):
+                if journal is not None and sample:
+                    toks_np, emits_np, done_np, cursor_np, keys_np = \
+                        jax.device_get(out)                    # one sync
+                else:
+                    toks_np, emits_np, done_np, cursor_np = \
+                        jax.device_get(out)                    # one sync
+                if injector is not None and injector.want_crash_after(step):
+                    # mid-segment death: the device produced this segment's
+                    # tokens but the flush below never runs — the torn
+                    # window. Recovery resumes from the *previous* boundary
+                    # and must regenerate the lost tokens bit-identically
+                    if journal is not None:
+                        journal.wait()
+                    if snap_ckpt is not None:
+                        snap_ckpt.wait()
+                    raise SimulatedCrash(step, "mid-segment")
+                straggler_segs += watchdog.observe(
+                    time.perf_counter() - t_seg).straggler
+                now_s = time.perf_counter() - t0
+                for s in range(slots):
+                    if slot_req[s] is None:
+                        continue
+                    i = slot_req[s]
+                    row = toks_np[s][emits_np[s]].tolist()
+                    if row:
+                        first_tok.setdefault(i, now_s)
+                        emitted[i].extend(row)
+                    cursor_host[s] = int(cursor_np[s])
+                    prefilling[s] = cursor_host[s] < plen_host[s]
+            if index is not None:
+                with jax.profiler.TraceAnnotation("serve.register"):
+                    # register every freshly completed *full* page of prompt
+                    # tokens (bytes final: no-wrap donors never rewrite them)
+                    # so later arrivals can adopt it; runs before the
+                    # finish/release bookkeeping so a request that just
+                    # completed still donates. One small device_get of layer
+                    # 0's page tables serves every layer — the pools are in
+                    # lockstep.
+                    reg_rows = []
+                    for s in range(slots):
+                        if slot_req[s] is None or not slot_shareable[s]:
+                            continue
+                        full = min(cursor_host[s], plen_host[s]) // page_size
+                        if full > reg_done[s]:
+                            reg_rows.append((s, full))
+                    if reg_rows:
+                        table = np.asarray(jax.device_get(
+                            _first_paged(caches).page_table[0]))
+                        new_pins = []
+                        for s, full in reg_rows:
+                            # the slot's *pending* stream, not the original
+                            # prompt: a resumed slot prefills prompt +
+                            # generated prefix, and those pages hash under that
+                            # stream — which is also what makes a
+                            # re-preemption's re-admission adopt them back
+                            # nearly for free
+                            got = index.register(slot_prompt[s],
+                                                 table[s, :full])
+                            reg_done[s] = full
+                            new_pins.extend(got)
+                        if new_pins:
+                            pins.update((p, 1) for p in new_pins)
+                            pad = np.full((slots * pages_per_seq,), -1,
+                                          np.int32)
+                            pad[:len(new_pins)] = new_pins
+                            caches = _pin_pages(caches, jnp.asarray(pad))
+            fin = [s for s in range(slots)
+                   if slot_req[s] is not None and done_np[s]]
+            for s in fin:
+                finish(s, now_s)
+            to_release.extend(fin)
+            if journal is not None:
+                with jax.profiler.TraceAnnotation("serve.journal"):
+                    # the boundary's group-commit point: progress deltas + key
+                    # snapshots + any completes land in one written batch
+                    # (fsynced on the journal's bounded cadence); a crash
+                    # before the *next* flush loses at most a bounded suffix of
+                    # regenerable work
+                    _journal_progress(keys_np)
+                    journal.flush()
+                    if snap_ckpt is not None \
+                            and segments % snapshot_every == 0:
+                        save_snapshot()
 
     if journal is not None:
         _journal_progress(None)

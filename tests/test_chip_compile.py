@@ -79,6 +79,39 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind,
     assert out_bytes == SLOTS * hq * sq * max(d, 128)
 
 
+def test_paged_kernels_carry_their_names(one_chip, no_persistent_cache):
+    """The serve path's two kernels keep their ``pallas_call`` names in
+    the compiled program, where a profiler trace shows them, next to the
+    ``tpu_custom_call`` target and the int8 result shape the benchmark's
+    kernel readers match."""
+    pages = SLOTS * PAGES_PER_SEQ + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q_dec, q_chunk, k, v, page_table, kv_len, q_offset, q_lens):
+        common = dict(kv_len=kv_len, page_table=page_table, interpret=False)
+        dec = fused_attention(q_dec, k, v, 0.05, 0.05, 0.05, 0.02,
+                              q_offset=q_offset, kind="decode", **common)
+        chunk = fused_attention(q_chunk, k, v, 0.05, 0.05, 0.05, 0.02,
+                                q_offset=q_offset, q_lens=q_lens,
+                                kind="onepass", **common)
+        return dec, chunk
+
+    pool = sds((pages, 32, PAGE, 96), jnp.int8)
+    rows = sds((SLOTS,), jnp.int32)
+    text = jax.jit(call).lower(
+        sds((SLOTS, 32, 1, 96), jnp.int8), sds((SLOTS, 32, CHUNK, 96),
+                                               jnp.int8),
+        pool, pool, sds((SLOTS, PAGES_PER_SEQ), jnp.int32), rows, rows,
+        rows).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name, q in (("ita_decode_paged", 8), ("ita_onepass_paged", CHUNK)):
+        line, = [c for c in calls if f"%{name}" in c.split("=", 1)[0]]
+        assert f"s8[{SLOTS * 32},{q},96]" in line, line[:200]
+
+
 def test_one_row_dense_runs_on_the_mxu(one_chip, no_persistent_cache):
     """A one-row ``x @ w`` lowers to a vector multiply-reduce on the TPU,
     which sums in another order than the MXU path every larger row count
