@@ -308,7 +308,7 @@ def _fused_run(kind, q, k, v, spec, scales, q_offset, kv_len, opts):
         window=spec.window, kind=kind, adaptive=spec.softmax == "adaptive",
         block_q=opts.get("block_q", dbq or 128),
         block_kv=opts.get("block_kv", dbkv),
-        page_table=page_table,
+        page_table=page_table, layer=opts.get("layer"),
         interpret=opts.get("interpret"))
     if spec.layout == "bshd":
         out = jnp.swapaxes(out, 1, 2)                    # back to (B,S,H,D)

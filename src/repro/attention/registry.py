@@ -84,18 +84,20 @@ def _shapes(q, k, spec: AttentionSpec):
     elif spec.layout == "bhsd":
         hq, sq = q.shape[1], q.shape[2]
         hkv, skv = k.shape[1], k.shape[2]
-    elif spec.layout == "bhsd_paged":           # kv = (P, G, page, hd) pool
+    elif spec.layout == "bhsd_paged":           # kv = ([L,] P, G, page, hd)
         hq, sq = q.shape[1], q.shape[2]
-        hkv, skv = k.shape[1], k.shape[2]       # skv = one page here
+        hkv, skv = k.shape[-3], k.shape[-2]     # skv = one page here
     else:                                       # bhsd_bsgd: q bhsd, kv bsgd
         hq, sq = q.shape[1], q.shape[2]
         skv, hkv = k.shape[1], k.shape[2]
     return sq, hq, skv, hkv, q.shape[-1]
 
 
-def _validate(q, k, v, spec: AttentionSpec, scales):
-    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
-        raise ValueError(f"q/k/v must be rank-4, got "
+def _validate(q, k, v, spec: AttentionSpec, scales, layer=None):
+    # a paged pool may come stacked over layers, read at ``layer``
+    kv_rank = 5 if layer is not None else 4
+    if q.ndim != 4 or k.ndim != kv_rank or v.ndim != kv_rank:
+        raise ValueError(f"q/k/v must be rank-4/{kv_rank}/{kv_rank}, got "
                          f"{q.ndim}/{k.ndim}/{v.ndim}")
     sq, hq, skv, hkv, d = _shapes(q, k, spec)
     if hq % hkv != 0:
@@ -118,7 +120,7 @@ def _validate(q, k, v, spec: AttentionSpec, scales):
 def dispatch(q, k, v, *, spec: AttentionSpec, scales=None,
              q_offset: Any = 0, kv_len: Any = None,
              page_table: Any = None, q_lens: Any = None,
-             backend: str | None = None, **opts):
+             layer: Any = None, backend: str | None = None, **opts):
     """Run one attention computation through the registry.
 
     ``q``/``k``/``v``: rank-4 arrays in ``spec.layout``. Integer impls
@@ -127,11 +129,14 @@ def dispatch(q, k, v, *, spec: AttentionSpec, scales=None,
     ``q_offset``/``kv_len``: dynamic decode plumbing (logical position of
     query 0; valid KV prefix). ``page_table`` (B, n_pages) int32 —
     required by (and only by) the ``bhsd_paged`` layout, where ``k``/``v``
-    are a shared paged pool. ``q_lens`` (B,) int32 — required by (and
-    only by) ``spec.ragged_q``: each row's count of valid query rows in
-    the mixed chunked-prefill/decode call. ``backend``: explicit override
-    by name — still capability-checked, so an ineligible (spec, backend)
-    pair raises ``BackendUnsupported`` with the backend's stated reason.
+    are a shared paged pool; ``layer`` (() int32) reads a pool stacked
+    over layers, ``(L, P, G, page, hd)``, at that index (paged only —
+    the model's pools travel whole through its layer scan). ``q_lens``
+    (B,) int32 — required by (and only by) ``spec.ragged_q``: each
+    row's count of valid query rows in the mixed chunked-prefill/decode
+    call. ``backend``: explicit override by name — still
+    capability-checked, so an ineligible (spec, backend) pair raises
+    ``BackendUnsupported`` with the backend's stated reason.
     ``opts``: tuning knobs forwarded to the backend (``block_q``,
     ``block_kv``, ``q_chunk``, ``kv_chunk``, ``interpret``,
     ``scan_unroll``); unknown knobs are ignored by backends that don't
@@ -167,9 +172,13 @@ def dispatch(q, k, v, *, spec: AttentionSpec, scales=None,
             "q_lens= is required by exactly ragged_q specs "
             f"(ragged_q={spec.ragged_q}, q_lens "
             f"{'missing' if q_lens is None else 'given'})")
-    _validate(q, k, v, spec, scales)
+    if layer is not None and page_table is None:
+        raise ValueError("layer= indexes a stacked paged pool; it needs "
+                         "the 'bhsd_paged' layout and page_table=")
+    _validate(q, k, v, spec, scales, layer)
     if page_table is not None:
         opts["page_table"] = page_table
+        opts["layer"] = layer
     if q_lens is not None:
         opts["q_lens"] = q_lens
     return b.run(q, k, v, spec, scales, q_offset=q_offset, kv_len=kv_len,

@@ -19,7 +19,8 @@ the cache rides the model's per-tensor QAT scales.
 ``PagedKVState`` is the continuous-batching allocator: **one** shared
 head-major ``(num_pages, G, page_size, hd)`` int8 arena for the whole
 batch (each kv head's page is one contiguous ``(page_size, hd)`` tile,
-the block the fused kernels DMA), a
+the block the fused kernels DMA; ``hd`` padded to whole 128-lane
+tiles), a
 per-sequence page table translating logical KV pages to physical arena
 pages, and an on-device free stack. Logical semantics are *identical* to
 a ring of capacity ``n_pages * page_size`` (slot ``t % C``, same
@@ -28,10 +29,12 @@ is bit-identical to the ring path — but physically a sequence only holds
 ``ceil(pos / page_size)`` pages, and ``release`` returns them to the
 pool the moment the sequence finishes: KV memory is O(tokens live), not
 O(B * max_len) reserved. Physical page 0 is the **parking page** — never
-allocated and never written (masked writes scatter to an out-of-bounds
-index and are dropped), it backs unassigned page-table entries so every
+allocated and never written (a write with nothing to land names it and
+writes its zeros back), it backs unassigned page-table entries so every
 gather stays in bounds without branches, and its bytes stay zero for the
-life of the pool.
+life of the pool. Writes land in place through the ``ita_kv_write``
+kernel (``repro.kernels.kv_write``): copy-on-write pages, then the
+``(G, 32, hd)`` tiles the new tokens fall in.
 
 Pages carry a **refcount** (``ref_count``, per physical page): rows
 admitted with a shared prompt prefix point their leading page-table
@@ -55,7 +58,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import MIN_BLOCK_KV
+from repro.kernels.common import LANES, MIN_BLOCK_KV
+from repro.kernels.kv_write import ita_kv_write, write_tile
 
 
 def _align_capacity(capacity: int) -> int:
@@ -210,9 +214,10 @@ PARKING_PAGE = 0        # physical page 0: write sink / unassigned entries
 class PagedKVState:
     """Shared paged int8 KV pool + per-sequence page tables + free stack.
 
-    ``k``/``v``: head-major ``(num_pages, G, page_size, hd)`` arena
-    shared by every sequence (and, at the model level, one arena per
-    layer).
+    ``k``/``v``: head-major ``(num_pages, G, page_size, lanes)`` arena
+    shared by every sequence (``lanes``: ``hd`` padded to whole 128-lane
+    tiles, zeros past ``hd``), and at the model level one arena per
+    layer, stacked.
     ``page_table``: ``(B, n_pages)`` int32 — logical KV page ``j`` of
     sequence ``b`` lives in physical page ``page_table[b, j]``
     (``PARKING_PAGE`` = unassigned). ``pos``: per-sequence stream length,
@@ -231,10 +236,18 @@ class PagedKVState:
     invariant (``check_invariants``): every page is on the free stack
     XOR referenced with count >= 1, and the count equals the number of
     page-table references plus pins.
+
+    ``layer``: ``None`` while ``k``/``v`` are one layer's arena. A model
+    stacks every leaf over its layers; ``at_layer(i)`` then slices the
+    bookkeeping to layer ``i`` but keeps ``k``/``v`` whole, the stacked
+    ``(L, P, G, page, hd)`` pool, with ``layer = i``: the writes
+    (``ita_kv_write``) and the paged attention kernels address the pool
+    at that index, so no op copies a layer's pool out of the stack and
+    back.
     """
 
-    k: Any                      # (P, G, page, hd)
-    v: Any                      # (P, G, page, hd)
+    k: Any                      # (P, G, page, hd), or (L, P, G, page, hd)
+    v: Any                      # with ``layer`` set
     page_table: Any             # (B, n_pages) int32
     pos: Any                    # (B,) int32
     free_stack: Any             # (P,) int32
@@ -242,6 +255,7 @@ class PagedKVState:
     ref_count: Any = None       # (P,) int32 — references per physical page
     k_scale: Any = None         # (G,) f32 per-head scales, optional
     v_scale: Any = None
+    layer: Any = None           # () int32 — index into a stacked k/v
 
     # -- construction -----------------------------------------------------
 
@@ -254,7 +268,8 @@ class PagedKVState:
         up to a ``page_size`` multiple; ``num_pages`` sizes the shared
         arena (default: fully provisioned, ``B * pages_per_seq`` + the
         parking page — pass less to oversubscribe under an admission
-        scheduler)."""
+        scheduler). The pool's minor dim is ``head_dim`` padded to whole
+        ``LANES`` (zeros past ``head_dim``; see ``kernels.common``)."""
         capacity = max(capacity, 1)
         n_pages = _ceil_div(capacity, page_size)
         if num_pages is None:
@@ -262,7 +277,8 @@ class PagedKVState:
         if num_pages < 2:
             raise ValueError("num_pages must cover the parking page plus "
                              "at least one allocatable page")
-        shape = (num_pages, n_kv_heads, page_size, head_dim)
+        shape = (num_pages, n_kv_heads, page_size,
+                 _ceil_div(head_dim, LANES) * LANES)
         scales = (jnp.ones((n_kv_heads,), jnp.float32)
                   if per_head_scales else None)
         # free pages are 1..P-1 (0 is parking); stack[:free_top] free,
@@ -281,15 +297,35 @@ class PagedKVState:
     def with_scales(self, k_scale, v_scale) -> "PagedKVState":
         return dataclasses.replace(self, k_scale=k_scale, v_scale=v_scale)
 
+    # -- layers -----------------------------------------------------------
+
+    def at_layer(self, i) -> "PagedKVState":
+        """Layer ``i`` of a pool stacked over layers: the bookkeeping
+        leaves sliced at ``i``, ``k``/``v`` kept whole with ``layer =
+        i``."""
+        small = {f: getattr(self, f)[i] for f in _BOOKKEEPING
+                 if getattr(self, f) is not None}
+        return dataclasses.replace(self, layer=jnp.asarray(i, jnp.int32),
+                                   **small)
+
+    def put_layer(self, view: "PagedKVState", i) -> "PagedKVState":
+        """Store ``at_layer(i)``'s updated ``view`` back into this stack:
+        the bookkeeping written at ``i``, ``k``/``v`` taken whole (the
+        view's writes already landed in the stacked pool)."""
+        small = {f: jax.lax.dynamic_update_index_in_dim(
+                     getattr(self, f), getattr(view, f), i, 0)
+                 for f in _BOOKKEEPING if getattr(self, f) is not None}
+        return dataclasses.replace(self, k=view.k, v=view.v, **small)
+
     # -- geometry ---------------------------------------------------------
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[-2]
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[0]
+        return self.k.shape[-4]
 
     @property
     def pages_per_seq(self) -> int:
@@ -473,7 +509,12 @@ class PagedKVState:
         refcount gather. Callers guarantee pop headroom the same way they
         do for ``_alloc``: total references (row holds + pins) never
         exceed the allocatable pool, and a COW swap keeps that sum
-        constant."""
+        constant.
+
+        Only the bookkeeping happens here: returns ``(state, (src,
+        dst))``, the page copies for ``_write_rows`` to make (flat
+        ``(B * maxp,)`` page ids, parking to parking where a page is
+        not copied)."""
         ps, cs = self.page_size, self.capacity
         npps = self.pages_per_seq
         b = first.shape[0]
@@ -496,10 +537,7 @@ class PagedKVState:
         sidx = self.free_top - 1 - rank
         fresh = self.free_stack[jnp.clip(sidx, 0, self.num_pages - 1)] \
             .reshape(b, maxp)
-        src = jnp.where(shared, phys, PARKING_PAGE).reshape(-1)
         dst = jnp.where(shared, fresh, self.num_pages).reshape(-1)
-        k = self.k.at[dst].set(self.k[src], mode="drop")
-        v = self.v.at[dst].set(self.v[src], mode="drop")
         pt = self.page_table.at[bidx, jnp.where(shared, jc, npps)] \
             .set(fresh, mode="drop")
         ref = self.ref_count.at[dst].set(1, mode="drop")
@@ -507,9 +545,56 @@ class PagedKVState:
             .at[jnp.where(shared, phys, self.num_pages).reshape(-1)] \
             .add(1, mode="drop")
         top = self.free_top - jnp.sum(flat.astype(jnp.int32))
-        cow = dataclasses.replace(self, k=k, v=v, page_table=pt,
-                                  ref_count=ref, free_top=top)
-        return cow._decref(dec)
+        cow = dataclasses.replace(self, page_table=pt, ref_count=ref,
+                                  free_top=top)
+        copies = (jnp.where(shared, phys, PARKING_PAGE).reshape(-1),
+                  jnp.where(shared, fresh, PARKING_PAGE).reshape(-1))
+        return cow._decref(dec), copies
+
+    def _write_rows(self, k_q: jax.Array, v_q: jax.Array,
+                    table: jax.Array, first: jax.Array, n: jax.Array,
+                    copies=None) -> "PagedKVState":
+        """Land row ``i``'s first ``n[i]`` presented tokens ``k_q[i]``/
+        ``v_q[i]`` (``(R, S, G, hd)``) at ring slots ``first[i] ..
+        first[i] + n[i] - 1`` of the pages its ``table[i]`` names, after
+        the copy-on-write page ``copies`` — in place, through
+        ``ita_kv_write``. One job per ``(G, tile, hd)`` tile a row's
+        tokens can touch; a tile nothing lands in is no job (parking)."""
+        ps, cs = self.page_size, self.capacity
+        r, s = k_q.shape[:2]
+        tile = write_tile(ps)
+        nt = min((s + tile - 2) // tile + 1, cs // tile)
+        f = (jnp.asarray(first, jnp.int32) % cs)[:, None]
+        row0 = ((f // tile) * tile
+                + tile * jnp.arange(nt, dtype=jnp.int32)[None, :]) % cs
+        # the presented token each tile row takes, and whether it is real
+        src = (row0[:, :, None] + jnp.arange(tile, dtype=jnp.int32)
+               - f[:, :, None]) % cs                        # (R, nt, tile)
+        take = src < jnp.asarray(n, jnp.int32)[:, None, None]
+        live = take.any(-1)
+        pages = jnp.where(live, jnp.take_along_axis(table, row0 // ps, 1),
+                          PARKING_PAGE)
+        tiles = jnp.where(live, (row0 % ps) // tile, 0)
+        bits = jax.lax.bitcast_convert_type(jnp.sum(
+            take.astype(jnp.uint32)
+            << jnp.arange(tile, dtype=jnp.uint32), axis=-1), jnp.int32)
+        idx = (jnp.arange(r, dtype=jnp.int32)[:, None, None],
+               jnp.minimum(src, s - 1))
+
+        def as_tiles(x):                      # (R * nt, G, tile, lanes)
+            x = x[idx].swapaxes(2, 3)
+            x = jnp.pad(x, [(0, 0)] * 4 + [(0, self.k.shape[-1]
+                                            - x.shape[-1])])
+            return x.reshape(r * nt, *x.shape[2:])
+
+        stacked = self.layer is not None
+        k, v = (self.k, self.v) if stacked else (self.k[None], self.v[None])
+        cow_src, cow_dst = (None, None) if copies is None else copies
+        k, v = ita_kv_write(k, v, self.layer if stacked else 0, cow_src,
+                            cow_dst, pages.reshape(-1), tiles.reshape(-1),
+                            bits.reshape(-1), as_tiles(k_q), as_tiles(v_q))
+        return dataclasses.replace(self, k=k if stacked else k[0],
+                                   v=v if stacked else v[0])
 
     # -- writes -----------------------------------------------------------
 
@@ -568,26 +653,14 @@ class PagedKVState:
                                                        mode="drop")
         new = self._alloc(need)
 
-        t = jnp.arange(s, dtype=jnp.int32)
-        # rows == b clamps in the gather; the result is discarded below.
-        # Columns past the window (S > capacity sources) clamp to the last
-        # logical page — always pad columns, dropped below.
-        cols = jnp.minimum(t // ps, self.pages_per_seq - 1)
-        phys = new.page_table[jnp.minimum(rows, b - 1)][:, cols]     # (n, s)
-        real = valid[:, None] & (t[None, :] < new_pos[:, None])
-        # pad columns / dummy rows: OOB page index + mode="drop" discards
-        # the write entirely — nothing ever scatters into the parking
-        # page (its bytes stay zero), and with the duplicate parking
-        # targets gone the scatter is duplicate-free, i.e. deterministic
-        # rather than relying on an unspecified duplicate winner
-        phys = jnp.where(real, phys, self.num_pages)
-        slot = jnp.broadcast_to((t % ps)[None, :], (n, s))
-        # (page, head, slot) scatter: the separated advanced indices put
-        # the (n, s) index dims first, matching k_q's (n, s, G, hd)
-        k_t = new.k.at[phys, :, slot].set(k_q, mode="drop")
-        v_t = new.v.at[phys, :, slot].set(v_q, mode="drop")
+        # rows == b clamps in the gather; a dummy row writes nothing
+        # (new_pos 0), and neither do columns past a row's length, so the
+        # parking page's bytes stay zero
+        table = new.page_table[jnp.minimum(rows, b - 1)]
+        new = new._write_rows(k_q, v_q, table, jnp.zeros((n,), jnp.int32),
+                              new_pos)
         pos = self.pos.at[rows].set(new_pos, mode="drop")
-        return dataclasses.replace(new, k=k_t, v=v_t, pos=pos)
+        return dataclasses.replace(new, pos=pos)
 
     @functools.partial(jax.named_call, name="kv_write")
     def decode_append(self, k_q: jax.Array, v_q: jax.Array,
@@ -610,21 +683,15 @@ class PagedKVState:
         live_i = live.astype(jnp.int32)
         start = max(s_new - cs, 0)
         n_eff = s_new - start
-        state = self._cow(self.pos + start, n_eff * live_i, n_eff)
+        state, copies = self._cow(self.pos + start, n_eff * live_i, n_eff)
         held = state.pages_held()
         want = jnp.minimum(_ceil_div(state.pos + s_new, ps),
                            state.pages_per_seq)
         new = state._alloc((want - held) * live_i)
-
-        toks = (state.pos[:, None] + start
-                + jnp.arange(n_eff, dtype=jnp.int32)[None, :]) % cs
-        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        phys = new.page_table[bidx, toks // ps]            # (B, n_eff)
-        phys = jnp.where(live[:, None], phys, self.num_pages)  # drop dead
-        k_t = new.k.at[phys, :, toks % ps].set(k_q[:, start:], mode="drop")
-        v_t = new.v.at[phys, :, toks % ps].set(v_q[:, start:], mode="drop")
-        return dataclasses.replace(new, k=k_t, v=v_t,
-                                   pos=state.pos + s_new * live_i)
+        new = new._write_rows(k_q[:, start:], v_q[:, start:],
+                              new.page_table, state.pos + start,
+                              n_eff * live_i, copies)
+        return dataclasses.replace(new, pos=state.pos + s_new * live_i)
 
     @functools.partial(jax.named_call, name="kv_write")
     def append_chunk(self, k_q: jax.Array, v_q: jax.Array,
@@ -647,22 +714,14 @@ class PagedKVState:
                 f"append_chunk width {s} exceeds the per-sequence window "
                 f"{cs}; split the chunk (serving sizes chunk <= capacity)")
         n_new = jnp.clip(jnp.asarray(n_new, jnp.int32).reshape(b), 0, s)
-        state = self._cow(self.pos, n_new, s)
+        state, copies = self._cow(self.pos, n_new, s)
         held = state.pages_held()
         want = jnp.minimum(_ceil_div(state.pos + n_new, ps),
                            state.pages_per_seq)
         new = state._alloc(want - held)
-
-        cols = jnp.arange(s, dtype=jnp.int32)[None, :]
-        toks = (state.pos[:, None] + cols) % cs            # (B, S)
-        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        real = cols < n_new[:, None]
-        phys = jnp.where(real, new.page_table[bidx, toks // ps],
-                         self.num_pages)                   # pad -> drop
-        k_t = new.k.at[phys, :, toks % ps].set(k_q, mode="drop")
-        v_t = new.v.at[phys, :, toks % ps].set(v_q, mode="drop")
-        return dataclasses.replace(new, k=k_t, v=v_t,
-                                   pos=state.pos + n_new)
+        new = new._write_rows(k_q, v_q, new.page_table, state.pos, n_new,
+                              copies)
+        return dataclasses.replace(new, pos=state.pos + n_new)
 
     # -- debug ------------------------------------------------------------
 
@@ -720,8 +779,12 @@ class PagedKVState:
 jax.tree_util.register_dataclass(
     PagedKVState,
     data_fields=("k", "v", "page_table", "pos", "free_stack", "free_top",
-                 "ref_count", "k_scale", "v_scale"),
+                 "ref_count", "k_scale", "v_scale", "layer"),
     meta_fields=())
+
+# the per-layer leaves ``at_layer`` slices (a few KB; k/v stay whole)
+_BOOKKEEPING = ("page_table", "pos", "free_stack", "free_top", "ref_count",
+                "k_scale", "v_scale")
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,15 @@ BLOCK_DEFAULTS = {
 # capacities above one block to it.
 MIN_BLOCK_KV = 128
 
+# Vector lanes of a TPU tile: the paged KV pool's minor (head) dim is
+# padded to a multiple of it (``PagedKVState.init``). A row-major int8
+# ``(page, hd)`` page occupies whole 128-lane tiles in HBM whatever its
+# ``hd``, so the padding costs no memory over the row-major layout the
+# paged kernels read; without it, XLA's default layout for ``hd < 128``
+# puts ``hd`` major (more compact, and unreadable by the kernels), and
+# every program that hands the pool to a kernel copies it whole.
+LANES = 128
+
 
 def default_blocks(backend: str) -> tuple:
     """(block_q, block_kv) defaults for a fused *attention* backend name

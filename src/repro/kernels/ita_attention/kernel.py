@@ -52,11 +52,13 @@ mixed serve call carries decode rows (q_len 1) next to chunked-prefill
 rows (q_len = chunk). Scalars broadcast to all rows (the dense case).
 
 Paged KV pool: the ``*_paged`` entry points consume one shared
-head-major ``(num_pages, G, page_size, hd)`` int8 arena (each block is
-one kv head's ``(page_size, hd)`` page, a layout Mosaic tiles) through a
-**page table** delivered as a flat scalar-prefetch operand — the KV
-BlockSpec index map reads ``page_table[b * n_pages + j]`` to translate
-logical KV tile ``j`` of sequence ``b`` into a physical arena page, so
+head-major ``(num_pages, G, page_size, hd)`` int8 arena per layer,
+stacked over the model's layers as ``(L, num_pages, G, page_size, hd)``
+(each block is one kv head's ``(page_size, hd)`` page, a layout Mosaic
+tiles) through a **page table** delivered as a flat scalar-prefetch
+operand, next to the layer index — the KV BlockSpec index map reads
+``page_table[b * n_pages + j]`` to translate logical KV tile ``j`` of
+sequence ``b`` into a physical page of layer ``layer``'s arena, so
 scattered pages stream through the very
 same kernel bodies (``decode_kernel``/``onepass_kernel``) tile-for-tile.
 With ``block_kv == page_size`` the DA tile schedule is identical to the
@@ -132,8 +134,8 @@ def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive):
 
 def _kv_tile(ref, paged):
     """(bkv, d) tile of a K/V block: (1, bkv, d) rows of the 3-D kernel
-    layout, or (1, 1, page, d) of the head-major paged pool."""
-    return ref[0, 0] if paged else ref[0]
+    layout, or (1, 1, 1, page, d) of the stacked head-major paged pool."""
+    return ref[0, 0, 0] if paged else ref[0]
 
 
 def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
@@ -411,34 +413,39 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
 # ---------------------------------------------------------------------------
 
 def _swallow_pt(kern):
-    """Scalar-prefetch calling convention hands the page-table ref to the
-    kernel body as its first argument; the compute bodies never touch it
-    (all translation happens in the index maps), so drop it here — the
-    paged kernels stay byte-for-byte the ring kernels."""
-    def wrapped(pt_ref, *refs):
+    """Scalar-prefetch calling convention hands the page-table and layer
+    refs to the kernel body as its first arguments; the compute bodies
+    never touch them (all translation happens in the index maps), so
+    drop them here — the paged kernels stay byte-for-byte the ring
+    kernels."""
+    def wrapped(pt_ref, layer_ref, *refs):
         return kern(*refs)
     return wrapped
 
 
 def _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis):
-    """K/V BlockSpec of the head-major pool: kernel row ``r`` (batch
-    ``r // hq``, head ``r % hq``) reads its kv head's page
-    ``pt[(r // hq) * n_pages + j]`` for logical KV tile ``j``."""
-    def page_of(r, j, pt):
-        return (pt[(r // hq) * n_pages + j], (r % hq) // kv_rep, 0, 0)
+    """K/V BlockSpec of the stacked head-major pool: kernel row ``r``
+    (batch ``r // hq``, head ``r % hq``) reads its kv head's page
+    ``pt[(r // hq) * n_pages + j]`` of layer ``layer[0]`` for logical KV
+    tile ``j``."""
+    def page_of(r, j, pt, layer):
+        return (layer[0], pt[(r // hq) * n_pages + j], (r % hq) // kv_rep,
+                0, 0)
     if with_q_axis:
-        return pl.BlockSpec((1, 1, page, d),
-                            lambda r, i, j, pt: page_of(r, j, pt))
-    return pl.BlockSpec((1, 1, page, d), page_of)
+        return pl.BlockSpec((1, 1, 1, page, d),
+                            lambda r, i, j, pt, layer: page_of(r, j, pt,
+                                                               layer))
+    return pl.BlockSpec((1, 1, 1, page, d), page_of)
 
 
 def _paged_call(name, kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
-                page_table, lmult, omult, meta, bq, interpret):
+                page_table, layer, lmult, omult, meta, bq, interpret):
     """Shared pallas_call of the paged kernels, named ``name``: the flat
-    page table is the scalar-prefetch operand the K/V index maps read."""
+    page table and the layer index are the scalar-prefetch operands the
+    K/V index maps read."""
     bh, sq, d = q_q.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
         out_specs=q_spec,
@@ -451,31 +458,34 @@ def _paged_call(name, kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
         interpret=interpret,
         name=name,
-    )(page_table.reshape(-1), q_q, k_pool, v_pool, lmult, omult, meta)
+    )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1), q_q,
+      k_pool, v_pool, lmult, omult, meta)
 
 
 def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
-                               out_mult, kv_len, *, q_offset=0, q_len=None,
-                               causal: bool = True, window: int = 0,
-                               adaptive: bool = True, kv_rep: int = 1,
-                               hq: int = 1, interpret: bool = True):
+                               out_mult, kv_len, *, layer=0, q_offset=0,
+                               q_len=None, causal: bool = True,
+                               window: int = 0, adaptive: bool = True,
+                               kv_rep: int = 1, hq: int = 1,
+                               interpret: bool = True):
     """Fused decode step over a paged KV pool.
 
     ``q_q`` (BH, Sq<=8, D) int8; ``k_pool``/``v_pool``
-    ``(num_pages, G, page_size, D)`` int8 shared head-major arena;
+    ``(L, num_pages, G, page_size, D)`` int8 shared head-major arenas,
+    one per layer, of which ``layer`` (() int32) is read;
     ``page_table`` ``(B, n_pages)`` int32 maps each sequence's logical KV
     page to a physical arena page (entries beyond the valid prefix may
     point anywhere — those tiles are skipped/masked via ``kv_len``).
 
     ``block_kv`` is the page size: logical tile ``j`` of kernel row ``r``
-    is DMA'd from ``pool[page_table[r // hq, j], kv head]`` by a
+    is DMA'd from ``pool[layer, page_table[r // hq, j], kv head]`` by a
     scalar-prefetch index map, and the DA streaming schedule is identical
     to ``ita_attention_decode`` at ``block_kv == page_size`` — paged
     decode is bit-identical to the contiguous ring path (family
     ``ita_fused``).
     """
     bh, sq, d = q_q.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     n_pages = page_table.shape[1]
     assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
         (bh, hq, page_table.shape)
@@ -485,26 +495,28 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     return _paged_call(
         "ita_decode_paged", kern, (bh, n_pages),
-        pl.BlockSpec((1, sq, d), lambda b, j, pt: (b, 0, 0)),
+        pl.BlockSpec((1, sq, d), lambda b, j, *_: (b, 0, 0)),
         _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=False),
-        q_q, k_pool, v_pool, page_table, lmult, omult, meta, sq, interpret)
+        q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, sq,
+        interpret)
 
 
 def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
-                                out_mult, kv_len, *, q_offset=0, q_len=None,
-                                causal: bool, window: int = 0,
+                                out_mult, kv_len, *, layer=0, q_offset=0,
+                                q_len=None, causal: bool, window: int = 0,
                                 adaptive: bool = True, block_q: int = 128,
                                 kv_rep: int = 1, hq: int = 1,
                                 interpret: bool = True):
     """Flash-style onepass over a paged KV pool (prefill-from-pool, decode
     bursts longer than the decode kernel's single tile, and the mixed
-    chunked-prefill/decode serve step). Grid and page translation as in
+    chunked-prefill/decode serve step). Grid, layer and page translation
+    as in
     ``ita_attention_decode_paged``, with the q tiling axis of
     ``ita_attention_onepass`` restored. ``q_len`` (scalar or per-row)
     marks each row's count of valid query rows — ragged q_len: one call
     serves rows with q widths in {1, chunk} (pad rows emit zeros)."""
     bh, sq, d = q_q.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[3]
     n_pages = page_table.shape[1]
     bq = min(block_q, sq)
     assert sq % bq == 0, (sq, bq)
@@ -516,6 +528,7 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     return _paged_call(
         "ita_onepass_paged", kern, (bh, sq // bq, n_pages),
-        pl.BlockSpec((1, bq, d), lambda b, i, j, pt: (b, i, 0)),
+        pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
         _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=True),
-        q_q, k_pool, v_pool, page_table, lmult, omult, meta, bq, interpret)
+        q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, bq,
+        interpret)

@@ -90,10 +90,13 @@ def _per_row(x, b, h):
     "interpret"))
 def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
            causal, window, kind, adaptive, block_q, block_kv,
-           interpret, page_table=None, q_lens=None):
+           interpret, page_table=None, q_lens=None, layer=None):
     b, hq, sq, d = q_q.shape
-    # (B, Hkv, Skv, D), or the paged pool (P, Hkv, page, D): heads at 1
-    hkv, skv = k_q.shape[1], k_q.shape[2]
+    if page_table is not None and k_q.ndim == 4:
+        # one layer's pool (P, Hkv, page, D): the stack of one
+        k_q, v_q, layer = k_q[None], v_q[None], 0
+    # (B, Hkv, Skv, D), or the stacked paged pool (L, P, Hkv, page, D)
+    hkv, skv = k_q.shape[-3], k_q.shape[-2]
     assert hq % hkv == 0, (hq, hkv)
     rep = hq // hkv
 
@@ -108,16 +111,19 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
     if page_table is not None:
         # Pages are blocks: block_kv == page_size by construction, so the
         # pool is never padded/copied — tiles stream straight from the
-        # arena through the page-table index maps.
+        # arena through the page-table index maps. A lane-padded pool
+        # (head dim past d) meets q padded alike: the zero lanes add
+        # nothing to Q·Kᵀ, and the output's extra lanes are dropped.
         bq = min(block_q, max(8, sq))
         qf = _pad_seq(q_q.reshape(b * hq, sq, d), bq)
-        skv = page_table.shape[1] * k_q.shape[2]
+        qf = jnp.pad(qf, [(0, 0), (0, 0), (0, k_q.shape[-1] - d)])
+        skv = page_table.shape[1] * skv
         kv_len = _per_row(skv if kv_len is None else kv_len, b, hq)
         q_offset = _per_row(q_offset, b, hq)
         q_len = None if q_lens is None else _per_row(q_lens, b, hq)
-        common = dict(q_offset=q_offset, q_len=q_len, causal=causal,
-                      window=window, adaptive=adaptive, kv_rep=rep, hq=hq,
-                      interpret=interpret)
+        common = dict(layer=layer, q_offset=q_offset, q_len=q_len,
+                      causal=causal, window=window, adaptive=adaptive,
+                      kv_rep=rep, hq=hq, interpret=interpret)
         if kind == "decode":
             out = ita_attention_decode_paged(
                 qf, k_q, v_q, page_table, lmult, omult, kv_len, **common)
@@ -125,7 +131,7 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
             out = ita_attention_onepass_paged(
                 qf, k_q, v_q, page_table, lmult, omult, kv_len, block_q=bq,
                 **common)
-        return out[:, :sq].reshape(b, hq, sq, d)
+        return out[:, :sq, :d].reshape(b, hq, sq, d)
 
     bq = min(block_q, max(8, sq))
     bkv = min(block_kv, max(128, skv)) if skv >= 128 else skv
@@ -163,6 +169,7 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
                     kind: str = "onepass", adaptive: bool = True,
                     block_q: int = 128, block_kv: int = 128,
                     page_table: jax.Array | None = None,
+                    layer: jax.Array | int | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """Quantized multi-head attention with the ITA integer softmax.
 
@@ -174,7 +181,10 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     sequence ``b`` streams from physical page ``page_table[b, j]``
     (scalar-prefetch index maps; ``block_kv`` is the page size — the
     ``block_kv`` argument is ignored). Bit-identical to the contiguous
-    ring path when ``page_size`` equals the ring's ``block_kv``.
+    ring path when ``page_size`` equals the ring's ``block_kv``. A pool
+    stacked over layers, ``(L, num_pages, Hkv, page_size, D)``, is read
+    at ``layer`` (() int32) — the model's pools travel whole through its
+    layer scan.
     ``q_offset``: logical position of query 0 (decode: valid_kv - Sq).
     ``kv_len``: valid prefix of the KV cache (defaults to Skv).
     Both accept (B,) per-sequence vectors — the ragged batch path: each
@@ -193,5 +203,5 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     return _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, q_offset=q_offset,
                   kv_len=kv_len, causal=causal, window=window, kind=kind,
                   adaptive=adaptive, block_q=block_q, block_kv=block_kv,
-                  page_table=page_table,
-                  q_lens=q_lens, interpret=resolve_interpret(interpret))
+                  page_table=page_table, q_lens=q_lens, layer=layer,
+                  interpret=resolve_interpret(interpret))

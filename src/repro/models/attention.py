@@ -161,7 +161,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
 
     def run(qq, kk, vv, *, mode, causal=causal, window=window,
             q_offset=0, kv_len=None, layout="bshd", page_table=None,
-            q_lens=None):
+            q_lens=None, layer=None):
         q_len = qq.shape[2] if layout == "bhsd_paged" else qq.shape[1]
         spec = make_spec(cfg, mode=mode, causal=causal, window=window,
                          q_len=q_len, has_s_out=scales.s_out is not None,
@@ -177,7 +177,8 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
             out = ATT.dispatch(qq, kk, vv, spec=spec, scales=scales,
                                q_offset=q_offset, kv_len=kv_len,
                                page_table=page_table, q_lens=q_lens,
-                               backend=backend, q_chunk=cfg.attn_q_chunk,
+                               layer=layer, backend=backend,
+                               q_chunk=cfg.attn_q_chunk,
                                kv_chunk=cfg.attn_kv_chunk,
                                scan_unroll=cfg.scan_unroll)
             return out.astype(dt)
@@ -208,7 +209,8 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
         y = run(jnp.swapaxes(q, 1, 2), new_cache.k, new_cache.v,
                 mode=mode, q_offset=new_cache.q_offset(n_new),
                 kv_len=new_cache.valid_len(), layout="bhsd_paged",
-                page_table=new_cache.page_table, q_lens=n_new)
+                page_table=new_cache.page_table, q_lens=n_new,
+                layer=new_cache.layer)
         y = jnp.swapaxes(y, 1, 2)
     else:                                           # decode append
         s_new = q.shape[1]
@@ -219,7 +221,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
             y = run(jnp.swapaxes(q, 1, 2), new_cache.k, new_cache.v,
                     mode=mode, q_offset=new_cache.q_offset(s_new),
                     kv_len=new_cache.valid_len(), layout="bhsd_paged",
-                    page_table=new_cache.page_table)
+                    page_table=new_cache.page_table, layer=new_cache.layer)
             y = jnp.swapaxes(y, 1, 2)
         else:
             y = run(q, new_cache.k, new_cache.v, mode=mode,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.attention import KVCacheState, PagedKVState
 from repro.launch import hints
 from repro.models import attention as A
 from repro.models import moe as MOE
@@ -86,7 +87,6 @@ def init_block_cache(cfg, kind: str, batch: int, max_len: int,
     shared arena + page tables per layer) instead of per-sequence rings —
     the continuous-batching layout; ``num_pages`` sizes each layer's
     arena (None = fully provisioned)."""
-    from repro.attention import KVCacheState, PagedKVState
     g, hd = cfg.n_kv_heads, cfg.head_dim
     quant = cfg.attention_impl != "float"
     kv_dt = jnp.int8 if quant else cfg.compute_dtype()
@@ -238,7 +238,18 @@ def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
     writing it back in place (``dynamic_update_index_in_dim``): as scan
     ``xs -> ys`` they would be a second full copy of every layer's KV
     (``ys`` cannot alias ``xs``), which a paged pool at full width cannot
-    afford on one chip."""
+    afford on one chip.
+
+    A paged pool (``PagedKVState``) is not sliced: only its bookkeeping
+    (page tables, positions, free stack, refcounts; a few KB) takes the
+    slice and write-back, while its int8 K/V stay whole, stacked over the
+    periods, and travel with the period index (``at_layer`` /
+    ``put_layer``). The pool write (``ita_kv_write``) and the paged
+    attention kernels address the stack at that index, so the only ops
+    that touch a layer's pool move the pages a step reads or writes; a
+    slice and write-back of the pool itself would copy it whole every
+    layer of every step. Ring caches and recurrent states keep the
+    slice."""
 
     def blocks(xc, aux, pparams, pcache):
         xc = hints.constrain(xc, "batch", "seq", None)   # seq-parallel
@@ -274,16 +285,22 @@ def apply_group(params, x, cfg, pattern, *, positions, mem, caches, mode,
         (x, aux), _ = jax.lax.scan(body, (x, aux0), params, unroll=unroll)
         return x, None, aux
 
+    def is_pool(c):
+        return isinstance(c, PagedKVState)
+
     def body_cached(carry, xs):
         xc, aux, all_caches = carry
         pparams, i = xs
         with jax.named_scope("layer_carry"):
-            pcache = jax.tree.map(lambda c: c[i], all_caches)
+            pcache = jax.tree.map(
+                lambda c: c.at_layer(i) if is_pool(c) else c[i],
+                all_caches, is_leaf=is_pool)
         xc, aux, new = blocks(xc, aux, pparams, pcache)
         with jax.named_scope("layer_carry"):
             all_caches = jax.tree.map(
-                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
-                all_caches, new)
+                lambda c, n: c.put_layer(n, i) if is_pool(c)
+                else jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                all_caches, new, is_leaf=is_pool)
         return (xc, aux, all_caches), None
 
     (x, aux, new_caches), _ = jax.lax.scan(

@@ -584,9 +584,9 @@ def _adopt_prompts(pool, temp, slot_ids, lengths):
 
     def one(p, t):
         if isinstance(p, PagedKVState):
-            return jax.vmap(
-                lambda pp, tt: pp.write_prompts(tt.k, tt.v, lengths=lengths,
-                                                slots=slot_ids))(p, t)
+            for i in range(p.k.shape[0]):
+                p = p.put_layer(p.at_layer(i).write_prompts(
+                    t.k[i], t.v[i], lengths=lengths, slots=slot_ids), i)
         return p
 
     return jax.tree.map(one, pool, temp, is_leaf=_is_kv_state)

@@ -145,3 +145,56 @@ def test_norm_sums_its_row_without_a_reduce(one_chip, no_persistent_cache):
         sums = [line for line in text.splitlines()
                 if " reduce(" in line and "reduce_sum" in line]
         assert not sums, sums
+
+
+@pytest.mark.parametrize("head_dim", [96, 128])
+def test_serve_segments_copy_no_pool(one_chip, no_persistent_cache,
+                                     monkeypatch, head_dim):
+    """In the compiled decode and mixed serve segments, no op but the
+    kernels touches a whole layer's KV pool: no copy, dynamic slice or
+    dynamic-update-slice (fused or not) has the pool's shape, one layer's
+    or the stack's. The pool write and both attention kernels are there,
+    compiled. Two layers of four heads, page 128, 16 slots of one page
+    (17 pages with the parking page)."""
+    import re
+
+    from repro.configs.base import ModelConfig
+    from repro.launch.steps import ServeSlotState
+    from repro.models import init_caches, init_model
+    from repro.runtime.generate import _serve_segment_fn
+
+    monkeypatch.setenv("ITA_PALLAS_INTERPRET", "0")
+    cfg = ModelConfig(name=f"poolcopy-hd{head_dim}", family="dense",
+                      d_model=256, n_heads=4, n_kv_heads=4,
+                      head_dim=head_dim, d_ff=512, vocab_size=256,
+                      layer_groups=((("attn",), 2),), dtype="bfloat16",
+                      attention_impl="ita")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    state = on_chip(jax.eval_shape(lambda: ServeSlotState.init(16, 128)))
+    caches = on_chip(jax.eval_shape(lambda: init_caches(
+        cfg, 16, max_len=128, paged=True, page_size=128)))
+    pool = caches[0][0]["mix"].k.shape
+    assert pool[:4] == (2, 17, 4, 128), pool
+    dims = ",".join(map(str, pool[1:]))
+    shaped = re.compile(rf"= s8\[(2,)?{dims}\]\S* (copy|copy-start|"
+                        rf"dynamic-slice|dynamic-update-slice|fusion)\(")
+    temp = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    for chunk, kernel in ((None, "ita_decode_paged"),
+                          (32, "ita_onepass_paged")):
+        fn = _serve_segment_fn(cfg, 4, False, None, 0, chunk,
+                               None if chunk is None else 512,
+                               None if chunk is None else 4)
+        text = fn.lower(params, state, caches, temp).compile().as_text()
+        whole = [line.strip()[:160] for line in text.splitlines()
+                 if shaped.search(line)]
+        assert not whole, "\n".join(whole)
+        calls = " ".join(line.split("=", 1)[0] for line in text.splitlines()
+                         if 'custom_call_target="tpu_custom_call"' in line)
+        for name in ("ita_kv_write", kernel):
+            assert f"%{name}" in calls, (name, calls)

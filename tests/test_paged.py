@@ -10,6 +10,8 @@ physical page is ever double-booked, released pages return to the free
 stack, and realloc reuses them without leaking state.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -130,12 +132,16 @@ def test_paged_layout_capability_matrix():
 # State: logical ring equivalence + allocator properties
 # ---------------------------------------------------------------------------
 
-def _logical_view(p: PagedKVState):
+def _logical_view(p: PagedKVState, hd: int):
+    """(B, capacity, G, hd) bytes the page tables map; the pool's lanes
+    past ``hd`` (its minor dim is lane-padded) must hold zeros."""
     pt = np.asarray(p.page_table)
-    g, hd = p.k.shape[1], p.k.shape[3]
-    # (B, n_pages, G, page, hd) -> (B, n_pages * page, G, hd)
-    return np.asarray(p.k)[pt].swapaxes(2, 3).reshape(p.batch, p.capacity,
-                                                      g, hd)
+    g, lanes = p.k.shape[1], p.k.shape[3]
+    # (B, n_pages, G, page, lanes) -> (B, n_pages * page, G, lanes)
+    view = np.asarray(p.k)[pt].swapaxes(2, 3).reshape(p.batch, p.capacity,
+                                                      g, lanes)
+    assert not view[..., hd:].any(), "pool lanes past head_dim written"
+    return view[..., :hd]
 
 
 def test_paged_state_matches_ring_through_wrap():
@@ -163,7 +169,7 @@ def test_paged_state_matches_ring_through_wrap():
                                   np.asarray(paged.valid_len()))
     np.testing.assert_array_equal(np.asarray(ring.q_offset(1)),
                                   np.asarray(paged.q_offset(1)))
-    lv, rv = _logical_view(paged), np.asarray(ring.k)
+    lv, rv = _logical_view(paged, hd), np.asarray(ring.k)
     for row in range(b):
         n, pos = int(ring.valid_len()[row]), int(ring.pos[row])
         for t in range(pos - n, pos):
@@ -220,9 +226,9 @@ def test_page_free_and_realloc_reuse():
                         lengths=jnp.asarray([9]),
                         slots=jnp.asarray([0]))
     assert int(p.pos[0]) == 9 and _partition_ok(p)
-    np.testing.assert_array_equal(_logical_view(p)[0, :9], fresh[0])
+    np.testing.assert_array_equal(_logical_view(p, hd)[0, :9], fresh[0])
     # row 1 untouched by the realloc
-    np.testing.assert_array_equal(_logical_view(p)[1, :12], a[1])
+    np.testing.assert_array_equal(_logical_view(p, hd)[1, :12], a[1])
 
 
 def test_allocator_partition_property_seeded():
@@ -291,7 +297,7 @@ def test_burst_and_overlong_append_match_ring():
                                     jnp.asarray(toks[:, lo:hi]))
         np.testing.assert_array_equal(np.asarray(ring.pos),
                                       np.asarray(paged.pos))
-        lv, rv = _logical_view(paged), np.asarray(ring.k)
+        lv, rv = _logical_view(paged, hd), np.asarray(ring.k)
         pos, n = int(ring.pos[0]), int(ring.valid_len()[0])
         for t in range(pos - n, pos):
             np.testing.assert_array_equal(lv[0, t % cap], rv[0, t % cap],
@@ -458,7 +464,8 @@ def test_append_chunk_straddling_pages_during_neighbor_cow():
     unshared = mk().write_prompts(jnp.asarray(prompts), jnp.asarray(prompts))
     unshared = unshared.append_chunk(jnp.asarray(toks), jnp.asarray(toks),
                                      jnp.asarray(n_new))
-    lv_c, lv_r, lv_u = (_logical_view(x) for x in (chunked, ref, unshared))
+    lv_c, lv_r, lv_u = (_logical_view(x, hd)
+                        for x in (chunked, ref, unshared))
     for row in range(b):
         n = int(chunked.valid_len()[row])
         pos = int(chunked.pos[row])
@@ -697,7 +704,7 @@ def test_append_chunk_equals_sequential_appends():
     np.testing.assert_array_equal(np.asarray(chunked.pages_held()),
                                   np.asarray(ref.pages_held()))
     assert _partition_ok(chunked)
-    lv_c, lv_r = _logical_view(chunked), _logical_view(ref)
+    lv_c, lv_r = _logical_view(chunked, hd), _logical_view(ref, hd)
     for row in range(b):
         n = int(chunked.valid_len()[row])
         pos = int(chunked.pos[row])
@@ -895,3 +902,161 @@ def test_preempt_readmit_evict_cycles_keep_invariants_seeded():
     checked("drain")
     assert not pins and int(p.free_top) == 10, \
         "pages leaked through the preempt/pin cycle"
+
+
+# ---------------------------------------------------------------------------
+# ita_kv_write: the in-place pool write against the XLA scatter it replaced
+# ---------------------------------------------------------------------------
+
+def _xla_append(p: PagedKVState, k_q, v_q, n):
+    """``append_chunk``'s bookkeeping with the XLA writes the in-place
+    kernel replaced: each copy-on-write page as ``k.at[dst].set(k[src])``,
+    then the ``(page, head, slot)`` scatter of the real rows (dead rows
+    and columns past ``n`` dropped)."""
+    ps, cs = p.page_size, p.capacity
+    b, s = k_q.shape[:2]
+    n = jnp.asarray(n, jnp.int32)
+    state, (src, dst) = p._cow(p.pos, n, s)
+    held = state.pages_held()
+    want = jnp.minimum(-(-(state.pos + n) // ps), state.pages_per_seq)
+    new = state._alloc(want - held)
+    dst = jnp.where(dst == 0, p.num_pages, dst)          # parking: no copy
+    k, v = p.k.at[dst].set(p.k[src], mode="drop"), \
+        p.v.at[dst].set(p.v[src], mode="drop")
+    cols = jnp.arange(s, dtype=jnp.int32)[None, :]
+    toks = (state.pos[:, None] + cols) % cs
+    phys = jnp.where(cols < n[:, None],
+                     new.page_table[jnp.arange(b)[:, None], toks // ps],
+                     p.num_pages)
+    lanes = [(0, 0)] * 3 + [(0, p.k.shape[-1] - k_q.shape[-1])]
+    k = k.at[phys, :, toks % ps].set(jnp.pad(k_q, lanes), mode="drop")
+    v = v.at[phys, :, toks % ps].set(jnp.pad(v_q, lanes), mode="drop")
+    return dataclasses.replace(new, k=k, v=v, pos=state.pos + n)
+
+
+def _kv_write_case(case):
+    """(pool, K rows, V rows, n, chunked) for one write pattern: page 64
+    (two 32-row write tiles), two pages per row."""
+    b, g, hd, page, cap = 3, 2, 4, 64, 128
+    prng = np.random.default_rng(sum(map(ord, case)))
+
+    def rows(n, s):
+        return prng.integers(-128, 128, (n, s, g, hd)).astype(np.int8)
+
+    p = PagedKVState.init(b, cap, g, hd, page_size=page,
+                          num_pages=2 * b + 3)
+    if case == "cow-shared-page":
+        # row 1 adopts row 0's first page, then its chunk wraps onto it
+        p = p.write_prompts(jnp.asarray(rows(1, 64)), jnp.asarray(
+            rows(1, 64)), lengths=jnp.asarray([64]), slots=jnp.asarray([0]))
+        p = p.adopt_prefix(jnp.asarray([1]), p.page_table[:1, :1],
+                           jnp.asarray([1]), jnp.asarray([64]))
+        p = p.append_chunk(jnp.asarray(rows(b, 36)), jnp.asarray(
+            rows(b, 36)), jnp.asarray([0, 36, 0]))
+        assert int(p.ref_count[p.page_table[1, 0]]) == 2
+        return p, rows(b, 40), rows(b, 40), [9, 40, 0], True
+    lengths = {"decode": [32, 63, 31], "decode-dead-rows": [32, 63, 31],
+               "chunk-across-page": [30, 60, 10],
+               "wrap": [120, 127, 100]}[case]
+    pre = rows(b, max(lengths))
+    p = p.prefill_write(jnp.asarray(pre), jnp.asarray(pre),
+                        lengths=jnp.asarray(lengths))
+    if case == "decode":
+        return p, rows(b, 1), rows(b, 1), [1, 1, 1], False
+    if case == "decode-dead-rows":
+        return p, rows(b, 1), rows(b, 1), [1, 0, 1], False
+    if case == "chunk-across-page":
+        return p, rows(b, 40), rows(b, 40), [40, 3, 0], True
+    return p, rows(b, 16), rows(b, 16), [16, 9, 16], True      # wrap
+
+
+@pytest.mark.parametrize("case", ["decode", "decode-dead-rows",
+                                  "chunk-across-page", "wrap",
+                                  "cow-shared-page"])
+def test_kv_write_matches_xla_scatter(case):
+    """``decode_append`` / ``append_chunk`` through ``ita_kv_write``
+    leave the pool and its bookkeeping bit-identical to the XLA scatter
+    path: decode rows, dead rows, ragged chunks across tile and page
+    boundaries with padded columns, ring wraps, and a copy-on-write of a
+    shared page in the same call as the rows written into it."""
+    p, k_q, v_q, n, chunked = _kv_write_case(case)
+    if chunked:
+        got = p.append_chunk(jnp.asarray(k_q), jnp.asarray(v_q),
+                             jnp.asarray(n))
+    else:
+        got = p.decode_append(jnp.asarray(k_q), jnp.asarray(v_q),
+                              live=jnp.asarray(n) > 0)
+    want = _xla_append(p, jnp.asarray(k_q), jnp.asarray(v_q), n)
+    for name in ("k", "v", "page_table", "pos", "free_stack", "free_top",
+                 "ref_count"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
+            err_msg=f"{case}: {name}")
+    assert not np.asarray(got.k[0]).any(), "parking page written"
+    got.check_invariants()
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["decode", "ragged"])
+def test_paged_kernels_read_stacked_pool_at_layer(ragged):
+    """The paged kernels on a pool stacked over layers, read at layer
+    ``i``, equal the same call on that layer's pool alone, bit for bit —
+    the layer only moves the index maps."""
+    b, g, hq, hd, page, sq = 2, 2, 4, 16, 32, 8
+    pools = [PagedKVState.init(b, 96, g, hd, page_size=page, num_pages=7)
+             for _ in range(3)]
+    pre = _i8(3, b, 70, g, hd)
+    pools = [p.prefill_write(jnp.asarray(x), jnp.asarray(x[:, ::-1]),
+                             lengths=jnp.asarray([70, 41]))
+             for p, x in zip(pools, pre)]
+    k_stack = jnp.stack([p.k for p in pools])
+    v_stack = jnp.stack([p.v for p in pools])
+    q_len = sq if ragged else 1
+    spec = ATT.AttentionSpec(mode="decode", impl="ita", layout="bhsd_paged",
+                             out_dtype="int8", q_len=q_len, ragged_q=ragged)
+    q = jnp.asarray(_i8(b, hq, q_len, hd))
+    scales = ATT.QuantScales.per_tensor(S_Q, s_out=S_OUT)
+    n_new = jnp.asarray([1, sq]) if ragged else None
+    for layer, p in enumerate(pools):
+        kw = dict(spec=spec, scales=scales, q_offset=p.q_offset(q_len),
+                  kv_len=p.valid_len(), page_table=p.page_table,
+                  q_lens=n_new)
+        one = ATT.dispatch(q, p.k, p.v, **kw)
+        stacked = ATT.dispatch(q, k_stack, v_stack, layer=layer, **kw)
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(one),
+                                      err_msg=f"layer {layer}")
+    with pytest.raises(ValueError, match="rank"):
+        ATT.dispatch(q, k_stack, v_stack, **kw)
+
+
+@pytest.mark.parametrize("admission", ["chunked", "stall"])
+def test_serve_on_lane_padded_pool_matches_solo_generate(admission):
+    """Served through the in-place pool writes, at a head_dim the pool
+    pads to whole lanes (24 of 128), every request's greedy tokens equal
+    generating it alone: the writes and the layer-indexed kernels change
+    nothing about a sequence's arithmetic."""
+    from repro.configs.base import ModelConfig
+    from repro.models import init_model
+    from repro.runtime.generate import (ServeRequest, generate,
+                                        serve_continuous)
+    cfg = ModelConfig(name="kvwrite-lanes", family="dense", d_model=48,
+                      n_heads=4, n_kv_heads=2, head_dim=24, d_ff=96,
+                      vocab_size=128, layer_groups=((("attn",), 2),),
+                      dtype="float32", attention_impl="ita",
+                      attention_backend="ita_onepass_pallas")
+    params = init_model(jax.random.PRNGKey(1), cfg)
+    prng = np.random.default_rng(5)
+    reqs = [ServeRequest(
+        prompt=prng.integers(0, cfg.vocab_size, int(n)).astype(np.int32),
+        gen=int(g), arrival=int(a))
+        for n, g, a in ((9, 6, 0), (40, 3, 0), (3, 8, 1), (21, 5, 2))]
+    res = serve_continuous(params, cfg, reqs, slots=2, segment=4,
+                           max_len=128, page_size=128, admission=admission,
+                           chunk_size=16)
+    assert len(res.completed) == len(reqs)
+    for c in res.completed:
+        r = reqs[c.index]
+        solo = generate(params, cfg, jnp.asarray(r.prompt)[None], r.gen,
+                        max_len=128)
+        np.testing.assert_array_equal(np.asarray(c.tokens),
+                                      np.asarray(solo.tokens)[0],
+                                      err_msg=f"request {c.index}")
