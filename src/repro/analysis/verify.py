@@ -110,6 +110,21 @@ def _softmax_cases(g: Geometry) -> list:
             name=f"ita_softmax_ref/{mode}", backend="ita_softmax",
             desc=f"one-shot jnp reference softmax, {mode} inverse",
             fn=ref_fn, args=[x, m], seeds=list(seeds)))
+
+    def aligned_fn(x, mask):
+        return SM.ita_softmax_aligned_int(x, mask)
+
+    def aligned_stream_fn(x, mask):
+        return SM.ita_softmax_aligned_streaming(x, g.skv // g.bkv, mask)
+
+    cases += [
+        Case(name="ita_softmax_ref/aligned", backend="ita_softmax",
+             desc="one-shot grid-aligned softmax (the fused kernels' "
+                  "arithmetic), paper inverse",
+             fn=aligned_fn, args=[x, m], seeds=list(seeds)),
+        Case(name="ita_softmax_ref/aligned-streaming", backend="ita_softmax",
+             desc=f"grid-aligned streaming DA over {g.skv // g.bkv} parts",
+             fn=aligned_stream_fn, args=[x, m], seeds=list(seeds))]
     return cases
 
 

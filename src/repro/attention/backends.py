@@ -62,13 +62,6 @@ def _quantize(x, scale, head_axis):
     return X.quantize_to_int8(x, s)
 
 
-def _dequantize(x_i8, scale, head_axis):
-    s = jnp.asarray(scale, jnp.float32)
-    if s.ndim:
-        s = s.reshape(_head_shape(x_i8.ndim, head_axis))
-    return x_i8.astype(jnp.float32) * s
-
-
 def _requant_out(out_f, spec: AttentionSpec, scales: QuantScales,
                  head_axis):
     """Float backend output -> the spec's out_dtype (int8 rides s_out)."""
@@ -302,8 +295,12 @@ def _fused_run(kind, q, k, v, spec, scales, q_offset, kv_len, opts):
         k8 = k8.transpose(0, 2, 1, 3)
         v8 = v8.transpose(0, 2, 1, 3)
     dbq, dbkv = default_blocks(f"ita_{kind}_pallas")
+    # a float spec takes the kernels' f32 output as it is: the int8
+    # requant through s_out would clip every output past 127 * s_out
+    int8_out = spec.out_dtype == "int8"
     out = fused_attention(
-        q8, k8, v8, scales.s_q, scales.s_k, scales.s_v, scales.s_out,
+        q8, k8, v8, scales.s_q, scales.s_k, scales.s_v,
+        scales.s_out if int8_out else None,
         q_offset=q_offset, kv_len=kv_len, q_lens=q_lens, causal=spec.causal,
         window=spec.window, kind=kind, adaptive=spec.softmax == "adaptive",
         block_q=opts.get("block_q", dbq or 128),
@@ -312,9 +309,7 @@ def _fused_run(kind, q, k, v, spec, scales, q_offset, kv_len, opts):
         interpret=opts.get("interpret"))
     if spec.layout == "bshd":
         out = jnp.swapaxes(out, 1, 2)                    # back to (B,S,H,D)
-    if spec.out_dtype == "int8":
-        return out
-    return _dequantize(out, scales.s_out, 2 if spec.layout == "bshd" else 1)
+    return out
 
 
 def _onepass_run(q, k, v, spec, scales, *, q_offset=0, kv_len=None, **opts):

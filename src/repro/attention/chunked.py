@@ -9,7 +9,8 @@ One skeleton, three arithmetics:
                  *same shift-based running-max correction the silicon
                  applies* (training sees deployed semantics).
 - ``ita_int``  — serve path: int8 Q·Kᵀ chunks requantized onto the ITA
-                 logit grid, integer DA (Σ >>= Δmax>>5), fused ``u=128>>k``
+                 logit grid, grid-aligned integer DA (the max kept as its
+                 2^5 bucket, Σ >>= Δbucket, exact), fused ``u=128>>k``
                  numerators, adaptive power-of-two DI — mirrors the Pallas
                  onepass kernel exactly (same semantics at chunk granularity).
 
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 
 from repro.attention.xla import softcap as _softcap
 from repro.core.quant import EPS_MAX, SOFTMAX_SHIFT
-from repro.core.softmax import _ste_floor, _ste_round
+from repro.core.softmax import _ste_floor, _ste_round, ita_bucket
 
 NEG = -1e30
 Q_CHUNK = 512
@@ -113,7 +114,7 @@ def streaming_attention(q, k, v, *, impl, scale, s_q=None, s_k=None,
 
         if impl == "ita_int":
             qc = q_i[:, q0:q0 + cq]
-            carry = (jnp.full((b, g, m_, cq, 1), -256, jnp.int32),
+            carry = (jnp.full((b, g, m_, cq, 1), -8, jnp.int32),
                      jnp.zeros((b, g, m_, cq, 1), jnp.int32),
                      jnp.zeros((b, g, m_, cq, hd), jnp.float32))
         else:
@@ -140,12 +141,13 @@ def streaming_attention(q, k, v, *, impl, scale, s_q=None, s_k=None,
                       else _softcap(acc32.astype(jnp.float32) * fmult,
                                     softcap) / EPS_MAX)
                 lg = jnp.clip(jnp.round(lf), -128, 127).astype(jnp.int32)
-                x = jnp.where(valid, lg, -256)
-                new_m = jnp.maximum(m, jnp.max(x, -1, keepdims=True))
-                delta = jnp.minimum(jax.lax.shift_right_logical(
-                    new_m - m, SOFTMAX_SHIFT), 31)
-                kk = jax.lax.shift_right_logical(new_m - lg, SOFTMAX_SHIFT)
-                kk = jnp.where(valid, jnp.minimum(kk, 31), 31)
+                # grid-aligned DA (core.softmax.ita_da_update_aligned): m
+                # is the running max bucket, every correction exact
+                xb = ita_bucket(lg)
+                new_m = jnp.maximum(m, jnp.max(jnp.where(valid, xb, -8), -1,
+                                               keepdims=True))
+                delta = jnp.clip(new_m - m, 0, 31)
+                kk = jnp.where(valid, jnp.clip(new_m - xb, 0, 31), 31)
                 # u = 128>>k clipped to int8 (127) so the A·V product also
                 # rides the int8 MXU; Σ uses the same clipped numerators so
                 # normalization stays consistent (<=0.8% skew on the max

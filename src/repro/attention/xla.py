@@ -109,11 +109,14 @@ def direct_int(q8, k8, v8, *, s_q, s_k, s_v, scale, impl="ita",
         out = jnp.einsum("bgmqk,bkgd->bqgmd", p, v8.astype(jnp.float32))
         out = out * s_v
     else:                                                   # ITA
+        # the fused kernels' grid-aligned exponent (core.softmax
+        # ita_softmax_aligned_int), so every serve path shares one softmax
         if softmax == "paper":
-            p_int, sigma, _ = S.ita_softmax_int(lq, mask=bmask)
+            p_int, sigma, _ = S.ita_softmax_aligned_int(lq, mask=bmask)
             e_r = jnp.full_like(sigma, 8)
         else:                                               # adaptive
-            p_int, e_r, _ = S.ita_softmax_adaptive_int(lq, mask=bmask)
+            p_int, e_r, _ = S.ita_softmax_adaptive_int(lq, mask=bmask,
+                                                       aligned=True)
         acc_o = jnp.einsum("bgmqk,bkgd->bqgmd", p_int,
                            v8.astype(jnp.int32))            # Σp·v, int32-safe
         out = acc_o.astype(jnp.float32) \

@@ -27,6 +27,11 @@ Modes implemented here (pure jnp references; Pallas kernels in
                              ("wide mode" — the 15-bit HW accumulator is a
                              gate-count constraint, not algorithmic).
 - ``ita_softmax_streaming``  tiled DA/DI/EN with the paper's max-correction.
+- ``ita_softmax_aligned``    beyond-paper: the exponent taken on the 2^5
+                             grid (``(max >> 5) - (x >> 5)``), so streaming
+                             DA (``ita_softmax_aligned_streaming``) equals
+                             one-shot exactly — the fused attention
+                             kernels' arithmetic.
 - ``ita_softmax_bitexact``   15-bit sigma / 16-bit sigma_inv silicon
                              semantics (validates the paper's MAE claim).
 - ``ita_softmax_adaptive``   beyond-paper: per-row power-of-two output scale
@@ -157,6 +162,111 @@ def ita_softmax_streaming(x_q: jax.Array, num_parts: int,
 
 
 # ---------------------------------------------------------------------------
+# Grid-aligned DA — the fused attention kernels' arithmetic (beyond paper)
+# ---------------------------------------------------------------------------
+#
+# The paper's streaming correction does not compose: a key shifted by
+# ``(m_old - x) >> 5`` and later by ``(m_new - m_old) >> 5`` can end up one
+# short of ``(m_new - x) >> 5`` per max update (floor(a) + floor(b) <=
+# floor(a + b)), and Σ's shift drops bits. Over a long context whose max
+# keeps growing that biases every earlier key upwards, by up to 2x per
+# update. Taking the exponent on the 2^5 grid — ``k = (max >> 5) - (x >> 5)``,
+# the difference of the two logits' 3-bit buckets — makes every update a
+# whole number of buckets, so a late correction composes exactly with the
+# earlier shifts: streaming Σ (and the kernels' A·V accumulator) equal the
+# one-shot values bit for bit, whatever order the maxima arrive in. The
+# quantisation is the paper's (one factor of two per bucket), with the
+# bucket edges fixed on the logit grid instead of hung from the row max.
+
+# The masked-logit sentinel's bucket: below every live bucket [-4, 3].
+_NEG_BUCKET = _NEG_SENTINEL >> SOFTMAX_SHIFT
+
+
+def ita_bucket(x_q: jax.Array) -> jax.Array:
+    """``x >> 5`` (arithmetic): a logit's exponent bucket, in [-4, 3] for
+    int8 logits."""
+    return jax.lax.shift_right_arithmetic(x_q.astype(jnp.int32),
+                                          jnp.int32(SOFTMAX_SHIFT))
+
+
+def _aligned_k(xb: jax.Array, row_bucket: jax.Array,
+               mask: jax.Array | None) -> jax.Array:
+    """k = row bucket - key bucket, in [0, 7] on live keys. The clip is an
+    identity on every reachable value (the row's bucket is the largest
+    live one), stated so the range verifier proves the shift legal."""
+    return _apply_mask_k(jnp.clip(row_bucket - xb, 0, _MASK_K), mask)
+
+
+def _masked_bucket_max(xb, mask, axis):
+    if mask is not None:
+        xb = jnp.where(mask, xb, jnp.int32(_NEG_BUCKET))
+    return jnp.max(xb, axis=axis, keepdims=True)
+
+
+def ita_softmax_aligned_int(x_q: jax.Array, mask: jax.Array | None = None,
+                            axis: int = -1):
+    """One-shot grid-aligned ITA softmax. Returns ``(p, sigma, row_bucket)``
+    with ``p`` in units of 2^-8, as ``ita_softmax_int``; ``sigma`` sums the
+    ``256 >> k`` terms with ``k = row_bucket - (x >> 5)``."""
+    xb = ita_bucket(x_q)
+    row_bucket = _masked_bucket_max(xb, mask, axis)
+    k = _aligned_k(xb, row_bucket, mask)
+    terms = jax.lax.shift_right_logical(jnp.int32(_UNIT), k)
+    sigma = jnp.sum(terms, axis=axis, keepdims=True)            # DA
+    sigma_inv = (jnp.int32(1) << _W_INV) // jnp.maximum(sigma, 1)  # DI
+    p = jnp.minimum(jax.lax.shift_right_logical(sigma_inv, k), _UNIT)  # EN
+    return p, sigma, row_bucket
+
+
+def ita_softmax_aligned(x_q: jax.Array, mask: jax.Array | None = None,
+                        axis: int = -1) -> jax.Array:
+    """Grid-aligned ITA softmax as float probabilities (p * 2^-8)."""
+    p, _, _ = ita_softmax_aligned_int(x_q, mask=mask, axis=axis)
+    return p.astype(jnp.float32) * (2.0 ** -B_BITS)
+
+
+def ita_da_update_aligned(carry_bucket: jax.Array, carry_sigma: jax.Array,
+                          part_q: jax.Array,
+                          part_mask: jax.Array | None = None,
+                          axis: int = -1):
+    """One exact streaming DA step on the 2^5 grid: fold a row part into
+    (running max bucket, running sigma). Every term already in ``sigma``
+    is ``2^(8 - k)`` with its final ``k <= 7``, so ``sigma >> delta`` drops
+    no bit and the result equals the one-shot sigma of the parts so far.
+    Start from ``carry_bucket = -8`` (the sentinel's bucket), sigma 0."""
+    xb = ita_bucket(part_q)
+    new_bucket = jnp.maximum(carry_bucket,
+                             _masked_bucket_max(xb, part_mask, axis))
+    delta = jnp.clip(new_bucket - carry_bucket, 0, _MASK_K)
+    k = _aligned_k(xb, new_bucket, part_mask)
+    terms = jax.lax.shift_right_logical(jnp.int32(_UNIT), k)
+    sigma = jax.lax.shift_right_logical(carry_sigma, delta) \
+        + jnp.sum(terms, axis=axis, keepdims=True)
+    return new_bucket, sigma
+
+
+def ita_softmax_aligned_streaming(x_q: jax.Array, num_parts: int,
+                                  mask: jax.Array | None = None
+                                  ) -> jax.Array:
+    """DA over ``num_parts`` chunks of the last axis with the grid-aligned
+    update, then DI and EN: equal to ``ita_softmax_aligned`` exactly."""
+    *lead, n = x_q.shape
+    assert n % num_parts == 0, (n, num_parts)
+    part = n // num_parts
+    run_bucket = jnp.full((*lead, 1), _NEG_BUCKET, jnp.int32)
+    run_sigma = jnp.zeros((*lead, 1), jnp.int32)
+    for i in range(num_parts):                                   # DA
+        sl = slice(i * part, (i + 1) * part)
+        m = None if mask is None else mask[..., sl]
+        run_bucket, run_sigma = ita_da_update_aligned(
+            run_bucket, run_sigma, x_q[..., sl], m)
+    sigma_inv = (jnp.int32(1) << _W_INV) // jnp.maximum(run_sigma, 1)
+    k = _aligned_k(ita_bucket(x_q), run_bucket, mask)            # EN
+    p = jax.lax.shift_right_logical(sigma_inv, k)
+    return p.astype(jnp.float32) * (2.0 ** -B_BITS)
+
+
+# ---------------------------------------------------------------------------
 # Bit-exact silicon mode (15-bit sigma, 16-bit sigma_inv)
 # ---------------------------------------------------------------------------
 
@@ -186,7 +296,7 @@ def ita_softmax_bitexact(x_q: jax.Array, num_parts: int = 1,
 # ---------------------------------------------------------------------------
 
 def ita_softmax_adaptive_int(x_q: jax.Array, mask: jax.Array | None = None,
-                             axis: int = -1):
+                             axis: int = -1, aligned: bool = False):
     """ITA softmax with a per-row power-of-two output scale.
 
     The paper's fixed ``sigma_inv = 2^16/sigma`` underflows to 0 when
@@ -195,10 +305,17 @@ def ita_softmax_adaptive_int(x_q: jax.Array, mask: jax.Array | None = None,
     ``e_r = floor(log2 sigma)`` and compute ``sigma_inv = 2^(e_r+8)/sigma``
     in (128, 256], so ``softmax ~= p * 2^-e_r``. All operations remain
     shifts + one divide; the per-row 2^-e_r folds into the A.V output
-    requant as a row shift. Returns ``(p, e_r, row_max)``.
+    requant as a row shift. Returns ``(p, e_r, row_max)``. ``aligned``
+    takes the exponent on the 2^5 grid (``ita_softmax_aligned_int``; then
+    ``row_max`` is the row's max bucket).
     """
-    row_max = _masked_max(x_q, mask, axis)
-    k = _apply_mask_k(_k_of(x_q, row_max), mask)
+    if aligned:
+        xb = ita_bucket(x_q)
+        row_max = _masked_bucket_max(xb, mask, axis)
+        k = _aligned_k(xb, row_max, mask)
+    else:
+        row_max = _masked_max(x_q, mask, axis)
+        k = _apply_mask_k(_k_of(x_q, row_max), mask)
     terms = jax.lax.shift_right_logical(jnp.int32(_UNIT), jnp.minimum(k, 31))
     sigma = jnp.maximum(jnp.sum(terms, axis=axis, keepdims=True), 1)
     e_r = 31 - jax.lax.clz(sigma)                         # floor(log2 sigma)

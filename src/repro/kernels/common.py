@@ -22,6 +22,10 @@ from repro.core.quant import SOFTMAX_SHIFT
 #   [0, 31] *before* the explicit min(k, 31) clamp — the subtraction can
 #   never approach int32 overflow.
 NEG_SENTINEL = -256
+# NEG_BUCKET: the sentinel's exponent bucket (``-256 >> 5``), the running
+#   max bucket's start in the fused kernels' grid-aligned DA: below every
+#   live bucket [-4, 3], so a row's first live tile moves it by at most 11.
+NEG_BUCKET = NEG_SENTINEL >> SOFTMAX_SHIFT
 # MASK_K: shift applied to masked elements; 128 >> 31 == 0, so a masked
 #   element contributes exactly nothing to sigma. Also the largest legal
 #   int32 shift, which is why every DA shift amount is clamped to it.
@@ -151,7 +155,9 @@ def tile_mask(q_tile: jax.Array, kv_tile: jax.Array, bq: int, bkv: int,
 
 
 def da_update(m_ref, sigma_ref, logits_i32: jax.Array, valid: jax.Array):
-    """One streaming DA step over a (bq, bkv) logits tile.
+    """One streaming DA step over a (bq, bkv) logits tile, as the paper
+    updates (the two-pass and softmax kernels; the fused attention kernels
+    use ``da_update_aligned``).
 
     Updates the per-row running max and running denominator stored in the
     (bq, 1) scratch refs and returns ``(u8 numerator tile, delta_shift)``
@@ -171,6 +177,38 @@ def da_update(m_ref, sigma_ref, logits_i32: jax.Array, valid: jax.Array):
     sigma_ref[...] = jax.lax.shift_right_logical(sigma_ref[...], delta) \
         + 2 * jnp.sum(u, axis=1, keepdims=True)
     m_ref[...] = new_max
+    return u, delta
+
+
+def da_update_aligned(b_ref, sigma_ref, logits_i32: jax.Array,
+                      valid: jax.Array, at=...):
+    """One streaming DA step of the fused attention kernels, on the 2^5
+    grid (``core.softmax.ita_da_update_aligned`` is its pure-jnp twin).
+
+    ``b_ref`` holds each row's running max *bucket* (``max >> 5``, starting
+    at ``NEG_BUCKET``); a key's exponent is ``k = bucket - (x >> 5)``. The
+    max grows by whole buckets, so the returned correction ``delta`` (the
+    growth) composes exactly with every earlier key's ``k``: once a row
+    has seen all its tiles, each key carries ``k`` against the final
+    bucket, as a one-shot pass gives it, and ``sigma >> delta`` (updated
+    here) drops no bit — every term in it is ``2^(8-k)`` with ``k <= 7``
+    in the end. Returns ``(u = 128 >> k, delta)``; the caller corrects
+    anything it accumulated under the old bucket by ``>> delta``, which is
+    exact for the same reason. ``at`` indexes the refs (a head's slot of
+    a per-head scratch; the whole ref by default).
+    """
+    xb = jax.lax.shift_right_arithmetic(logits_i32, jnp.int32(SOFTMAX_SHIFT))
+    part = jnp.max(jnp.where(valid, xb, NEG_BUCKET), axis=1, keepdims=True)
+    old = b_ref[at]
+    new = jnp.maximum(old, part)
+    # both clips are identities on reachable values (new >= old, and
+    # new >= every live xb), stated for the non-relational range verifier
+    delta = jnp.clip(new - old, 0, MASK_K)
+    k = jnp.where(valid, jnp.clip(new - xb, 0, MASK_K), MASK_K)
+    u = jax.lax.shift_right_logical(jnp.int32(U_MAX), k)     # 128 >> k
+    sigma_ref[at] = jax.lax.shift_right_logical(sigma_ref[at], delta) \
+        + 2 * jnp.sum(u, axis=1, keepdims=True)
+    b_ref[at] = new
     return u, delta
 
 

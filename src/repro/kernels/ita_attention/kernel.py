@@ -4,12 +4,12 @@ Two dataflows, both with the ITA integer softmax:
 
 - ``onepass`` (beyond-paper, flash-style): the int8 attention tile never
   leaves VMEM. Per (q-tile, kv-tile): int8 Q·Kᵀ on the MXU → requant to the
-  ITA logit grid → DA update of the per-row (max, Σ) stats → the *unnormal-
-  ized* numerators ``u = 128 >> k`` (int8!) multiply V on the MXU and add
-  into a running accumulator which is shift-corrected when the row max
-  grows (the same correction silicon applies to Σ). DI happens once per row
-  at the final kv tile and folds into the output requant as a per-row
-  multiplier. HBM traffic for the S×S matrix: zero.
+  ITA logit grid → grid-aligned DA update of the per-row (max bucket, Σ)
+  stats → the *unnormalized* numerators ``u = 128 >> k`` multiply V on the
+  MXU and add into a running int32 accumulator which is shift-corrected
+  when the row's max bucket grows (the correction Σ gets too). DI happens
+  once per row at the final kv tile and folds into the output requant as a
+  per-row multiplier. HBM traffic for the S×S matrix: zero.
 
 - ``twopass`` (paper-faithful): pass 1 streams Q·Kᵀ tiles, writes the int8
   attention matrix A to HBM exactly once and accumulates the (max, Σ) row
@@ -19,16 +19,53 @@ Two dataflows, both with the ITA integer softmax:
   read once, softmax adds **no** extra passes.
 
 Integer semantics notes:
-- ``Σ p ≤ 2^(e_r)``... for paper mode (e_r = 8): ``Σ p ≤ 256`` so the A·V
-  accumulator is bounded by 2^15 — f32 scratch holds it exactly (ints are
-  exact in f32 below 2^24), so paper mode remains bit-exact integer.
+- the fused kernels (onepass, decode and their paged forms) take a key's
+  exponent on the 2^5 grid: ``k = (max >> 5) - (x >> 5)``, the difference
+  of the row max's and the logit's 3-bit buckets
+  (``kernels.common.da_update_aligned``; pure-jnp twin
+  ``core.softmax.ita_softmax_aligned_int``). The running max is kept as
+  its bucket, so it grows by whole buckets, ``delta``, and a late
+  correction composes exactly with every earlier shift. Once a row has
+  seen all its tiles each key carries ``k`` against the final bucket,
+  ``k <= 7`` on live keys, so every term ``u = 2^(7-k)`` of the
+  accumulators is divisible by 2^delta at each correction: ``Σ >> delta``
+  and ``acc >> delta`` drop no bit. The kernels therefore equal the
+  one-shot arithmetic (``ref.ita_attention_fused_ref``) bit for bit at
+  any tiling and whatever order the row maxima arrive in, and paged
+  decode equals the ring and prefill at any page size.
+- What departs from the paper: the paper's multi-part update keeps the
+  max itself and corrects by ``(m_new - m_old) >> 5``. Those floors do
+  not compose (``floor(a) + floor(b)`` can be one short of ``floor(a +
+  b)``), so each max update can leave every earlier key with twice its
+  one-shot weight, and the integer ``Σ`` shift drops bits: over a 4k
+  context whose max keeps growing the error builds up with depth. The
+  aligned grid keeps the paper's quantisation (one factor of two per
+  bucket of 32 logit steps), with the bucket edges fixed on the logit
+  grid instead of hung from the row max; a row whose max is 127 (the
+  int8 clip, a bucket edge) gets exactly the paper's one-shot result.
+  The two-pass kernel and ``core.softmax.ita_softmax_streaming`` keep
+  the paper's update.
+- Σ and the A·V accumulator: ``Σ = Σ 2u <= 256 * kv_len`` (2^20 at 4k).
+  The numerator·V matmuls (onepass ``u``, twopass ``p``) run as bf16 x
+  bf16 -> f32 on the MXU, which is exact: ``u, p <= 256`` and ``|v| <=
+  128`` are exact in bf16, every product is exact in f32, and a tile's
+  sum stays below 2^24 (``128 * 128 * bkv`` = 2^21 at bkv 128), where f32
+  integers are exact. The fused kernels convert each tile's sum to int32
+  and keep the running accumulator there: ``|acc| <= 128 * 128 *
+  kv_len``, 2^26 at 4k, exact up to 2^17 keys. The output requant
+  converts it to f32 once (rounding only past 2^24, as the one-shot twin
+  does). Mosaic has no int32 matmul, and ``u`` reaches 128, which int8
+  cannot hold. The two-pass A·V accumulator stays f32: for paper mode
+  (e_r = 8) ``Σ p <= 256`` bounds it by 2^15, exact.
 - onepass uses ``u = 128 >> k`` (the missing factor 2 folds into the
-  output requant). The numerator·V matmuls (onepass ``u``, twopass ``p``)
-  run as bf16 x bf16 -> f32 on the MXU, which is exact: ``u, p <= 256``
-  and ``|v| <= 128`` are exact in bf16, every product is exact in f32,
-  and a tile's sum stays below 2^24 (``256 * 128 * bkv`` for bkv <= 256
-  on live rows), where f32 integers are exact. Mosaic has no int32
-  matmul, and ``u`` reaches 128, which int8 cannot hold.
+  output requant).
+- the output: int8 on the ``s_out`` grid (``out_mult = s_v / s_out``,
+  rounded and clipped), or with ``float_out`` the f32 attention output
+  itself (``out_mult = s_v``), which the model's float specs take. An
+  output past ``127 * s_out`` is clipped on the int8 grid; a model whose
+  outputs reach that far (a peaked row returns one value row of V) loses
+  them at every layer, and over 32 layers that was most of the served
+  path's distance from float32 (PERF.md).
 - per-row scalars (the requant multipliers and the ``[kv_len, q_offset,
   q_len]`` meta) are whole 1-D arrays in SMEM, indexed by the kernel row
   ``pl.program_id(0)`` — Mosaic refuses ``(1, 1)`` VMEM blocks of a
@@ -54,16 +91,27 @@ rows (q_len = chunk). Scalars broadcast to all rows (the dense case).
 Paged KV pool: the ``*_paged`` entry points consume one shared
 head-major ``(num_pages, G, page_size, hd)`` int8 arena per layer,
 stacked over the model's layers as ``(L, num_pages, G, page_size, hd)``
-(each block is one kv head's ``(page_size, hd)`` page, a layout Mosaic
-tiles) through a **page table** delivered as a flat scalar-prefetch
-operand, next to the layer index — the KV BlockSpec index map reads
+(a onepass block is one kv head's ``(page_size, hd)`` page, a decode
+block the page of every kv head: layouts Mosaic tiles) through a **page
+table** delivered as a flat scalar-prefetch operand, next to the layer
+index — the KV BlockSpec index map reads
 ``page_table[b * n_pages + j]`` to translate logical KV tile ``j`` of
 sequence ``b`` into a physical page of layer ``layer``'s arena, so
 scattered pages stream through the very
-same kernel bodies (``decode_kernel``/``onepass_kernel``) tile-for-tile.
-With ``block_kv == page_size`` the DA tile schedule is identical to the
-contiguous ring path, which is what keeps paged decode bit-identical to
-the ring (the ``ita_fused`` family invariant).
+same arithmetic (``onepass_kernel``; ``decode_heads_kernel`` for decode)
+tile-for-tile.
+The aligned DA makes the result independent of the tile schedule, so
+paged decode is bit-identical to the ring (the ``ita_fused`` family
+invariant) at ``block_kv == page_size`` and at any other page size.
+
+Paged decode takes one grid step per (sequence, logical page), not per
+(sequence, head, page): its block is the page of every kv head, and
+``decode_heads_kernel`` streams the sequence's query heads through it
+one after another. A decode tile is (8, 128) of work, so a step per head
+spent most of a call in the grid's per-step cost (32 heads x 32 pages x
+16 slots a layer for phi3-mini at 4k). The page table it reads stops at
+each sequence's last live page (``_live_page_table``), so pages past it
+cost neither DMA nor work, and an empty slot costs one step per page.
 """
 
 from __future__ import annotations
@@ -76,8 +124,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import INT8_MAX, INT8_MIN, SOFTMAX_SHIFT
-from repro.kernels.common import (MASK_K, NEG_SENTINEL, adaptive_inverse,
-                                  da_update, paper_inverse, pow2_neg,
+from repro.kernels.common import (MASK_K, NEG_BUCKET, NEG_SENTINEL,
+                                  adaptive_inverse, da_update,
+                                  da_update_aligned, paper_inverse, pow2_neg,
                                   tile_mask)
 
 
@@ -90,11 +139,12 @@ def _qk_logits(q_tile, k_tile, mult):
     return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int32)
 
 
-def _row_scalars(lmult_ref, omult_ref, meta_ref):
-    """This kernel row's (logit_mult, out_mult, kv_len, q_offset, q_len)
+def _row_scalars(lmult_ref, omult_ref, meta_ref, r=None):
+    """Kernel row ``r``'s (logit_mult, out_mult, kv_len, q_offset, q_len)
     from the whole-array SMEM operands (meta is flat, 3 entries per
-    row)."""
-    r = pl.program_id(0)
+    row); ``r`` defaults to the grid's row ``pl.program_id(0)``."""
+    if r is None:
+        r = pl.program_id(0)
     return (lmult_ref[r], omult_ref[r], meta_ref[3 * r], meta_ref[3 * r + 1],
             meta_ref[3 * r + 2])
 
@@ -108,28 +158,49 @@ def _pv(p, v_tile):
                                preferred_element_type=jnp.float32)
 
 
-def _stream_tile(q_tile, k_tile, v_tile, lmult, m_ref, sigma_ref, acc_ref,
-                 valid):
-    """One onepass DA step: logits -> (max, Σ) update -> shift-corrected
-    A·V accumulate (the correction multiplies by 2^-delta, exact in f32,
-    unlike the integer Σ shift)."""
+def _stream_tile(q_tile, k_tile, v_tile, lmult, b_ref, sigma_ref, acc_ref,
+                 valid, at=...):
+    """One onepass DA step: logits -> grid-aligned (max bucket, Σ) update
+    -> shift-corrected A·V accumulate. The tile's exact f32 product joins
+    the int32 accumulator, whose ``>> delta`` correction drops no bit (see
+    ``da_update_aligned``). ``at`` picks a head's slot of the scratch."""
     logits = _qk_logits(q_tile, k_tile, lmult)
-    u, delta = da_update(m_ref, sigma_ref, logits, valid)
-    corr = pow2_neg(delta)
-    acc_ref[...] = acc_ref[...] * corr + _pv(u, v_tile)
+    u, delta = da_update_aligned(b_ref, sigma_ref, logits, valid, at)
+    acc_ref[at] = (acc_ref[at] >> delta) \
+        + _pv(u, v_tile).astype(jnp.int32)
 
 
-def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive):
-    if adaptive:
-        inv, e_r = adaptive_inverse(sigma_ref[...])
+def _init_stream(b_ref, sigma_ref, acc_ref):
+    b_ref[...] = jnp.full_like(b_ref, NEG_BUCKET)
+    sigma_ref[...] = jnp.zeros_like(sigma_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _emit(o_ref, y, float_out, h=0):
+    """Output tile ``h``: ``y`` as it is (f32, the dequantized attention
+    output), or rounded and clipped onto the int8 ``s_out`` grid."""
+    if float_out:
+        o_ref[h] = y
     else:
-        inv = paper_inverse(sigma_ref[...])
+        o_ref[h] = jnp.clip(jnp.round(y), INT8_MIN, INT8_MAX).astype(jnp.int8)
+
+
+def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
+                      float_out, h=None):
+    """DI and the output requant of a row's stats; ``h`` picks a head's
+    slot of the scratch and of the output block (one row's by default)."""
+    at = ... if h is None else h
+    if adaptive:
+        inv, e_r = adaptive_inverse(sigma_ref[at])
+    else:
+        inv = paper_inverse(sigma_ref[at])
         e_r = jnp.full_like(inv, 8)
-    # out = acc * 2 * inv * 2^-(e_r+8) * (s_v/s_out); the 2 restores the
-    # halved numerator unit (u = 128>>k vs the paper's 256>>k).
+    # out = acc * 2 * inv * 2^-(e_r+8) * omult (s_v/s_out, or s_v for a
+    # float output); the 2 restores the halved numerator unit (u = 128>>k
+    # vs the paper's 256>>k).
     scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-    y = jnp.round(acc_ref[...] * scale)
-    o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    _emit(o_ref, acc_ref[at].astype(jnp.float32) * scale, float_out,
+          0 if h is None else h)
 
 
 def _kv_tile(ref, paged):
@@ -139,18 +210,17 @@ def _kv_tile(ref, paged):
 
 
 def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
-                   o_ref, m_ref, sigma_ref, acc_ref,
+                   o_ref, b_ref, sigma_ref, acc_ref,
                    *, causal: bool, window: int, adaptive: bool,
-                   bq: int, bkv: int, paged: bool = False):
+                   bq: int, bkv: int, paged: bool = False,
+                   float_out: bool = False):
     i, j = pl.program_id(1), pl.program_id(2)
     lmult, omult, kv_len, q_off, q_len = _row_scalars(lmult_ref, omult_ref,
                                                       meta_ref)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_SENTINEL)
-        sigma_ref[...] = jnp.zeros_like(sigma_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stream(b_ref, sigma_ref, acc_ref)
 
     # KV tiles wholly beyond this row's valid prefix are fully masked —
     # DA/acc no-ops — so skip their MXU work: chunked-prefill rows stream
@@ -159,11 +229,12 @@ def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     def _tile():
         valid = tile_mask(i, j, bq, bkv, causal, window, kv_len, q_off, q_len)
         _stream_tile(q_ref[0], _kv_tile(k_ref, paged), _kv_tile(v_ref, paged),
-                     lmult, m_ref, sigma_ref, acc_ref, valid)
+                     lmult, b_ref, sigma_ref, acc_ref, valid)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive)
+        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
+                          float_out)
 
 
 def qk_da_kernel(q_ref, k_ref, lmult_ref, meta_ref, a_ref, max_o_ref,
@@ -192,7 +263,8 @@ def qk_da_kernel(q_ref, k_ref, lmult_ref, meta_ref, a_ref, max_o_ref,
 
 def av_en_kernel(a_ref, inv_ref, er_ref, max_ref, v_ref, omult_ref,
                  meta_ref, o_ref, acc_ref,
-                 *, causal: bool, window: int, bq: int, bkv: int):
+                 *, causal: bool, window: int, bq: int, bkv: int,
+                 float_out: bool = False):
     """Two-pass, pass 2: re-stream A, EN by pure shifts, A·V on the MXU."""
     i, j = pl.program_id(1), pl.program_id(2)
     r = pl.program_id(0)
@@ -211,14 +283,14 @@ def av_en_kernel(a_ref, inv_ref, er_ref, max_ref, v_ref, omult_ref,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        y = jnp.round(acc_ref[...] * pow2_neg(er_ref[0]) * omult_ref[r])
-        o_ref[0] = jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+        _emit(o_ref, acc_ref[...] * pow2_neg(er_ref[0]) * omult_ref[r],
+              float_out)
 
 
 def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
-                  o_ref, m_ref, sigma_ref, acc_ref,
+                  o_ref, b_ref, sigma_ref, acc_ref,
                   *, causal: bool, window: int, adaptive: bool,
-                  bq: int, bkv: int, paged: bool = False):
+                  bq: int, bkv: int, float_out: bool = False):
     """Onepass dataflow without a q grid axis (decode: sq <= one tile)."""
     j = pl.program_id(1)
     lmult, omult, kv_len, q_off, q_len = _row_scalars(lmult_ref, omult_ref,
@@ -226,24 +298,94 @@ def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_SENTINEL)
-        sigma_ref[...] = jnp.zeros_like(sigma_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stream(b_ref, sigma_ref, acc_ref)
 
     # Ring buffers are allocated at max_len; tiles wholly beyond the valid
     # prefix are fully masked (max/sigma/acc all no-ops) — skip the MXU work.
     @pl.when(j * bkv < kv_len)
     def _tile():
         valid = tile_mask(0, j, bq, bkv, causal, window, kv_len, q_off, q_len)
-        _stream_tile(q_ref[0], _kv_tile(k_ref, paged), _kv_tile(v_ref, paged),
-                     lmult, m_ref, sigma_ref, acc_ref, valid)
+        _stream_tile(q_ref[0], k_ref[0], v_ref[0], lmult, b_ref, sigma_ref,
+                     acc_ref, valid)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive)
+        _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
+                          float_out)
+
+
+# The paged decode kernel lays out this many query heads' tiles together
+# at most (the largest divisor of the head count up to it): on a v5e at
+# phi3-mini's 32 heads, 16 took 0.83 ms a layer-call of 16 sequences at
+# 300-3500 tokens, against 1.10 for 8, 1.18 for 32 and 4.0 for one.
+HEAD_GROUP = 16
+
+
+def _head_group(hq: int) -> int:
+    return max(g for g in range(1, min(hq, HEAD_GROUP) + 1) if hq % g == 0)
+
+
+def decode_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
+                        o_ref, b_ref, sigma_ref, acc_ref,
+                        *, causal: bool, window: int, adaptive: bool,
+                        hq: int, kv_rep: int, group: int, bq: int, bkv: int,
+                        float_out: bool = False):
+    """The decode dataflow with one grid step per (sequence, KV page): the
+    block holds the page of every kv head and the body streams each of
+    the sequence's ``hq`` query heads through it, as ``decode_kernel``
+    streams row ``b * hq + h`` (the same arithmetic on the same tiles, so
+    the same bits). A grid step per head would pay the grid's per-step
+    cost ``hq`` times over for a (8, 128) tile of work. A page is skipped
+    when it lies past every head's valid prefix; within a live page a
+    head past its own prefix streams a fully masked tile, which changes
+    none of its (bucket, Σ, accumulator), so the heads' tiles run with no
+    branch between them and ``group`` of them are laid out together for
+    the scheduler to overlap."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows = b * hq
+
+    @pl.when(j == 0)
+    def _init():
+        _init_stream(b_ref, sigma_ref, acc_ref)
+
+    seq_len = jax.lax.fori_loop(
+        0, hq, lambda h, m: jnp.maximum(m, meta_ref[3 * (rows + h)]),
+        jnp.int32(0))
+
+    @pl.when(j * bkv < seq_len)
+    def _page():
+        def heads(t, carry):
+            for u in range(group):
+                h = t * group + u
+                lmult, _, kv_len, q_off, q_len = _row_scalars(
+                    lmult_ref, omult_ref, meta_ref, rows + h)
+                valid = tile_mask(0, j, bq, bkv, causal, window, kv_len,
+                                  q_off, q_len)
+                _stream_tile(q_ref[h], k_ref[0, 0, h // kv_rep],
+                             v_ref[0, 0, h // kv_rep], lmult, b_ref,
+                             sigma_ref, acc_ref, valid, h)
+            return carry
+        jax.lax.fori_loop(0, hq // group, heads, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        def head(h, carry):
+            omult = omult_ref[rows + h]
+            _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
+                              float_out, h)
+            return carry
+        jax.lax.fori_loop(0, hq, head, 0)
 
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _out_dtype(float_out: bool):
+    """int8 on the ``s_out`` grid (``out_mult = s_v / s_out``), or, with
+    ``float_out``, the f32 attention output itself (``out_mult = s_v``):
+    what a caller that wants float gets without the int8 requant, whose
+    clip at ``127 * s_out`` would cut every larger output."""
+    return jnp.float32 if float_out else jnp.int8
 
 
 def _row_mults(logit_mult, out_mult, bh):
@@ -279,17 +421,19 @@ def ita_attention_onepass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                           window: int = 0,
                           adaptive: bool = True, block_q: int = 128,
                           block_kv: int = 128, kv_rep: int = 1,
-                          interpret: bool = True):
+                          float_out: bool = False, interpret: bool = True):
     """q (BH, Sq, D) int8; k/v (BH/kv_rep, Skv, D) int8; returns (BH, Sq, D)
-    int8. GQA: q row r reads kv row r // kv_rep via the index map — the KV
-    head broadcast never materializes."""
+    int8 (f32 with ``float_out``, see ``_out_dtype``). GQA: q row r reads
+    kv row r // kv_rep via the index map — the KV head broadcast never
+    materializes."""
     bh, sq, d = q_q.shape
     skv = k_q.shape[1]
     bq, bkv = min(block_q, sq), min(block_kv, skv)
     assert sq % bq == 0 and skv % bkv == 0
     assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
     kern = functools.partial(onepass_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=bq, bkv=bkv)
+                             adaptive=adaptive, bq=bq, bkv=bkv,
+                             float_out=float_out)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     kv_spec = pl.BlockSpec((1, bkv, d), lambda b, i, j: (b // kv_rep, j, 0))
@@ -299,10 +443,10 @@ def ita_attention_onepass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
         in_specs=[pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
                   kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
                         pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
+                        pltpu.VMEM((bq, d), jnp.int32)],
         interpret=interpret,
         name="ita_onepass",
     )(q_q, k_q, v_q, lmult, omult, meta)
@@ -312,7 +456,7 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                           q_offset=0, causal: bool, window: int = 0,
                           adaptive: bool = False, block_q: int = 128,
                           block_kv: int = 128, kv_rep: int = 1,
-                          interpret: bool = True):
+                          float_out: bool = False, interpret: bool = True):
     """Paper-faithful dataflow. Returns (out int8, a_mat int8) — A is the
     materialized int8 attention matrix (written once, read once).
     GQA via ``kv_rep`` index maps as in onepass. Row stats travel as
@@ -354,14 +498,14 @@ def ita_attention_twopass(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
         e_r = jnp.full_like(sigma_inv, 8)
 
     k2 = functools.partial(av_en_kernel, causal=causal, window=window,
-                           bq=bq, bkv=bkv)
+                           bq=bq, bkv=bkv, float_out=float_out)
     out = pl.pallas_call(
         k2,
         grid=grid,
         in_specs=[a_spec, col_spec, col_spec, col_spec, kv_spec, _SMEM,
                   _SMEM],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="ita_twopass_av",
@@ -373,7 +517,8 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
                          q_offset=0, q_len=None, causal: bool = True,
                          window: int = 0,
                          adaptive: bool = True, block_kv: int = 128,
-                         kv_rep: int = 1, interpret: bool = True):
+                         kv_rep: int = 1, float_out: bool = False,
+                         interpret: bool = True):
     """Fused decode step: q (BH, Sq<=8, D) int8 against an int8 KV ring
     buffer ``(BH/kv_rep, C, D)`` with ``kv_len`` valid entries. Single q
     tile (no q grid axis); KV tiles past ``kv_len`` are skipped inside
@@ -389,7 +534,8 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
     assert skv % bkv == 0, (skv, bkv)
     assert k_q.shape[0] * kv_rep == bh, (k_q.shape, kv_rep, bh)
     kern = functools.partial(decode_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=sq, bkv=bkv)
+                             adaptive=adaptive, bq=sq, bkv=bkv,
+                             float_out=float_out)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     kv_spec = pl.BlockSpec((1, bkv, d), lambda r, j: (r // kv_rep, j, 0))
@@ -399,10 +545,10 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
         in_specs=[pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
                   kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
         out_specs=pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
         scratch_shapes=[pltpu.VMEM((sq, 1), jnp.int32),
                         pltpu.VMEM((sq, 1), jnp.int32),
-                        pltpu.VMEM((sq, d), jnp.float32)],
+                        pltpu.VMEM((sq, d), jnp.int32)],
         interpret=interpret,
         name="ita_decode",
     )(q_q, k_q, v_q, lmult, omult, meta)
@@ -423,43 +569,57 @@ def _swallow_pt(kern):
     return wrapped
 
 
-def _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis):
-    """K/V BlockSpec of the stacked head-major pool: kernel row ``r``
-    (batch ``r // hq``, head ``r % hq``) reads its kv head's page
-    ``pt[(r // hq) * n_pages + j]`` of layer ``layer[0]`` for logical KV
-    tile ``j``."""
-    def page_of(r, j, pt, layer):
+def _paged_kv_spec(page, d, hq, kv_rep, n_pages):
+    """K/V BlockSpec of the stacked head-major pool on the onepass grid
+    (row, q tile, KV tile): kernel row ``r`` (batch ``r // hq``, head
+    ``r % hq``) reads its kv head's page ``pt[(r // hq) * n_pages + j]``
+    of layer ``layer[0]`` for logical KV tile ``j``."""
+    def page_of(r, i, j, pt, layer):
         return (layer[0], pt[(r // hq) * n_pages + j], (r % hq) // kv_rep,
                 0, 0)
-    if with_q_axis:
-        return pl.BlockSpec((1, 1, 1, page, d),
-                            lambda r, i, j, pt, layer: page_of(r, j, pt,
-                                                               layer))
     return pl.BlockSpec((1, 1, 1, page, d), page_of)
 
 
 def _paged_call(name, kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
-                page_table, layer, lmult, omult, meta, bq, interpret):
+                page_table, layer, lmult, omult, meta, bq, float_out,
+                interpret, heads=()):
     """Shared pallas_call of the paged kernels, named ``name``: the flat
     page table and the layer index are the scalar-prefetch operands the
-    K/V index maps read."""
+    K/V index maps read. ``heads``: the leading shape of the per-row
+    (max bucket, Σ, accumulator) scratch, ``(hq,)`` when one grid step
+    serves every head of a sequence."""
     bh, sq, d = q_q.shape
+    heads = tuple(heads)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, 1), jnp.int32),
-                        pltpu.VMEM((bq, d), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM(heads + (bq, 1), jnp.int32),
+                        pltpu.VMEM(heads + (bq, 1), jnp.int32),
+                        pltpu.VMEM(heads + (bq, d), jnp.int32)])
     return pl.pallas_call(
         _swallow_pt(kern),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
         interpret=interpret,
         name=name,
     )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1), q_q,
       k_pool, v_pool, lmult, omult, meta)
+
+
+def _live_page_table(page_table, kv_len, hq, page_size):
+    """The page table with every entry past a sequence's last live page
+    (the most of its heads' ``kv_len``) replaced by that page: the kernel
+    skips those tiles' work, and a block index that repeats the previous
+    step's costs no DMA, so a call reads each sequence's live pages and
+    nothing past them."""
+    b, n_pages = page_table.shape
+    live = kv_len.reshape(b, hq).max(1)
+    last = jnp.clip(-(-live // page_size) - 1, 0, n_pages - 1)
+    tiles = jnp.minimum(jnp.arange(n_pages, dtype=jnp.int32)[None, :],
+                        last[:, None])
+    return jnp.take_along_axis(page_table, tiles, axis=1)
 
 
 def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
@@ -467,6 +627,7 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
                                q_len=None, causal: bool = True,
                                window: int = 0, adaptive: bool = True,
                                kv_rep: int = 1, hq: int = 1,
+                               float_out: bool = False,
                                interpret: bool = True):
     """Fused decode step over a paged KV pool.
 
@@ -477,28 +638,38 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     page to a physical arena page (entries beyond the valid prefix may
     point anywhere — those tiles are skipped/masked via ``kv_len``).
 
-    ``block_kv`` is the page size: logical tile ``j`` of kernel row ``r``
-    is DMA'd from ``pool[layer, page_table[r // hq, j], kv head]`` by a
-    scalar-prefetch index map, and the DA streaming schedule is identical
-    to ``ita_attention_decode`` at ``block_kv == page_size`` — paged
-    decode is bit-identical to the contiguous ring path (family
-    ``ita_fused``).
+    The grid is (sequence, logical page): logical page ``j`` of sequence
+    ``b`` is DMA'd for all ``G`` kv heads at once from ``pool[layer,
+    page_table[b, j]]`` by a scalar-prefetch index map, and the body
+    (``decode_heads_kernel``) streams each of the ``hq`` query heads of
+    the sequence through it. Pages past a sequence's last live page are
+    neither read nor computed. Per head the DA streaming schedule is
+    ``ita_attention_decode``'s at ``block_kv == page_size`` — paged decode
+    is bit-identical to the contiguous ring path (family ``ita_fused``).
     """
     bh, sq, d = q_q.shape
     page = k_pool.shape[3]
-    n_pages = page_table.shape[1]
-    assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
-        (bh, hq, page_table.shape)
-    kern = functools.partial(decode_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=sq, bkv=page, paged=True)
+    g = k_pool.shape[2]
+    b, n_pages = page_table.shape
+    assert bh % hq == 0 and b * hq == bh and hq == g * kv_rep, \
+        (bh, hq, g, kv_rep, page_table.shape)
+    kern = functools.partial(decode_heads_kernel, causal=causal,
+                             window=window, adaptive=adaptive, hq=hq,
+                             kv_rep=kv_rep, group=_head_group(hq),
+                             bq=sq, bkv=page, float_out=float_out)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
+    page_table = _live_page_table(page_table, meta.reshape(bh, 3)[:, 0], hq,
+                                  page)
+
+    def kv_block(s, j, pt, lyr):
+        return (lyr[0], pt[s * n_pages + j], 0, 0, 0)
     return _paged_call(
-        "ita_decode_paged", kern, (bh, n_pages),
-        pl.BlockSpec((1, sq, d), lambda b, j, *_: (b, 0, 0)),
-        _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=False),
+        "ita_decode_paged", kern, (b, n_pages),
+        pl.BlockSpec((hq, sq, d), lambda s, j, *_: (s, 0, 0)),
+        pl.BlockSpec((1, 1, g, page, d), kv_block),
         q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, sq,
-        interpret)
+        float_out, interpret, heads=(hq,))
 
 
 def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
@@ -506,6 +677,7 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
                                 q_len=None, causal: bool, window: int = 0,
                                 adaptive: bool = True, block_q: int = 128,
                                 kv_rep: int = 1, hq: int = 1,
+                                float_out: bool = False,
                                 interpret: bool = True):
     """Flash-style onepass over a paged KV pool (prefill-from-pool, decode
     bursts longer than the decode kernel's single tile, and the mixed
@@ -523,12 +695,13 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
         (bh, hq, page_table.shape)
     kern = functools.partial(onepass_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=bq, bkv=page, paged=True)
+                             adaptive=adaptive, bq=bq, bkv=page, paged=True,
+                             float_out=float_out)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
     meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
     return _paged_call(
         "ita_onepass_paged", kern, (bh, sq // bq, n_pages),
         pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
-        _paged_kv_spec(page, d, hq, kv_rep, n_pages, with_q_axis=True),
+        _paged_kv_spec(page, d, hq, kv_rep, n_pages),
         q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, bq,
-        interpret)
+        float_out, interpret)

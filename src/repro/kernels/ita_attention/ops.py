@@ -10,7 +10,7 @@ head-group sharing (via kernel index maps — no broadcast copies), padding
 to block multiples and the quantization-scale plumbing:
 
     logit_mult = s_q * s_k / (sqrt(d) * EPS_MAX)   (requant onto ITA's grid)
-    out_mult   = s_v / s_out
+    out_mult   = s_v / s_out     (s_v alone for the f32 output)
 
 Scales may be scalars (per-tensor, the QAT-calibrated path) or per-head
 vectors — ``s_q``/``s_out`` of shape (Hq,), ``s_k``/``s_v`` of shape (Hkv,)
@@ -104,7 +104,9 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
     sk_h = jnp.repeat(_per_head(s_k, hkv), rep)
     sv_h = jnp.repeat(_per_head(s_v, hkv), rep)
     lmult = _per_head(s_q, hq) * sk_h / (np.sqrt(d) * EPS_MAX)
-    omult = sv_h / _per_head(s_out, hq)
+    # no s_out: the kernels emit the f32 output, dequantized through s_v
+    float_out = s_out is None
+    omult = sv_h if float_out else sv_h / _per_head(s_out, hq)
     lmult = jnp.tile(lmult, b)
     omult = jnp.tile(omult, b)
 
@@ -123,7 +125,8 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
         q_len = None if q_lens is None else _per_row(q_lens, b, hq)
         common = dict(layer=layer, q_offset=q_offset, q_len=q_len,
                       causal=causal, window=window, adaptive=adaptive,
-                      kv_rep=rep, hq=hq, interpret=interpret)
+                      kv_rep=rep, hq=hq, float_out=float_out,
+                      interpret=interpret)
         if kind == "decode":
             out = ita_attention_decode_paged(
                 qf, k_q, v_q, page_table, lmult, omult, kv_len, **common)
@@ -142,21 +145,18 @@ def _fused(q_q, k_q, v_q, s_q, s_k, s_v, s_out, *, q_offset, kv_len,
     kv_len = _per_row(skv if kv_len is None else kv_len, b, hq)
     q_offset = _per_row(q_offset, b, hq)
     q_len = None if q_lens is None else _per_row(q_lens, b, hq)
+    common = dict(q_offset=q_offset, causal=causal, window=window,
+                  adaptive=adaptive, block_kv=bkv, kv_rep=rep,
+                  float_out=float_out, interpret=interpret)
     if kind == "decode":
-        out = ita_attention_decode(
-            qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
-            q_len=q_len, causal=causal, window=window, adaptive=adaptive,
-            block_kv=bkv, kv_rep=rep, interpret=interpret)
+        out = ita_attention_decode(qf, kf, vf, lmult, omult, kv_len,
+                                   q_len=q_len, **common)
     elif kind == "onepass":
-        out = ita_attention_onepass(
-            qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
-            q_len=q_len, causal=causal, window=window, adaptive=adaptive,
-            block_q=bq, block_kv=bkv, kv_rep=rep, interpret=interpret)
+        out = ita_attention_onepass(qf, kf, vf, lmult, omult, kv_len,
+                                    q_len=q_len, block_q=bq, **common)
     else:
-        out, _ = ita_attention_twopass(
-            qf, kf, vf, lmult, omult, kv_len, q_offset=q_offset,
-            causal=causal, window=window, adaptive=adaptive, block_q=bq,
-            block_kv=bkv, kv_rep=rep, interpret=interpret)
+        out, _ = ita_attention_twopass(qf, kf, vf, lmult, omult, kv_len,
+                                       block_q=bq, **common)
     return out[:, :sq].reshape(b, hq, sq, d)
 
 
@@ -193,7 +193,8 @@ def fused_attention(q_q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     treats only its first ``q_lens[b]`` of the ``Sq`` query rows as real
     (the rest emit zeros) — one mixed call serves decode rows (1 query)
     next to chunked-prefill rows (``chunk`` queries).
-    Returns (B, Hq, Sq, D) int8 at scale ``s_out``.
+    Returns (B, Hq, Sq, D) int8 at scale ``s_out``; with ``s_out=None``
+    the f32 attention output itself (no int8 requant, so no clip).
     """
     assert kind in KINDS, kind
     assert not (page_table is not None and kind == "twopass"), \

@@ -6,12 +6,16 @@ Three references:
                                then p·V). The twopass kernel must match this
                                exactly when given a single kv tile, and match
                                ``ita_attention_stream_ref`` exactly always.
-- ``ita_attention_fused_ref``  one-shot, fused semantics (u = 128>>k, u·V,
-                               Σ_inv folded into the output requant) — the
-                               onepass kernel's single-tile oracle.
+- ``ita_attention_fused_ref``  one-shot, fused semantics (u = 128>>k with
+                               the grid-aligned exponent ``k = (max >> 5) -
+                               (x >> 5)``, u·V, Σ_inv folded into the output
+                               requant) — the onepass and decode kernels'
+                               oracle at *any* tiling and any order of the
+                               row maxima.
 - ``ita_attention_stream_ref`` tile-by-tile mirror of the kernels' streaming
-                               DA (and accumulator corrections), for exact
-                               equality at any tiling.
+                               DA (and accumulator corrections): the aligned
+                               DA for ``onepass``, the paper's for
+                               ``twopass``.
 """
 
 from __future__ import annotations
@@ -21,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import INT8_MAX, INT8_MIN, SOFTMAX_SHIFT
-from repro.kernels.common import MASK_K, NEG_SENTINEL, pow2_neg
+from repro.core.softmax import ita_bucket, ita_softmax_aligned_int
+from repro.kernels.common import MASK_K, NEG_BUCKET, NEG_SENTINEL, pow2_neg
 
 
 def _full_mask(sq, skv, causal, window, kv_len, q_offset=0):
@@ -82,20 +87,44 @@ def ita_attention_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     return out, logits.astype(jnp.int8)
 
 
-def ita_attention_fused_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
-                            window=0, adaptive=True, q_offset=0):
-    """One-shot fused-EN reference (u = 128>>k numerators)."""
-    sq, skv = q_q.shape[1], k_q.shape[1]
-    valid = _full_mask(sq, skv, causal, window, kv_len, q_offset)[None]
-    logits = _logits(q_q, k_q, lmult)
-    k, sigma, _ = _k_and_sigma(logits, valid)
+def _fused_out(acc, sigma, omult, adaptive):
+    """The fused kernels' output requant of an int32 ``u·V`` accumulator
+    (``_finalize_onepass``: Σ_inv and the halved numerator unit folded
+    into one f32 multiplier)."""
     inv, e_r = _inverse(sigma, adaptive)
-    u = jax.lax.shift_right_logical(jnp.int32(128), k)
-    acc = jnp.einsum("bqk,bkd->bqd", u, v_q.astype(jnp.int32)
-                     ).astype(jnp.float32)
     scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-    y = jnp.round(acc * scale)
+    y = jnp.round(acc.astype(jnp.float32) * scale)
     return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+
+
+def ita_attention_fused_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
+                            window=0, adaptive=True, q_offset=0,
+                            q_len=None):
+    """One-shot fused-EN reference (u = 128>>k numerators, the grid-aligned
+    exponent of ``core.softmax.ita_softmax_aligned_int``). ``kv_len``,
+    ``q_offset`` and ``q_len`` may be per-row ``(bh,)`` vectors, as the
+    kernels take them; query rows past a row's ``q_len`` come out 0."""
+    bh, sq, skv = q_q.shape[0], q_q.shape[1], k_q.shape[1]
+    qi = jnp.asarray(q_offset, jnp.int32).reshape(-1, 1, 1) \
+        + jnp.arange(sq, dtype=jnp.int32)[None, :, None]
+    kj = jnp.arange(skv, dtype=jnp.int32)[None, None, :]
+    valid = jnp.broadcast_to(
+        kj < jnp.asarray(kv_len, jnp.int32).reshape(-1, 1, 1), (bh, sq, skv))
+    if causal or window > 0:
+        valid &= qi >= kj
+    if window > 0:
+        valid &= (qi - kj) < window
+    if q_len is not None:
+        valid &= jnp.arange(sq)[None, :, None] \
+            < jnp.asarray(q_len, jnp.int32).reshape(-1, 1, 1)
+    logits = _logits(q_q, k_q, lmult)
+    _, sigma, row_bucket = ita_softmax_aligned_int(logits, valid)
+    k = jnp.where(valid, jnp.clip(row_bucket - ita_bucket(logits), 0,
+                                  MASK_K), MASK_K)
+    u = jax.lax.shift_right_logical(jnp.int32(128), k)
+    acc = jnp.einsum("bqk,bkd->bqd", u, v_q.astype(jnp.int32))
+    omult = jnp.asarray(omult, jnp.float32).reshape(-1, 1, 1)
+    return _fused_out(acc, sigma, omult, adaptive)
 
 
 def ita_attention_stream_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
@@ -108,33 +137,41 @@ def ita_attention_stream_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     valid_full = _full_mask(sq, skv, causal, window, kv_len, q_offset)[None]
     logits = _logits(q_q, k_q, lmult)
 
-    run_max = jnp.full((bh, sq, 1), NEG_SENTINEL, jnp.int32)
+    aligned = kind == "onepass"
+    run_max = jnp.full((bh, sq, 1), NEG_BUCKET if aligned else NEG_SENTINEL,
+                       jnp.int32)
     run_sigma = jnp.zeros((bh, sq, 1), jnp.int32)
-    acc = jnp.zeros((bh, sq, d), jnp.float32)
+    acc = jnp.zeros((bh, sq, d), jnp.int32)
     for j in range(n_kv):
         sl = slice(j * block_kv, min((j + 1) * block_kv, skv))
         lg, vd = logits[..., sl], valid_full[..., sl]
-        x = jnp.where(vd, lg, NEG_SENTINEL)
-        part_max = jnp.max(x, axis=-1, keepdims=True)
-        new_max = jnp.maximum(run_max, part_max)
-        delta = jnp.minimum(jax.lax.shift_right_logical(
-            new_max - run_max, SOFTMAX_SHIFT), 31)
-        k = jax.lax.shift_right_logical(new_max - lg, SOFTMAX_SHIFT)
-        k = jnp.where(vd, jnp.minimum(k, 31), MASK_K)
+        if aligned:                     # kernels.common.da_update_aligned
+            xb = ita_bucket(lg)
+            new_max = jnp.maximum(run_max, jnp.max(
+                jnp.where(vd, xb, NEG_BUCKET), axis=-1, keepdims=True))
+            delta = jnp.clip(new_max - run_max, 0, MASK_K)
+            k = jnp.clip(new_max - xb, 0, MASK_K)
+        else:                           # kernels.common.da_update
+            x = jnp.where(vd, lg, NEG_SENTINEL)
+            new_max = jnp.maximum(run_max,
+                                  jnp.max(x, axis=-1, keepdims=True))
+            delta = jnp.minimum(jax.lax.shift_right_logical(
+                new_max - run_max, SOFTMAX_SHIFT), 31)
+            k = jnp.minimum(jax.lax.shift_right_logical(
+                new_max - lg, SOFTMAX_SHIFT), 31)
+        k = jnp.where(vd, k, MASK_K)
         u = jax.lax.shift_right_logical(jnp.int32(128), k)
         run_sigma = jax.lax.shift_right_logical(run_sigma, delta) \
             + 2 * jnp.sum(u, axis=-1, keepdims=True)
         run_max = new_max
-        if kind == "onepass":
+        if aligned:
             pv = jnp.einsum("bqk,bkd->bqd", u, v_q[:, sl].astype(jnp.int32))
-            acc = acc * pow2_neg(delta) \
-                + pv.astype(jnp.float32)
+            acc = (acc >> delta) + pv         # arithmetic: exact
+
+    if aligned:
+        return _fused_out(acc, run_sigma, omult, adaptive)
 
     inv, e_r = _inverse(run_sigma, adaptive)
-    if kind == "onepass":
-        scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-        y = jnp.round(acc * scale)
-        return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
 
     # twopass: EN with the final streamed stats (numerators exact).
     k = jax.lax.shift_right_logical(run_max - logits, SOFTMAX_SHIFT)

@@ -60,6 +60,18 @@ def init_attention(key, cfg, cross: bool = False):
     return p
 
 
+def _project(x, w):
+    """``dense(x, w)`` for the q/k/v projections, behind an optimization
+    barrier. Left to itself, XLA's TPU layout assignment asks for the
+    product in the layout the head split reads, which it gets by laying
+    the whole stacked ``[L, d, h * hd]`` weight out transposed once, out of
+    the layer loop: at phi3-mini's 32 layers, 1.81 GB of temporaries in
+    every serve program, the size of 54 KV pages. Behind the barrier the
+    product keeps its own layout and only the small activation is laid
+    out again. The values are the same."""
+    return jax.lax.optimization_barrier(dense(x, w))
+
+
 def _split_heads(x, n, hd):
     return x.reshape(*x.shape[:-1], n, hd)
 
@@ -122,7 +134,7 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
         return quantize_to_int8(t, params[s]) if quant_cache else t
 
     with jax.named_scope("attn_qkv"):
-        q = dense(x, params["wq"].astype(dt))
+        q = _project(x, params["wq"].astype(dt))
         if cfg.qkv_bias:
             q = q + params["bq"].astype(dt)
         q = _split_heads(q, h, hd)
@@ -132,8 +144,8 @@ def apply_attention(params, x, *, cfg, kind="global", positions=None,
                 and mode == "decode":
             k = v = None                           # static cross KV cached
         else:
-            k = dense(kv_src, params["wk"].astype(dt))
-            v = dense(kv_src, params["wv"].astype(dt))
+            k = _project(kv_src, params["wk"].astype(dt))
+            v = _project(kv_src, params["wv"].astype(dt))
             if cfg.qkv_bias:
                 k = k + params["bk"].astype(dt)
                 v = v + params["bv"].astype(dt)

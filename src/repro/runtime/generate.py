@@ -376,6 +376,13 @@ class ServeResult:
     recovery_s: float = 0.0          # wall spent in replay + restore
     aging_steps: int | None = None   # starvation-aging period (None = off)
     max_class: int = 0               # highest SLO class in the trace
+    # per admission round, from the host's ledger: (step, pages reserved,
+    # pages in use); reserved counts worst-case reservations and index
+    # pins, in use the pages that hold written tokens (shared ones once)
+    pool_rounds: list = dataclasses.field(default_factory=list)
+    pool_pages: int = 0              # usable pool pages (parking excluded)
+    admissions_held_for_pages: int = 0   # rounds whose next arrival had
+                                         # a free slot but not its pages
 
     @property
     def total_tokens(self) -> int:
@@ -766,7 +773,9 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     (``fold_in(key, request_index)``), so outputs are independent of
     admission interleaving and co-scheduled traffic.
     Returns ``ServeResult`` with per-request latency/TTFT and page-pool
-    utilization samples.
+    utilization samples: pages reserved against pages in use per round
+    (``pool_rounds``), and the rounds in which the next arrival found a
+    free slot but not its reservation (``admissions_held_for_pages``).
 
     **Tracing**: the loop writes host spans into the JAX profiler's own
     trace (``jax.profiler.TraceAnnotation``), on the clock it puts the
@@ -776,8 +785,12 @@ def serve_continuous(params, cfg, requests, *, slots: int,
 
     - ``serve.schedule``: arrivals, candidate order, prefix lookup, LRU
       eviction, victim choice (host only);
-    - ``serve.pool``: the round's preempt, release, unpin, adopt and
-      admit dispatches;
+    - ``serve.pool`` (args ``pages_reserved``: the worst-case
+      reservations and index pins admission counts against the pool,
+      ``pages_in_use``: the pages live rows and the index hold written
+      tokens in, ``pages_free``: pool pages not reserved; the host's
+      ledger, also in ``ServeResult.pool_rounds``): the round's preempt,
+      release, unpin, adopt and admit dispatches;
     - ``serve.dispatch`` (args ``segment``, ``step``, ``mixed``: the
       segment's chunk-wide steps, ``steps``: its length): the segment
       call;
@@ -1046,12 +1059,15 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     n_preempts = 0
     completed = list(replayed_completed)
     page_util = []
+    pool_rounds = []
+    held_for_pages = 0
     drain_since = None                             # wall time drain began
     snapshot_bytes = 0
 
     # prefix-sharing host state (all empty/zero when index is None)
     pins = {}                                      # page -> 1 (index pins)
     slot_shared = [[] for _ in range(slots)]       # adopted pages per slot
+    slot_pinned = [set() for _ in range(slots)]    # its pages the index pins
     slot_shareable = [False] * slots               # row may donate pages
     reg_done = [0] * slots                         # prompt pages registered
     prefill_tokens = 0
@@ -1160,6 +1176,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
         prefilling[slot] = False
         slot_prompt[slot] = None
         slot_shared[slot] = []
+        slot_pinned[slot] = set()
         slot_shareable[slot] = False
         reg_done[slot] = 0
 
@@ -1193,11 +1210,31 @@ def serve_continuous(params, cfg, requests, *, slots: int,
         plen_host[slot] = 0
         slot_prompt[slot] = None
         slot_shared[slot] = []
+        slot_pinned[slot] = set()
         slot_shareable[slot] = False
         reg_done[slot] = 0
         queue.append(i)
         queue.sort(key=lambda j: (requests[j].arrival, j))
         to_release.append(slot)
+
+    def pages_in_use(fresh):
+        """Pages that hold written tokens, from the host's mirrors (no
+        device sync): each live row's written prefix in pages, capped at
+        its window, and the index's pinned pages, a page that rows share
+        through the index counted once. ``fresh``: the slots admitted this
+        round, which have written nothing yet."""
+        known = set(pins)
+        own = 0
+        for s in range(slots):
+            i = slot_req[s]
+            if i is None or s in fresh:
+                continue
+            written = cursor_host[s] if prefilling[s] else \
+                int(np.asarray(requests[i].prompt).size) + len(emitted[i]) - 1
+            own += min(-(-written // page_size), pages_per_seq) \
+                - len(slot_pinned[s])
+            known |= slot_pinned[s]
+        return own + len(known)
 
     def _journal_progress(keys_np=None):
         """Journal every request's emitted-token delta since its last
@@ -1353,6 +1390,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                                 free_slots.append(s)
                             page_budget += gain
                     if not free_slots or need > page_budget:
+                        held_for_pages += bool(free_slots)
                         break                        # head-of-line: keep order
                     slot = free_slots.pop(0)
                     queue.remove(i)
@@ -1364,6 +1402,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                     adm_shared[slot] = sh_pages
                     slot_prompt[slot] = prompt_i
                     slot_shared[slot] = list(sh_pages)
+                    slot_pinned[slot] = set(sh_pages)
                     slot_shareable[slot] = (index is not None
                                             and plen_i + gen_i <= capacity)
                     reg_done[slot] = len(sh_pages)  # adopted = already indexed
@@ -1371,7 +1410,13 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                     prefill_tokens += plen_i - sh_toks
                     shared_tokens += sh_toks
                     prefix_hits += bool(sh_pages)
-            with jax.profiler.TraceAnnotation("serve.pool"):
+            reserved_now = sum(reserved) + len(pins)
+            in_use_now = pages_in_use({slot for slot, _ in adm})
+            pool_rounds.append((step, reserved_now, in_use_now))
+            with jax.profiler.TraceAnnotation(
+                    "serve.pool", pages_reserved=reserved_now,
+                    pages_in_use=in_use_now,
+                    pages_free=pool_pages - reserved_now):
                 if victims_round:
                     # one-dispatch device-row clear: the victims' done flag
                     # raises before any release/adopt/admit dispatch and before
@@ -1608,6 +1653,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                             got = index.register(slot_prompt[s],
                                                  table[s, :full])
                             reg_done[s] = full
+                            slot_pinned[s].update(got)
                             new_pins.extend(got)
                         if new_pins:
                             pins.update((p, 1) for p in new_pins)
@@ -1658,4 +1704,6 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                        replayed_tokens=replayed_tokens,
                        snapshot_bytes=snapshot_bytes,
                        recovery_s=recovery_s, aging_steps=aging_steps,
-                       max_class=max_class)
+                       max_class=max_class, pool_rounds=pool_rounds,
+                       pool_pages=pool_pages,
+                       admissions_held_for_pages=held_for_pages)

@@ -127,6 +127,12 @@ SOFTMAX_GOLDEN = {
         True, [[0, 256], [1, 32768], [-256, 127]], (1 << 27) - 1, 0),
     "ita_softmax_ref/adaptive": (
         True, [[0, 256], [0, 15], [-256, 127]], (1 << 27) - 1, 0),
+    # the fused kernels' grid-aligned arithmetic: k is a difference of
+    # buckets in [-8, 3], clipped to [0, 31], so no logical shift of a
+    # negative difference appears; the widest value is the 2^16 dividend
+    "ita_softmax_ref/aligned": (
+        True, [[0, 256], [0, 32768], [-8, 3]], 1 << 16, 0),
+    "ita_softmax_ref/aligned-streaming": (True, [[0.0, 256.0]], 1 << 16, 0),
 }
 
 
@@ -168,51 +174,50 @@ def test_mutant_dropped_requant_clip_is_flagged(monkeypatch):
 
 
 def test_mutant_dropped_shift_clamp_is_flagged(monkeypatch):
-    """Remove min(k, 31) from the DA update: the masked-row exponent
-    reaches 2^27 and the 128 >> k shift is no longer proven legal."""
+    """Remove the clip of k from the fused kernels' DA update: the
+    bucket difference ``max_bucket - (x >> 5)`` is not provably >= 0
+    (relational), so the 128 >> k shift is no longer proven legal."""
     import repro.kernels.ita_attention.kernel as K
     from repro.core.quant import SOFTMAX_SHIFT
-    from repro.kernels.common import MASK_K, NEG_SENTINEL
+    from repro.kernels.common import MASK_K, NEG_BUCKET
 
-    def da_noclamp(m_ref, sigma_ref, logits_i32, valid):
-        x = jnp.where(valid, logits_i32, NEG_SENTINEL)
-        new_max = jnp.maximum(m_ref[...],
-                              jnp.max(x, axis=1, keepdims=True))
-        delta = jnp.minimum(jax.lax.shift_right_logical(
-            new_max - m_ref[...], SOFTMAX_SHIFT), 31)
-        k = jax.lax.shift_right_logical(new_max - logits_i32,
-                                        SOFTMAX_SHIFT)
-        k = jnp.where(valid, k, MASK_K)         # min(k, 31) dropped
+    def da_noclamp(b_ref, sigma_ref, logits_i32, valid, at=...):
+        xb = jax.lax.shift_right_arithmetic(logits_i32,
+                                            jnp.int32(SOFTMAX_SHIFT))
+        new = jnp.maximum(b_ref[at], jnp.max(
+            jnp.where(valid, xb, NEG_BUCKET), axis=1, keepdims=True))
+        delta = jnp.clip(new - b_ref[at], 0, MASK_K)
+        k = jnp.where(valid, new - xb, MASK_K)  # clip(k, 0, 31) dropped
         u = jax.lax.shift_right_logical(jnp.int32(128), k)
-        sigma_ref[...] = jax.lax.shift_right_logical(
-            sigma_ref[...], delta) + 2 * jnp.sum(u, axis=1, keepdims=True)
-        m_ref[...] = new_max
+        sigma_ref[at] = jax.lax.shift_right_logical(
+            sigma_ref[at], delta) + 2 * jnp.sum(u, axis=1, keepdims=True)
+        b_ref[at] = new
         return u, delta
 
-    monkeypatch.setattr(K, "da_update", da_noclamp)
+    monkeypatch.setattr(K, "da_update_aligned", da_noclamp)
     r = run_case(_case("ita_onepass_pallas/prefill-paper"))
     assert not r["ok"]
     assert "shift_range" in {f["kind"] for f in r["findings"]}
 
 
 def test_mutant_widened_softmax_numerator_is_flagged(monkeypatch):
-    """Remove the p <= 256 identity clamp from the reference softmax:
+    """Remove the p <= 256 identity clamp from the direct path's softmax:
     at production kv length the p*V int32 accumulator (65536 * 127 *
     2048) is no longer proven in range -> overflow."""
     from repro.core import quant as Q
     from repro.core import softmax as SM
 
     def noclamp(x_q, mask=None, axis=-1):
-        row_max = SM._masked_max(x_q, mask, axis)
-        k = SM._apply_mask_k(SM._k_of(x_q, row_max), mask)
-        terms = jax.lax.shift_right_logical(
-            jnp.int32(SM._UNIT), jnp.minimum(k, 31))
+        xb = SM.ita_bucket(x_q)
+        row_bucket = SM._masked_bucket_max(xb, mask, axis)
+        k = SM._aligned_k(xb, row_bucket, mask)
+        terms = jax.lax.shift_right_logical(jnp.int32(SM._UNIT), k)
         sigma = jnp.maximum(jnp.sum(terms, axis=axis, keepdims=True), 1)
         sigma_inv = (jnp.int32(1) << SM._W_INV) // sigma
-        p = jax.lax.shift_right_logical(sigma_inv, jnp.minimum(k, 31))
-        return p, sigma, row_max                # p <= _UNIT clamp dropped
+        p = jax.lax.shift_right_logical(sigma_inv, k)
+        return p, sigma, row_bucket             # p <= _UNIT clamp dropped
 
-    monkeypatch.setattr(SM, "ita_softmax_int", noclamp)
+    monkeypatch.setattr(SM, "ita_softmax_aligned_int", noclamp)
     r = run_case(_case("ita_direct_xla/decode-paper", smoke=False))
     assert not r["ok"]
     kinds = {f["kind"] for f in r["findings"]}

@@ -255,3 +255,26 @@ def test_attention_accuracy_vs_float():
         jnp.asarray(vf.reshape(b * h, s, d)), causal=True))
     rel = np.abs(out.reshape(b * h, s, d) - ref).mean() / np.abs(ref).mean()
     assert rel < 0.25, rel
+
+
+@pytest.mark.parametrize("kind", ["onepass", "decode"])
+def test_float_output_is_the_int8_requant_without_its_clip(kind):
+    """Without ``s_out`` the fused kernels emit the f32 attention output
+    (dequantized through ``s_v``): within half a step of the int8 result
+    wherever that is not clipped, and past the clip where it is — what a
+    float spec gets instead of an output cut at ``127 * s_out``."""
+    from repro.kernels.ita_attention.ops import fused_attention
+    b, h, d, skv = 2, 2, 64, 384
+    sq = 1 if kind == "decode" else 64
+    q, k, v = _i8(b, h, sq, d), _i8(b, h, skv, d), _i8(b, h, skv, d)
+    s_out = np.float32(0.002)               # small: many outputs clip
+    args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), SQ, SQ, SQ)
+    kw = dict(kind=kind, q_offset=skv - sq, causal=True, block_q=32,
+              block_kv=128)
+    i8 = np.asarray(fused_attention(*args, s_out, **kw)).astype(np.float64)
+    f32 = np.asarray(fused_attention(*args, None, **kw))
+    assert f32.dtype == np.float32
+    steps = f32.astype(np.float64) / s_out
+    inside, past = np.abs(steps) < 126, np.abs(steps) > 128
+    assert np.abs(steps - i8)[inside].max() <= 0.5 + 1e-3
+    assert past.any() and (np.abs(i8[past]) >= 127).all()

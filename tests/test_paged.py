@@ -1060,3 +1060,192 @@ def test_serve_on_lane_padded_pool_matches_solo_generate(admission):
         np.testing.assert_array_equal(np.asarray(c.tokens),
                                       np.asarray(solo.tokens)[0],
                                       err_msg=f"request {c.index}")
+
+
+# ---------------------------------------------------------------------------
+# Many tiles, maxima in any order: the kernels equal the one-shot arithmetic
+# ---------------------------------------------------------------------------
+
+def _sorted_streams(q, k_log, v_log, lens, order):
+    """Per (sequence, kv head): sort the first ``lens[b]`` keys (and their
+    values) by the logit of the row's first query of that head, so the max
+    arrives last (``ascending``), first (``descending``) or anywhere."""
+    if order == "random":
+        return k_log, v_log
+    b, hq = q.shape[:2]
+    g = k_log.shape[2]
+    k_log, v_log = k_log.copy(), v_log.copy()
+    for bb in range(b):
+        n = lens[bb]
+        for h in range(g):
+            score = k_log[bb, :n, h].astype(np.int64) \
+                @ q[bb, h * (hq // g), 0].astype(np.int64)
+            idx = np.argsort(score, kind="stable")
+            if order == "descending":
+                idx = idx[::-1]
+            k_log[bb, :n, h] = k_log[bb, idx, h]
+            v_log[bb, :n, h] = v_log[bb, idx, h]
+    return k_log, v_log
+
+
+@pytest.mark.parametrize("order", ["ascending", "descending", "random"])
+def test_paged_kernels_equal_aligned_oneshot_over_many_tiles(order):
+    """Over 16-20 KV tiles, with every row's max arriving last, first or
+    anywhere, the ragged onepass-paged kernel (two decode rows beside a
+    chunked-prefill row) and the decode-paged kernel equal the one-shot
+    grid-aligned arithmetic (``ita_attention_fused_ref``) bit for bit,
+    and decode over the pool equals decode over the ring."""
+    from repro.core.quant import EPS_MAX
+    from repro.kernels.ita_attention.ref import ita_attention_fused_ref
+
+    b, hq, g, d, page, npps, chunk = 3, 4, 2, 16, 16, 20, 8
+    cap = page * npps
+    lens = [cap - 7, 257, cap]                  # 20, 17 and 20 tiles
+    # logits of about 46 steps of spread, as unit-variance q and k give
+    # on the ITA grid: few reach the clip, so the max keeps growing
+    gauss = lambda *s: np.clip(np.round(rng.normal(0, 20, s)),  # noqa: E731
+                               -128, 127).astype(np.int8)
+    q = gauss(b, hq, chunk, d)
+    k_log, v_log = _sorted_streams(q, gauss(b, cap, g, d), _i8(b, cap, g, d),
+                                   lens, order)
+    k_pool, v_pool, pt = _paged_from_logical(k_log, v_log, page)
+    scales = ATT.QuantScales.per_tensor(S_Q, s_out=S_OUT)
+    kv_len = jnp.asarray(lens)
+    q_lens = jnp.asarray([1, 1, chunk])         # decode, decode, prefill
+    offs = kv_len - q_lens
+    spec = ATT.AttentionSpec(mode="decode", impl="ita", layout="bhsd_paged",
+                             out_dtype="int8", q_len=chunk, ragged_q=True)
+    mixed = np.asarray(ATT.dispatch(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool), spec=spec,
+        scales=scales, q_offset=offs, kv_len=kv_len,
+        page_table=jnp.asarray(pt), q_lens=q_lens))
+
+    kr = np.repeat(k_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    vr = np.repeat(v_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    lmult = np.float32(S_Q * S_Q / (np.sqrt(d) * EPS_MAX))
+    rows = lambda x: np.repeat(np.asarray(x), hq)          # noqa: E731
+    ref = np.asarray(ita_attention_fused_ref(
+        jnp.asarray(q.reshape(b * hq, chunk, d)),
+        jnp.asarray(kr.reshape(b * hq, cap, d)),
+        jnp.asarray(vr.reshape(b * hq, cap, d)), lmult,
+        np.float32(S_Q / S_OUT), rows(kv_len), causal=True,
+        q_offset=rows(offs), q_len=rows(q_lens))).reshape(b, hq, chunk, d)
+    np.testing.assert_array_equal(mixed, ref, err_msg="onepass-paged")
+
+    dec_spec = spec.replace(q_len=1, ragged_q=False)
+    dec = ATT.dispatch(jnp.asarray(q[:, :, :1]), jnp.asarray(k_pool),
+                       jnp.asarray(v_pool), spec=dec_spec, scales=scales,
+                       q_offset=kv_len - 1, kv_len=kv_len,
+                       page_table=jnp.asarray(pt),
+                       backend="ita_decode_pallas")
+    ref1 = np.asarray(ita_attention_fused_ref(
+        jnp.asarray(q[:, :, :1].reshape(b * hq, 1, d)),
+        jnp.asarray(kr.reshape(b * hq, cap, d)),
+        jnp.asarray(vr.reshape(b * hq, cap, d)), lmult,
+        np.float32(S_Q / S_OUT), rows(kv_len), causal=True,
+        q_offset=rows(kv_len - 1))).reshape(b, hq, 1, d)
+    np.testing.assert_array_equal(np.asarray(dec), ref1,
+                                  err_msg="decode-paged")
+    np.testing.assert_array_equal(mixed[:2, :, :1], ref1[:2],
+                                  err_msg="decode rows of the mixed call")
+
+    ring_spec = dec_spec.replace(layout="bhsd_bsgd")
+    ring = ATT.dispatch(jnp.asarray(q[:, :, :1]), jnp.asarray(k_log),
+                        jnp.asarray(v_log), spec=ring_spec, scales=scales,
+                        q_offset=kv_len - 1, kv_len=kv_len,
+                        backend="ita_decode_pallas", block_kv=page)
+    np.testing.assert_array_equal(np.asarray(ring), np.asarray(dec),
+                                  err_msg="ring != paged")
+
+
+def test_live_page_table_stops_at_each_sequences_last_live_page():
+    """The decode-paged kernel's index maps read a table cut at each
+    sequence's last live page (its heads' longest ``kv_len``): entries up
+    to that page are the sequence's own, later ones repeat it (a repeated
+    block costs no DMA), and an empty row keeps its first entry."""
+    from repro.kernels.ita_attention.kernel import _live_page_table
+
+    pt = np.arange(1, 1 + 3 * 6, dtype=np.int32).reshape(3, 6)
+    # per-(sequence, head) lengths, 2 heads: the longer head decides
+    kv_len = jnp.asarray([33, 64, 0, 0, 1, 96], jnp.int32)
+    got = np.asarray(_live_page_table(jnp.asarray(pt), kv_len, 2, 16))
+    last = [3, 0, 5]                        # pages 0..3, 0, 0..5
+    for b in range(3):
+        want = pt[b, np.minimum(np.arange(6), last[b])]
+        np.testing.assert_array_equal(got[b], want, err_msg=f"sequence {b}")
+
+
+@pytest.mark.parametrize("hq,g", [(4, 4), (4, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_decode_paged_heads_of_one_sequence_at_their_own_lengths(hq, g):
+    """One grid step serves every head of a sequence: heads whose
+    ``kv_len`` differ (one of them empty) and sequences at different
+    lengths each equal the one-shot aligned arithmetic, with the table's
+    entries past the live pages pointing at a page of noise."""
+    from repro.core.quant import EPS_MAX
+    from repro.kernels.ita_attention.kernel import ita_attention_decode_paged
+    from repro.kernels.ita_attention.ref import ita_attention_fused_ref
+
+    b, d, page, npps = 2, 16, 16, 8
+    cap = page * npps
+    k_log, v_log = _i8(b, cap, g, d), _i8(b, cap, g, d)
+    k_pool, v_pool, pt = _paged_from_logical(k_log, v_log, page)
+    noise = k_pool.shape[0]
+    k_pool = np.concatenate([k_pool, _i8(1, g, page, d)])
+    v_pool = np.concatenate([v_pool, _i8(1, g, page, d)])
+    kv_rows = np.array([[cap, 0, 37, 100][:hq], [5, 16, 17, 64][:hq]],
+                       np.int32)
+    last = (kv_rows.max(1) + page - 1) // page
+    for bb in range(b):
+        pt[bb, last[bb]:] = noise
+    q = _i8(b * hq, 1, d)
+    lmult = np.float32(S_Q * S_Q / (np.sqrt(d) * EPS_MAX))
+    omult = np.float32(S_Q / S_OUT)
+    rows = kv_rows.reshape(-1)
+    out = ita_attention_decode_paged(
+        jnp.asarray(q), jnp.asarray(k_pool)[None], jnp.asarray(v_pool)[None],
+        jnp.asarray(pt), lmult, omult, jnp.asarray(rows),
+        q_offset=jnp.asarray(np.maximum(rows - 1, 0)), kv_rep=hq // g,
+        hq=hq)
+    kr = np.repeat(k_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    vr = np.repeat(v_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    ref = ita_attention_fused_ref(
+        jnp.asarray(q), jnp.asarray(kr.reshape(b * hq, cap, d)),
+        jnp.asarray(vr.reshape(b * hq, cap, d)), lmult, omult,
+        jnp.asarray(rows), causal=True,
+        q_offset=jnp.asarray(np.maximum(rows - 1, 0)))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    assert not np.asarray(out)[rows == 0].any()
+
+
+def test_serve_over_many_small_pages_matches_solo_generate():
+    """The fused kernels' softmax no longer depends on the KV tiling: a
+    serve over 16-token pages (prompts of up to 12 pages, chunked
+    prefill, answers that cross more pages) gives every request the
+    greedy tokens it gets alone from ``generate()`` on a ring read in
+    128-token tiles."""
+    from repro.configs.base import ModelConfig
+    from repro.models import init_model
+    from repro.runtime.generate import (ServeRequest, generate,
+                                        serve_continuous)
+    cfg = ModelConfig(name="kv-many-tiles", family="dense", d_model=48,
+                      n_heads=4, n_kv_heads=2, head_dim=24, d_ff=96,
+                      vocab_size=128, layer_groups=((("attn",), 2),),
+                      dtype="float32", attention_impl="ita",
+                      attention_backend="ita_onepass_pallas")
+    params = init_model(jax.random.PRNGKey(3), cfg)
+    prng = np.random.default_rng(6)
+    reqs = [ServeRequest(
+        prompt=prng.integers(0, cfg.vocab_size, int(n)).astype(np.int32),
+        gen=int(g), arrival=int(a))
+        for n, g, a in ((180, 24, 0), (97, 40, 0), (33, 20, 2))]
+    res = serve_continuous(params, cfg, reqs, slots=2, segment=8,
+                           max_len=256, page_size=16, chunk_size=32)
+    assert len(res.completed) == len(reqs)
+    for c in res.completed:
+        r = reqs[c.index]
+        solo = generate(params, cfg, jnp.asarray(r.prompt)[None], r.gen,
+                        max_len=256)
+        np.testing.assert_array_equal(np.asarray(c.tokens),
+                                      np.asarray(solo.tokens)[0],
+                                      err_msg=f"request {c.index}")

@@ -713,3 +713,58 @@ def test_paged_caches_validation_names_fields():
     res = generate(params, CFG, prompts, 4, max_len=MAX_LEN,
                    caches=small_pool)
     assert res.tokens.shape == (2, 4)
+
+
+def test_pool_ledger_counts_the_pages_the_device_holds(monkeypatch):
+    """With a pool too small for every slot's worst case: admission holds
+    requests back for pages while slots stand free (and counts it), each
+    round's pages in use (host ledger, no device sync) equal the pages the
+    device's page tables and pins hold, and reservation runs ahead of use."""
+    import jax
+
+    import repro.runtime.generate as gen
+    from repro.attention.state import PARKING_PAGE
+    from repro.configs.base import ModelConfig
+    from repro.models import init_model
+    from repro.runtime.generate import ServeRequest, serve_continuous
+
+    cfg = ModelConfig(name="pool-ledger", family="dense", d_model=32,
+                      n_heads=2, n_kv_heads=2, head_dim=16, d_ff=64,
+                      vocab_size=64, layer_groups=((("attn",), 2),),
+                      dtype="float32", attention_impl="ita",
+                      attention_backend="ita_onepass_pallas")
+    params = init_model(jax.random.PRNGKey(4), cfg)
+    prng = np.random.default_rng(7)
+    reqs = [ServeRequest(prompt=prng.integers(0, 64, int(n)).astype(np.int32),
+                         gen=int(g), arrival=int(a))
+            for n, g, a in ((20, 60, 0), (35, 40, 0), (12, 50, 0),
+                            (28, 30, 4), (9, 20, 8))]
+    pool_args = []
+    real = jax.profiler.TraceAnnotation
+
+    class Recorder(real):
+        def __init__(self, name, **kw):
+            if name == "serve.pool":
+                pool_args.append(kw)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    checked = []
+
+    def audit(caches, slot_req, pins):
+        table = np.asarray(gen._first_paged(caches).page_table[0])
+        held = {int(p) for s, i in enumerate(slot_req) if i is not None
+                for p in table[s] if p != PARKING_PAGE} | set(pins)
+        checked.append((len(held), pool_args[-1]["pages_in_use"]))
+
+    res = serve_continuous(params, cfg, reqs, slots=3, segment=4,
+                           max_len=128, page_size=16, num_pages=13,
+                           chunk_size=16, audit=audit)
+    assert len(res.completed) == len(reqs)
+    assert res.pool_pages == 12
+    assert res.admissions_held_for_pages > 0
+    assert len(checked) > 2 and all(dev == host for dev, host in checked)
+    assert [u for _, _, u in res.pool_rounds] \
+        == [a["pages_in_use"] for a in pool_args]
+    assert all(0 <= u <= r <= 12 for _, r, u in res.pool_rounds)
+    assert any(u < r for _, r, u in res.pool_rounds)

@@ -162,3 +162,18 @@ def test_segment_ops_carry_the_program_scopes():
     for scope in ("embed", "attn_qkv", "kv_write", "attn_kernel",
                   "attn_out", "mlp", "layer_carry", "head", "sample"):
         assert any(scope in n.split("/") for n in names), scope
+
+
+def test_pool_span_args_are_the_host_page_ledger(served):
+    """Each round's ``serve.pool`` span carries the pages reserved, in
+    use and left unreserved, as ``ServeResult.pool_rounds`` records them:
+    reserved + free is the pool, and no round uses more than it reserves."""
+    _, res, spans, _ = served
+    pool = [s[3] for s in spans if s[0] == "serve.pool"]
+    assert len(pool) == len(res.pool_rounds) == res.segments
+    for args, (_, reserved, in_use) in zip(pool, res.pool_rounds):
+        assert (args["pages_reserved"], args["pages_in_use"]) \
+            == (reserved, in_use)
+        assert args["pages_reserved"] + args["pages_free"] == res.pool_pages
+        assert 0 <= in_use <= reserved <= res.pool_pages
+    assert max(u for _, _, u in res.pool_rounds) > 0

@@ -151,3 +151,87 @@ def test_softermax_close_to_float(seed):
     pf = np.asarray(S.softmax_float(jnp.asarray(xq)))
     ps = np.asarray(S.softermax(jnp.asarray(xq)))
     assert np.abs(ps - pf).mean() < 5e-3
+
+
+# ---------------------------------------------------------------------------
+# Grid-aligned DA: the fused kernels' arithmetic
+# ---------------------------------------------------------------------------
+
+def _ordered(xq, order):
+    if order == "ascending":            # the max grows in every part
+        return np.sort(xq, axis=-1)
+    if order == "descending":           # the max comes first
+        return np.sort(xq, axis=-1)[:, ::-1].copy()
+    return xq
+
+
+@pytest.mark.parametrize("order", ["ascending", "descending", "random"])
+def test_aligned_streaming_equals_oneshot_any_order(order):
+    """Streaming DA on the 2^5 grid composes exactly: probabilities and
+    sigma equal the one-shot pass bit for bit, whichever part holds the
+    max; masked keys and a fully masked row included."""
+    rng = np.random.default_rng(9)
+    xq = _ordered(_quantize(rng.normal(0, 1.5, (16, 512))), order)
+    mask = rng.random(xq.shape) > 0.1
+    mask[3] = False
+    x, m = jnp.asarray(xq), jnp.asarray(mask)
+    np.testing.assert_array_equal(
+        np.asarray(S.ita_softmax_aligned_streaming(x, 16, mask=m)),
+        np.asarray(S.ita_softmax_aligned(x, mask=m)))
+    carry_b = jnp.full((16, 1), -8, jnp.int32)
+    carry_s = jnp.zeros((16, 1), jnp.int32)
+    for i in range(16):
+        sl = slice(32 * i, 32 * (i + 1))
+        carry_b, carry_s = S.ita_da_update_aligned(carry_b, carry_s,
+                                                   x[:, sl], m[:, sl])
+    _, sigma, row_b = S.ita_softmax_aligned_int(x, mask=m)
+    np.testing.assert_array_equal(np.asarray(carry_s), np.asarray(sigma))
+    np.testing.assert_array_equal(np.asarray(carry_b), np.asarray(row_b))
+
+
+def test_paper_streaming_leaves_oneshot_when_max_grows():
+    """The paper's multi-part update, kept as the paper defines it: when
+    the max grows part by part its floors do not compose and streaming
+    leaves the one-shot result; the aligned update does not."""
+    rng = np.random.default_rng(10)
+    xq = _ordered(_quantize(rng.normal(0, 1.5, (16, 512))), "ascending")
+    x = jnp.asarray(xq)
+    paper = np.asarray(S.ita_softmax_streaming(x, 16))
+    assert (paper != np.asarray(S.ita_softmax(x))).any()
+    np.testing.assert_array_equal(
+        np.asarray(S.ita_softmax_aligned_streaming(x, 16)),
+        np.asarray(S.ita_softmax_aligned(x)))
+
+
+def test_aligned_oneshot_matches_formula():
+    """k = (max >> 5) - (x >> 5), sigma = sum 256 >> k, p = (2^16 //
+    sigma) >> k; a row whose max is 127 (bucket edge) equals the paper's
+    arithmetic exactly."""
+    x = np.array([[10, -20, 100, 126, -128], [10, -20, 100, 127, -128]],
+                 np.int8)
+    p, sigma, rb = S.ita_softmax_aligned_int(jnp.asarray(x))
+    xi = x.astype(np.int64)
+    k = (xi.max(-1, keepdims=True) >> 5) - (xi >> 5)
+    sig = (256 >> k).sum(-1)
+    np.testing.assert_array_equal(np.asarray(sigma)[:, 0], sig)
+    np.testing.assert_array_equal(np.asarray(rb)[:, 0], [3, 3])
+    np.testing.assert_array_equal(np.asarray(p),
+                                  ((1 << 16) // sig)[:, None] >> k)
+    np.testing.assert_array_equal(
+        np.asarray(p)[1], np.asarray(S.ita_softmax_int(jnp.asarray(x))[0])[1])
+
+
+@settings(max_examples=25, deadline=None)
+@given(st.integers(0, 2 ** 31 - 1), st.sampled_from([2, 4, 8, 16]))
+def test_aligned_streaming_mae_matches_paper_oneshot(seed, parts):
+    """The aligned arithmetic is no coarser than the paper's: one factor
+    of two per bucket. Over rows of logits at a realistic spread its MAE
+    to float stays within 1.5x of the one-shot paper softmax's, and it
+    equals its own one-shot result at any part count."""
+    rng = np.random.default_rng(seed)
+    xq = jnp.asarray(_quantize(rng.normal(0, 1.0, (16, 256))))
+    pa = np.asarray(S.ita_softmax_aligned_streaming(xq, parts))
+    np.testing.assert_array_equal(pa, np.asarray(S.ita_softmax_aligned(xq)))
+    pf = np.asarray(S.softmax_float(xq))
+    mae_paper = np.abs(np.asarray(S.ita_softmax(xq)) - pf).mean()
+    assert np.abs(pa - pf).mean() < 1.5 * mae_paper
