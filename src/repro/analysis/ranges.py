@@ -941,6 +941,7 @@ _TRANSFER = {
     "slice": _t_identity,
     "rev": _t_identity,
     "copy": _t_identity,
+    "multiple_of": _t_identity,
     "dynamic_slice": _t_identity,
     "dynamic_update_slice": _t_dus,
     "gather": _t_identity,

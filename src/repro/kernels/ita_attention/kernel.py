@@ -91,27 +91,29 @@ rows (q_len = chunk). Scalars broadcast to all rows (the dense case).
 Paged KV pool: the ``*_paged`` entry points consume one shared
 head-major ``(num_pages, G, page_size, hd)`` int8 arena per layer,
 stacked over the model's layers as ``(L, num_pages, G, page_size, hd)``
-(a onepass block is one kv head's ``(page_size, hd)`` page, a decode
-block the page of every kv head: layouts Mosaic tiles) through a **page
-table** delivered as a flat scalar-prefetch operand, next to the layer
-index — the KV BlockSpec index map reads
+(a block is the page of every kv head, a layout Mosaic tiles) through a
+**page table** delivered as a flat scalar-prefetch operand, next to the
+layer index — the KV BlockSpec index map reads
 ``page_table[b * n_pages + j]`` to translate logical KV tile ``j`` of
 sequence ``b`` into a physical page of layer ``layer``'s arena, so
-scattered pages stream through the very
-same arithmetic (``onepass_kernel``; ``decode_heads_kernel`` for decode)
-tile-for-tile.
-The aligned DA makes the result independent of the tile schedule, so
-paged decode is bit-identical to the ring (the ``ita_fused`` family
-invariant) at ``block_kv == page_size`` and at any other page size.
+scattered pages stream through the very same per-head arithmetic
+(``paged_heads_kernel``) tile-for-tile. The aligned DA makes the result
+independent of the tile schedule, so paged decode is bit-identical to
+the ring (the ``ita_fused`` family invariant) at ``block_kv ==
+page_size`` and at any other page size.
 
-Paged decode takes one grid step per (sequence, logical page), not per
-(sequence, head, page): its block is the page of every kv head, and
-``decode_heads_kernel`` streams the sequence's query heads through it
-one after another. A decode tile is (8, 128) of work, so a step per head
-spent most of a call in the grid's per-step cost (32 heads x 32 pages x
-16 slots a layer for phi3-mini at 4k). The page table it reads stops at
-each sequence's last live page (``_live_page_table``), so pages past it
-cost neither DMA nor work, and an empty slot costs one step per page.
+Both paged kernels take one grid step per (sequence, q tile, logical
+page), not per (sequence, head, q tile, page): the block is the page of
+every kv head, and ``paged_heads_kernel`` streams the sequence's query
+heads through it: the ``kv_rep`` heads of a kv head as one stacked tile
+(GQA), and as many kv heads laid out together as fit ``HEAD_ROWS`` rows
+(16 MHA heads of a decode tile, 4 of a 32-row chunk). A step per head
+spent most of a call in the grid's per-step cost: 28 heads x 130 pages
+x 16 slots a layer of chunked prefill for qwen2-7b at 16k. Paged decode
+is the case of one q tile of ``sq <= 8`` rows. The page table both read
+stops at each sequence's last live page (``_live_page_table``), so pages
+past it cost neither DMA nor work, and an empty slot costs one step per
+page.
 """
 
 from __future__ import annotations
@@ -186,10 +188,10 @@ def _emit(o_ref, y, float_out, h=0):
 
 
 def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
-                      float_out, h=None):
-    """DI and the output requant of a row's stats; ``h`` picks a head's
-    slot of the scratch and of the output block (one row's by default)."""
-    at = ... if h is None else h
+                      float_out, at=..., h=0):
+    """DI and the output requant of a row's stats: ``at`` picks a head's
+    rows of the scratch, ``h`` its tile of the output block (one row's
+    whole scratch and tile by default)."""
     if adaptive:
         inv, e_r = adaptive_inverse(sigma_ref[at])
     else:
@@ -199,21 +201,13 @@ def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
     # float output); the 2 restores the halved numerator unit (u = 128>>k
     # vs the paper's 256>>k).
     scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-    _emit(o_ref, acc_ref[at].astype(jnp.float32) * scale, float_out,
-          0 if h is None else h)
-
-
-def _kv_tile(ref, paged):
-    """(bkv, d) tile of a K/V block: (1, bkv, d) rows of the 3-D kernel
-    layout, or (1, 1, 1, page, d) of the stacked head-major paged pool."""
-    return ref[0, 0, 0] if paged else ref[0]
+    _emit(o_ref, acc_ref[at].astype(jnp.float32) * scale, float_out, h)
 
 
 def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
                    o_ref, b_ref, sigma_ref, acc_ref,
                    *, causal: bool, window: int, adaptive: bool,
-                   bq: int, bkv: int, paged: bool = False,
-                   float_out: bool = False):
+                   bq: int, bkv: int, float_out: bool = False):
     i, j = pl.program_id(1), pl.program_id(2)
     lmult, omult, kv_len, q_off, q_len = _row_scalars(lmult_ref, omult_ref,
                                                       meta_ref)
@@ -228,8 +222,8 @@ def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     @pl.when(j * bkv < kv_len)
     def _tile():
         valid = tile_mask(i, j, bq, bkv, causal, window, kv_len, q_off, q_len)
-        _stream_tile(q_ref[0], _kv_tile(k_ref, paged), _kv_tile(v_ref, paged),
-                     lmult, b_ref, sigma_ref, acc_ref, valid)
+        _stream_tile(q_ref[0], k_ref[0], v_ref[0], lmult, b_ref, sigma_ref,
+                     acc_ref, valid)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -314,35 +308,53 @@ def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
                           float_out)
 
 
-# The paged decode kernel lays out this many query heads' tiles together
-# at most (the largest divisor of the head count up to it): on a v5e at
-# phi3-mini's 32 heads, 16 took 0.83 ms a layer-call of 16 sequences at
-# 300-3500 tokens, against 1.10 for 8, 1.18 for 32 and 4.0 for one.
-HEAD_GROUP = 16
+# The paged kernels stack the query heads that share a kv head into one
+# (kv_rep * bq, d) tile (one Q·Kᵀ and one A·V matmul per kv head), and
+# lay out together the most kv heads that divide the kv head count and
+# whose stacked rows fit in HEAD_ROWS (one kv head at least). One
+# layer-call on a v5e, 16 sequences, ms by kv heads together:
+# - decode, phi3-mini (32 heads, MHA, 300-3500 tokens): 16 (the rule)
+#   0.83, 8 1.10, 32 1.18, 1 4.0;
+# - 32-row chunks, phi3-mini (2 chunk rows, 14 decode rows): 4 (the
+#   rule) 2.54, 2 3.52, 8 2.86, 16 3.53; one step per head 8.57;
+# - 32-row chunks, qwen2-7b (28/4 heads, 8 chunk rows, 8 decode rows,
+#   2k-16k tokens): 7 heads stacked (224 rows, the rule) 4.35, two kv
+#   heads 5.50, four 7.19; heads not stacked, 4 together 6.75, 7 7.71,
+#   14 9.35, 1 16.7; one step per head 28.5.
+HEAD_ROWS = 128
 
 
-def _head_group(hq: int) -> int:
-    return max(g for g in range(1, min(hq, HEAD_GROUP) + 1) if hq % g == 0)
+def _head_group(g: int, rows: int) -> int:
+    """Kv heads laid out together, of ``g``, each ``rows`` stacked rows."""
+    return max(n for n in range(1, g + 1)
+               if g % n == 0 and (n == 1 or n * rows <= HEAD_ROWS))
 
 
-def decode_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
-                        o_ref, b_ref, sigma_ref, acc_ref,
-                        *, causal: bool, window: int, adaptive: bool,
-                        hq: int, kv_rep: int, group: int, bq: int, bkv: int,
-                        float_out: bool = False):
-    """The decode dataflow with one grid step per (sequence, KV page): the
-    block holds the page of every kv head and the body streams each of
-    the sequence's ``hq`` query heads through it, as ``decode_kernel``
-    streams row ``b * hq + h`` (the same arithmetic on the same tiles, so
-    the same bits). A grid step per head would pay the grid's per-step
-    cost ``hq`` times over for a (8, 128) tile of work. A page is skipped
-    when it lies past every head's valid prefix; within a live page a
-    head past its own prefix streams a fully masked tile, which changes
-    none of its (bucket, Σ, accumulator), so the heads' tiles run with no
-    branch between them and ``group`` of them are laid out together for
-    the scheduler to overlap."""
-    b, j = pl.program_id(0), pl.program_id(1)
-    rows = b * hq
+def paged_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
+                       o_ref, b_ref, sigma_ref, acc_ref,
+                       *, causal: bool, window: int, adaptive: bool,
+                       hq: int, kv_rep: int, group: int, bq: int, bkv: int,
+                       q_tiles: int, float_out: bool = False):
+    """The onepass dataflow with one grid step per (sequence, q tile, KV
+    page): the block holds the page of every kv head and the body streams
+    each of the sequence's ``hq`` query heads through it, as
+    ``onepass_kernel`` streams row ``b * hq + h`` (the same arithmetic on
+    the same tiles, so the same bits). A grid step per head would pay the
+    grid's per-step cost ``hq`` times over for one head's tile of work.
+
+    The ``kv_rep`` query heads of a kv head stream as one stacked
+    ``(kv_rep * bq, d)`` tile: the DA statistics are per row, so each
+    head's rows see the same arithmetic as alone, under their own mask
+    and logit multiplier. A page is skipped when it lies past every
+    head's valid prefix; within a live page a head past its own prefix
+    streams fully masked rows, which change none of its (bucket, Σ,
+    accumulator), so the kv heads' tiles run with no branch between them
+    and ``group`` of them are laid out together for the scheduler to
+    overlap. The scratch holds a ``(kv_rep * bq, ·)`` slot per kv head."""
+    s, j = pl.program_id(0), pl.program_id(2)
+    i = pl.program_id(1) if q_tiles > 1 else 0
+    rows = s * hq
+    d = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -352,27 +364,35 @@ def decode_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
         0, hq, lambda h, m: jnp.maximum(m, meta_ref[3 * (rows + h)]),
         jnp.int32(0))
 
+    def kv_head(kv):
+        h0 = kv * kv_rep
+        heads = [_row_scalars(lmult_ref, omult_ref, meta_ref, rows + h0 + r)
+                 for r in range(kv_rep)]
+        masks = [tile_mask(i, j, bq, bkv, causal, window, kv_len, q_off,
+                           q_len) for _, _, kv_len, q_off, q_len in heads]
+        if kv_rep == 1:
+            lmult, valid = heads[0][0], masks[0]
+        else:        # one logit multiplier per row: each head's own
+            lmult = jnp.concatenate([jnp.full((bq, 1), m) for m, *_ in heads])
+            valid = jnp.concatenate(masks)
+        q = q_ref[pl.ds(h0, kv_rep)].reshape(kv_rep * bq, d)
+        _stream_tile(q, k_ref[0, 0, kv], v_ref[0, 0, kv], lmult, b_ref,
+                     sigma_ref, acc_ref, valid, kv)
+
     @pl.when(j * bkv < seq_len)
     def _page():
-        def heads(t, carry):
+        def kv_heads(t, carry):
             for u in range(group):
-                h = t * group + u
-                lmult, _, kv_len, q_off, q_len = _row_scalars(
-                    lmult_ref, omult_ref, meta_ref, rows + h)
-                valid = tile_mask(0, j, bq, bkv, causal, window, kv_len,
-                                  q_off, q_len)
-                _stream_tile(q_ref[h], k_ref[0, 0, h // kv_rep],
-                             v_ref[0, 0, h // kv_rep], lmult, b_ref,
-                             sigma_ref, acc_ref, valid, h)
+                kv_head(t * group + u)
             return carry
-        jax.lax.fori_loop(0, hq // group, heads, 0)
+        jax.lax.fori_loop(0, hq // kv_rep // group, kv_heads, 0)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         def head(h, carry):
-            omult = omult_ref[rows + h]
-            _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
-                              float_out, h)
+            at = (h // kv_rep, pl.ds(pl.multiple_of(h % kv_rep * bq, bq), bq))
+            _finalize_onepass(o_ref, sigma_ref, acc_ref, omult_ref[rows + h],
+                              adaptive, float_out, at, h)
             return carry
         jax.lax.fori_loop(0, hq, head, 0)
 
@@ -560,52 +580,12 @@ def ita_attention_decode(q_q, k_q, v_q, logit_mult, out_mult, kv_len, *,
 
 def _swallow_pt(kern):
     """Scalar-prefetch calling convention hands the page-table and layer
-    refs to the kernel body as its first arguments; the compute bodies
-    never touch them (all translation happens in the index maps), so
-    drop them here — the paged kernels stay byte-for-byte the ring
-    kernels."""
+    refs to the kernel body as its first arguments; the compute body
+    never touches them (all translation happens in the index maps), so
+    drop them here."""
     def wrapped(pt_ref, layer_ref, *refs):
         return kern(*refs)
     return wrapped
-
-
-def _paged_kv_spec(page, d, hq, kv_rep, n_pages):
-    """K/V BlockSpec of the stacked head-major pool on the onepass grid
-    (row, q tile, KV tile): kernel row ``r`` (batch ``r // hq``, head
-    ``r % hq``) reads its kv head's page ``pt[(r // hq) * n_pages + j]``
-    of layer ``layer[0]`` for logical KV tile ``j``."""
-    def page_of(r, i, j, pt, layer):
-        return (layer[0], pt[(r // hq) * n_pages + j], (r % hq) // kv_rep,
-                0, 0)
-    return pl.BlockSpec((1, 1, 1, page, d), page_of)
-
-
-def _paged_call(name, kern, grid, q_spec, kv_spec, q_q, k_pool, v_pool,
-                page_table, layer, lmult, omult, meta, bq, float_out,
-                interpret, heads=()):
-    """Shared pallas_call of the paged kernels, named ``name``: the flat
-    page table and the layer index are the scalar-prefetch operands the
-    K/V index maps read. ``heads``: the leading shape of the per-row
-    (max bucket, Σ, accumulator) scratch, ``(hq,)`` when one grid step
-    serves every head of a sequence."""
-    bh, sq, d = q_q.shape
-    heads = tuple(heads)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM(heads + (bq, 1), jnp.int32),
-                        pltpu.VMEM(heads + (bq, 1), jnp.int32),
-                        pltpu.VMEM(heads + (bq, d), jnp.int32)])
-    return pl.pallas_call(
-        _swallow_pt(kern),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
-        interpret=interpret,
-        name=name,
-    )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1), q_q,
-      k_pool, v_pool, lmult, omult, meta)
 
 
 def _live_page_table(page_table, kv_len, hq, page_size):
@@ -620,6 +600,57 @@ def _live_page_table(page_table, kv_len, hq, page_size):
     tiles = jnp.minimum(jnp.arange(n_pages, dtype=jnp.int32)[None, :],
                         last[:, None])
     return jnp.take_along_axis(page_table, tiles, axis=1)
+
+
+def _paged_heads_call(name, q_q, k_pool, v_pool, page_table, logit_mult,
+                      out_mult, kv_len, *, layer, q_offset, q_len, causal,
+                      window, adaptive, bq, kv_rep, hq, float_out,
+                      interpret):
+    """The paged kernels' shared ``pallas_call``, named ``name``: grid
+    (sequence, q tile, logical page), ``paged_heads_kernel`` as the body.
+    Logical page ``j`` of sequence ``s`` is DMA'd for all ``G`` kv heads
+    at once from ``pool[layer, page_table[s, j]]`` by a scalar-prefetch
+    index map (the flat page table and the layer index are the two
+    scalar-prefetch operands), after the table is cut at each sequence's
+    last live page. The q and output blocks are the sequence's ``hq``
+    rows of a q tile; the (max bucket, Σ, accumulator) scratch has a slot
+    of ``kv_rep * bq`` rows per kv head."""
+    bh, sq, d = q_q.shape
+    page, g = k_pool.shape[3], k_pool.shape[2]
+    b, n_pages = page_table.shape
+    assert sq % bq == 0, (sq, bq)
+    assert b * hq == bh and hq == g * kv_rep, \
+        (bh, hq, g, kv_rep, page_table.shape)
+    kern = functools.partial(paged_heads_kernel, causal=causal,
+                             window=window, adaptive=adaptive, hq=hq,
+                             kv_rep=kv_rep, group=_head_group(g, kv_rep * bq),
+                             bq=bq, bkv=page, q_tiles=sq // bq,
+                             float_out=float_out)
+    lmult, omult = _row_mults(logit_mult, out_mult, bh)
+    meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
+    page_table = _live_page_table(page_table, meta.reshape(bh, 3)[:, 0], hq,
+                                  page)
+
+    def kv_block(s, i, j, pt, lyr):
+        return (lyr[0], pt[s * n_pages + j], 0, 0, 0)
+    q_spec = pl.BlockSpec((hq, bq, d), lambda s, i, j, *_: (s, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, g, page, d), kv_block)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, sq // bq, n_pages),
+        in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((g, kv_rep * bq, 1), jnp.int32),
+                        pltpu.VMEM((g, kv_rep * bq, 1), jnp.int32),
+                        pltpu.VMEM((g, kv_rep * bq, d), jnp.int32)])
+    return pl.pallas_call(
+        _swallow_pt(kern),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
+        interpret=interpret,
+        name=name,
+    )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1), q_q,
+      k_pool, v_pool, lmult, omult, meta)
 
 
 def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
@@ -638,38 +669,18 @@ def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,
     page to a physical arena page (entries beyond the valid prefix may
     point anywhere — those tiles are skipped/masked via ``kv_len``).
 
-    The grid is (sequence, logical page): logical page ``j`` of sequence
-    ``b`` is DMA'd for all ``G`` kv heads at once from ``pool[layer,
-    page_table[b, j]]`` by a scalar-prefetch index map, and the body
-    (``decode_heads_kernel``) streams each of the ``hq`` query heads of
-    the sequence through it. Pages past a sequence's last live page are
-    neither read nor computed. Per head the DA streaming schedule is
-    ``ita_attention_decode``'s at ``block_kv == page_size`` — paged decode
-    is bit-identical to the contiguous ring path (family ``ita_fused``).
+    One q tile holds every query: the grid is (sequence, 1, logical
+    page), see ``_paged_heads_call``. Pages past a sequence's last live
+    page are neither read nor computed. Per head the DA streaming
+    schedule is ``ita_attention_decode``'s at ``block_kv == page_size`` —
+    paged decode is bit-identical to the contiguous ring path (family
+    ``ita_fused``).
     """
-    bh, sq, d = q_q.shape
-    page = k_pool.shape[3]
-    g = k_pool.shape[2]
-    b, n_pages = page_table.shape
-    assert bh % hq == 0 and b * hq == bh and hq == g * kv_rep, \
-        (bh, hq, g, kv_rep, page_table.shape)
-    kern = functools.partial(decode_heads_kernel, causal=causal,
-                             window=window, adaptive=adaptive, hq=hq,
-                             kv_rep=kv_rep, group=_head_group(hq),
-                             bq=sq, bkv=page, float_out=float_out)
-    lmult, omult = _row_mults(logit_mult, out_mult, bh)
-    meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    page_table = _live_page_table(page_table, meta.reshape(bh, 3)[:, 0], hq,
-                                  page)
-
-    def kv_block(s, j, pt, lyr):
-        return (lyr[0], pt[s * n_pages + j], 0, 0, 0)
-    return _paged_call(
-        "ita_decode_paged", kern, (b, n_pages),
-        pl.BlockSpec((hq, sq, d), lambda s, j, *_: (s, 0, 0)),
-        pl.BlockSpec((1, 1, g, page, d), kv_block),
-        q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, sq,
-        float_out, interpret, heads=(hq,))
+    return _paged_heads_call(
+        "ita_decode_paged", q_q, k_pool, v_pool, page_table, logit_mult,
+        out_mult, kv_len, layer=layer, q_offset=q_offset, q_len=q_len,
+        causal=causal, window=window, adaptive=adaptive, bq=q_q.shape[1],
+        kv_rep=kv_rep, hq=hq, float_out=float_out, interpret=interpret)
 
 
 def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
@@ -681,27 +692,16 @@ def ita_attention_onepass_paged(q_q, k_pool, v_pool, page_table, logit_mult,
                                 interpret: bool = True):
     """Flash-style onepass over a paged KV pool (prefill-from-pool, decode
     bursts longer than the decode kernel's single tile, and the mixed
-    chunked-prefill/decode serve step). Grid, layer and page translation
-    as in
+    chunked-prefill/decode serve step). Pool, page table and layer as in
     ``ita_attention_decode_paged``, with the q tiling axis of
-    ``ita_attention_onepass`` restored. ``q_len`` (scalar or per-row)
-    marks each row's count of valid query rows — ragged q_len: one call
-    serves rows with q widths in {1, chunk} (pad rows emit zeros)."""
-    bh, sq, d = q_q.shape
-    page = k_pool.shape[3]
-    n_pages = page_table.shape[1]
-    bq = min(block_q, sq)
-    assert sq % bq == 0, (sq, bq)
-    assert bh % hq == 0 and page_table.shape[0] * hq == bh, \
-        (bh, hq, page_table.shape)
-    kern = functools.partial(onepass_kernel, causal=causal, window=window,
-                             adaptive=adaptive, bq=bq, bkv=page, paged=True,
-                             float_out=float_out)
-    lmult, omult = _row_mults(logit_mult, out_mult, bh)
-    meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    return _paged_call(
-        "ita_onepass_paged", kern, (bh, sq // bq, n_pages),
-        pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
-        _paged_kv_spec(page, d, hq, kv_rep, n_pages),
-        q_q, k_pool, v_pool, page_table, layer, lmult, omult, meta, bq,
-        float_out, interpret)
+    ``ita_attention_onepass`` restored: the grid is (sequence, q tile,
+    logical page), see ``_paged_heads_call``. ``q_len`` (scalar or
+    per-row) marks each row's count of valid query rows — ragged q_len:
+    one call serves rows with q widths in {1, chunk} (pad rows emit
+    zeros)."""
+    return _paged_heads_call(
+        "ita_onepass_paged", q_q, k_pool, v_pool, page_table, logit_mult,
+        out_mult, kv_len, layer=layer, q_offset=q_offset, q_len=q_len,
+        causal=causal, window=window, adaptive=adaptive,
+        bq=min(block_q, q_q.shape[1]), kv_rep=kv_rep, hq=hq,
+        float_out=float_out, interpret=interpret)

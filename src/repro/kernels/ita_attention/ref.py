@@ -87,23 +87,26 @@ def ita_attention_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     return out, logits.astype(jnp.int8)
 
 
-def _fused_out(acc, sigma, omult, adaptive):
+def _fused_out(acc, sigma, omult, adaptive, float_out=False):
     """The fused kernels' output requant of an int32 ``u·V`` accumulator
     (``_finalize_onepass``: Σ_inv and the halved numerator unit folded
-    into one f32 multiplier)."""
+    into one f32 multiplier); with ``float_out`` the f32 product itself."""
     inv, e_r = _inverse(sigma, adaptive)
     scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-    y = jnp.round(acc.astype(jnp.float32) * scale)
-    return jnp.clip(y, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    y = acc.astype(jnp.float32) * scale
+    if float_out:
+        return y
+    return jnp.clip(jnp.round(y), INT8_MIN, INT8_MAX).astype(jnp.int8)
 
 
 def ita_attention_fused_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
                             window=0, adaptive=True, q_offset=0,
-                            q_len=None):
+                            q_len=None, float_out=False):
     """One-shot fused-EN reference (u = 128>>k numerators, the grid-aligned
     exponent of ``core.softmax.ita_softmax_aligned_int``). ``kv_len``,
     ``q_offset`` and ``q_len`` may be per-row ``(bh,)`` vectors, as the
-    kernels take them; query rows past a row's ``q_len`` come out 0."""
+    kernels take them; query rows past a row's ``q_len`` come out 0.
+    ``float_out``: the kernels' f32 output instead of the int8 one."""
     bh, sq, skv = q_q.shape[0], q_q.shape[1], k_q.shape[1]
     qi = jnp.asarray(q_offset, jnp.int32).reshape(-1, 1, 1) \
         + jnp.arange(sq, dtype=jnp.int32)[None, :, None]
@@ -124,7 +127,7 @@ def ita_attention_fused_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,
     u = jax.lax.shift_right_logical(jnp.int32(128), k)
     acc = jnp.einsum("bqk,bkd->bqd", u, v_q.astype(jnp.int32))
     omult = jnp.asarray(omult, jnp.float32).reshape(-1, 1, 1)
-    return _fused_out(acc, sigma, omult, adaptive)
+    return _fused_out(acc, sigma, omult, adaptive, float_out)
 
 
 def ita_attention_stream_ref(q_q, k_q, v_q, lmult, omult, kv_len, *, causal,

@@ -173,29 +173,53 @@ def test_mutant_dropped_requant_clip_is_flagged(monkeypatch):
     assert "narrowing" in {f["kind"] for f in r["findings"]}
 
 
-def test_mutant_dropped_shift_clamp_is_flagged(monkeypatch):
-    """Remove the clip of k from the fused kernels' DA update: the
-    bucket difference ``max_bucket - (x >> 5)`` is not provably >= 0
-    (relational), so the 128 >> k shift is no longer proven legal."""
-    import repro.kernels.ita_attention.kernel as K
+def _da_noclamp(b_ref, sigma_ref, logits_i32, valid, at=...):
+    """``da_update_aligned`` without the clip of k: the bucket difference
+    ``max_bucket - (x >> 5)`` is not provably >= 0 (relational), so the
+    128 >> k shift is no longer proven legal."""
     from repro.core.quant import SOFTMAX_SHIFT
     from repro.kernels.common import MASK_K, NEG_BUCKET
 
-    def da_noclamp(b_ref, sigma_ref, logits_i32, valid, at=...):
-        xb = jax.lax.shift_right_arithmetic(logits_i32,
-                                            jnp.int32(SOFTMAX_SHIFT))
-        new = jnp.maximum(b_ref[at], jnp.max(
-            jnp.where(valid, xb, NEG_BUCKET), axis=1, keepdims=True))
-        delta = jnp.clip(new - b_ref[at], 0, MASK_K)
-        k = jnp.where(valid, new - xb, MASK_K)  # clip(k, 0, 31) dropped
-        u = jax.lax.shift_right_logical(jnp.int32(128), k)
-        sigma_ref[at] = jax.lax.shift_right_logical(
-            sigma_ref[at], delta) + 2 * jnp.sum(u, axis=1, keepdims=True)
-        b_ref[at] = new
-        return u, delta
+    xb = jax.lax.shift_right_arithmetic(logits_i32, jnp.int32(SOFTMAX_SHIFT))
+    new = jnp.maximum(b_ref[at], jnp.max(
+        jnp.where(valid, xb, NEG_BUCKET), axis=1, keepdims=True))
+    delta = jnp.clip(new - b_ref[at], 0, MASK_K)
+    k = jnp.where(valid, new - xb, MASK_K)      # clip(k, 0, 31) dropped
+    u = jax.lax.shift_right_logical(jnp.int32(128), k)
+    sigma_ref[at] = jax.lax.shift_right_logical(
+        sigma_ref[at], delta) + 2 * jnp.sum(u, axis=1, keepdims=True)
+    b_ref[at] = new
+    return u, delta
 
-    monkeypatch.setattr(K, "da_update_aligned", da_noclamp)
+
+def test_mutant_dropped_shift_clamp_is_flagged(monkeypatch):
+    """Remove the clip of k from the fused kernels' DA update: the
+    shift is no longer proven legal."""
+    import repro.kernels.ita_attention.kernel as K
+
+    monkeypatch.setattr(K, "da_update_aligned", _da_noclamp)
     r = run_case(_case("ita_onepass_pallas/prefill-paper"))
+    assert not r["ok"]
+    assert "shift_range" in {f["kind"] for f in r["findings"]}
+
+
+@pytest.mark.parametrize("name", ["ita_onepass_pallas/serve-ragged-paged",
+                                  "ita_decode_pallas/paged-adaptive"])
+def test_paged_kernels_certify_through_their_head_loop(name, monkeypatch):
+    """The paged kernels' shared body (one grid step per sequence, its
+    query heads streamed in a loop) is certified in range, and the
+    verifier reads into that loop: without the DA update's clip of k the
+    same case fails on the shift."""
+    import repro.kernels.ita_attention.kernel as K
+
+    r = run_case(_case(name))
+    assert r["ok"], r.get("findings", r.get("error"))
+    assert r["out"] == [[-128, 127]] and r["n_unproven"] == 0
+    monkeypatch.setattr(K, "da_update_aligned", _da_noclamp)
+    jax.clear_caches()                  # the dispatch's jit traced it above
+    r = run_case(_case(name))
+    monkeypatch.undo()
+    jax.clear_caches()                  # and leaves no mutant behind
     assert not r["ok"]
     assert "shift_range" in {f["kind"] for f in r["findings"]}
 

@@ -54,6 +54,8 @@ def no_persistent_cache():
     pytest.param("onepass", 32, 32, 96, CHUNK, True,
                  id="ragged-onepass-paged-phi3"),
     pytest.param("decode", 28, 4, 128, 1, False, id="decode-paged-gqa-hd128"),
+    pytest.param("onepass", 28, 4, 128, CHUNK, True,
+                 id="ragged-onepass-paged-gqa-hd128"),
 ])
 def test_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind,
                                        hq, hkv, d, sq, ragged):

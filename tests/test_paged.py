@@ -1159,8 +1159,8 @@ def test_paged_kernels_equal_aligned_oneshot_over_many_tiles(order):
 
 
 def test_live_page_table_stops_at_each_sequences_last_live_page():
-    """The decode-paged kernel's index maps read a table cut at each
-    sequence's last live page (its heads' longest ``kv_len``): entries up
+    """Both paged kernels' (decode and onepass) index maps read a table
+    cut at each sequence's last live page (its heads' longest ``kv_len``): entries up
     to that page are the sequence's own, later ones repeat it (a repeated
     block costs no DMA), and an empty row keeps its first entry."""
     from repro.kernels.ita_attention.kernel import _live_page_table
@@ -1216,6 +1216,62 @@ def test_decode_paged_heads_of_one_sequence_at_their_own_lengths(hq, g):
         q_offset=jnp.asarray(np.maximum(rows - 1, 0)))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     assert not np.asarray(out)[rows == 0].any()
+
+
+@pytest.mark.parametrize("hq,g,window", [(4, 4, 0), (4, 2, 0), (4, 1, 0),
+                                         (4, 2, 24)],
+                         ids=["mha", "gqa", "mqa", "gqa-window"])
+def test_onepass_paged_heads_of_one_sequence_at_their_own_lengths(hq, g,
+                                                                  window):
+    """One grid step per (sequence, q tile, page) serves every head of a
+    sequence: a chunk row, a decode row and a dead row (q_len 16, 1 and
+    0) in one call, over two q tiles, sequences and heads at their own
+    lengths (one head empty), and the table's entries past the live
+    pages pointing at a page of noise, each equal the one-shot aligned
+    arithmetic, int8 and f32 outputs alike; pad and dead rows emit 0."""
+    from repro.core.quant import EPS_MAX
+    from repro.kernels.ita_attention.kernel import ita_attention_onepass_paged
+    from repro.kernels.ita_attention.ref import ita_attention_fused_ref
+
+    b, d, page, npps, chunk = 3, 16, 16, 8, 16
+    cap = page * npps
+    k_log, v_log = _i8(b, cap, g, d), _i8(b, cap, g, d)
+    k_pool, v_pool, pt = _paged_from_logical(k_log, v_log, page)
+    noise = k_pool.shape[0]
+    k_pool = np.concatenate([k_pool, _i8(1, g, page, d)])
+    v_pool = np.concatenate([v_pool, _i8(1, g, page, d)])
+    kv_rows = np.array([[100, 100, 90, 64], [37, 37, 5, 0],
+                        [50, 50, 50, 50]], np.int32)
+    q_lens = np.repeat(np.array([chunk, 1, 0], np.int32), hq)
+    last = (kv_rows.max(1) + page - 1) // page
+    for bb in range(b):
+        pt[bb, last[bb]:] = noise
+    q = _i8(b * hq, chunk, d)
+    lmult = np.float32(S_Q * S_Q / (np.sqrt(d) * EPS_MAX))
+    omult = np.float32(S_Q / S_OUT)
+    rows = kv_rows.reshape(-1)
+    offs = np.maximum(rows - q_lens, 0)
+    kr = np.repeat(k_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    vr = np.repeat(v_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    for float_out in (False, True):
+        out = np.asarray(ita_attention_onepass_paged(
+            jnp.asarray(q), jnp.asarray(k_pool)[None],
+            jnp.asarray(v_pool)[None], jnp.asarray(pt), lmult, omult,
+            jnp.asarray(rows), q_offset=jnp.asarray(offs),
+            q_len=jnp.asarray(q_lens), causal=True, window=window,
+            block_q=8, kv_rep=hq // g, hq=hq, float_out=float_out))
+        ref = np.asarray(ita_attention_fused_ref(
+            jnp.asarray(q), jnp.asarray(kr.reshape(b * hq, cap, d)),
+            jnp.asarray(vr.reshape(b * hq, cap, d)), lmult, omult,
+            jnp.asarray(rows), causal=True, window=window,
+            q_offset=jnp.asarray(offs), q_len=jnp.asarray(q_lens),
+            float_out=float_out))
+        assert out.dtype == (np.float32 if float_out else np.int8)
+        np.testing.assert_array_equal(out, ref, err_msg=f"{float_out=}")
+        assert out[:hq].any() and out[hq:2 * hq, :1].any()
+        assert not out[hq:2 * hq, 1:].any()      # the decode row's pad rows
+        assert not out[2 * hq:].any()            # the dead row
+        assert not out[rows == 0].any()          # the empty head
 
 
 def test_serve_over_many_small_pages_matches_solo_generate():
