@@ -1,6 +1,6 @@
 """Continuous-batching serving benchmark: sustained tok/s, request
-latency and TTFT over an arrival trace — chunked-prefill admission vs
-stop-the-world (``stall``) admission vs static ragged batching.
+latency and TTFT over an arrival trace — chunked-prefill continuous
+batching vs static ragged batching.
 
 Claims under test:
 
@@ -8,17 +8,6 @@ Claims under test:
   static batching on the same trace — a static batch decodes until its
   *longest* member finishes, while the continuous scheduler releases a
   finished sequence's pages and admits queued work between segments.
-- (ISSUE 5) **chunked** admission beats **stall** admission on sustained
-  tok/s and strictly on p95 TTFT for a straggler-heavy trace with long
-  prompts: stall admission stops every decode slot to run a padded
-  full-prompt prefill into a ring scratch and bytes-copy it into pages,
-  so decode throughput craters whenever a prompt arrives; chunked
-  admission interleaves prompt chunks with decode steps inside the fused
-  segments (page-native writes), so the decode stream never stops and
-  queue waits — the p95 TTFT driver under load — stay short. The
-  stop-the-world cost is reported directly as ``prefill_stall_frac``
-  (fraction of wall time inside the admission prefill dispatches; 0
-  under chunked admission by construction).
 - (ISSUE 6) **prefix sharing** on a shared-system-prompt trace (every
   request opens with the same full page of tokens) strictly reduces
   prefilled tokens vs the unshared path at **bit-identical** outputs:
@@ -29,8 +18,7 @@ Claims under test:
 
 Measured on the CI (CPU/interpret) configuration: indicative structure,
 not silicon numbers, but the step-count arithmetic (static: sum of
-per-batch max-gen; stall: decode frozen for every admission prefill;
-chunked: decode-maximal every step; prefix: shared pages never
+per-batch max-gen; chunked: decode-maximal every step; prefix: shared pages never
 re-prefilled) is hardware-independent.
 
 - (ISSUE 8) **overload survival**: on a trace whose arrival rate exceeds
@@ -54,13 +42,14 @@ re-prefilled) is hardware-independent.
 
 Writes ``BENCH_serve.json`` (env ``ITA_BENCH_OUT_SERVE`` overrides the
 path): per-mode sustained tok/s, p50/p95 request latency, p50/p95 TTFT,
-prefill-stall fraction, page-pool utilization, (v3) prefix-sharing
+page-pool utilization, (v3) prefix-sharing
 counters — ``prefix_hit_rate``, prefilled/adopted token counts,
 ``prefill_tokens_saved`` — (v4) the overload section's preemption
 count and per-class admission delays — and (v5) the recovery section:
 ``recovery_time_s``, ``replayed_tokens``, ``snapshot_bytes``, the
 journal-on/off tok/s pair and ``journal_overhead_frac``, plus the
-``cold_start_fallback`` flag from the corrupt-snapshot fixture —
+``cold_start_fallback`` flag from the corrupt-snapshot fixture — (v6:
+the stop-the-world ``stall`` mode and its fields are gone) —
 schema-checked on every run; the smoke run (CI,
 ``benchmarks/run.py --smoke``) asserts every ordering including the
 strict prefill-token reduction, the overload SLO bound, crash-recovery
@@ -79,16 +68,15 @@ from repro.runtime.generate import ServeRequest, generate, serve_continuous
 
 # Sized so a decode step's compute is non-trivial next to the per-
 # dispatch overhead of the CPU-interpret CI config: the quantities under
-# test are step counts (static strands slots; stall freezes decode per
-# admission round) and those signals need steps to cost more than the
-# host glue around them.
+# test are step counts (static strands slots) and those signals need
+# steps to cost more than the host glue around them.
 CFG = ModelConfig(
     name="bench-serve", family="dense", d_model=128, n_heads=2,
     n_kv_heads=2, head_dim=32, d_ff=256, vocab_size=64,
     layer_groups=((("attn",), 1),), dtype="float32", attention_impl="ita")
 
 SLOTS = 4
-PROMPT_PAD = 128                # the padded width every stall round pays
+PROMPT_PAD = 128                # the static baseline's padded width
 CHUNK = 48
 # page == the fused kernels' KV block (the bit-parity tile schedule), so
 # a paged decode step tile-skips to the same occupied prefix the static
@@ -108,10 +96,9 @@ SYS_LEN = PAGE                  # shared system prompt: one full page
 OVERLOAD_POOL = 8
 OVERLOAD_SLO_STEPS = 4 * SEGMENT
 
-SCHEMA_KEYS = {"schema_version", "config", "chunked", "stall", "static",
+SCHEMA_KEYS = {"schema_version", "config", "chunked", "static",
                "prefix", "prefix_off", "prefill_tokens_saved",
-               "speedup_chunked_vs_stall", "speedup_continuous_vs_static",
-               "overload", "recovery"}
+               "speedup_continuous_vs_static", "overload", "recovery"}
 MODE_KEYS = {"tok_s", "wall_s", "tokens", "requests"}
 OVERLOAD_KEYS = MODE_KEYS | {"preemptions", "slo_steps", "hi_requests",
                              "hi_p95_admit_delay_steps",
@@ -122,8 +109,7 @@ RECOVERY_KEYS = {"crashes", "recovery_time_s", "replayed_tokens",
                  "journal_off_tok_s", "journal_overhead_frac"}
 JOURNAL_OVERHEAD_MAX = 0.03     # WAL cost gate: <= 3% of journal-off tok/s
 SERVE_KEYS = MODE_KEYS | {"latency_p50_s", "latency_p95_s", "ttft_p50_s",
-                          "ttft_p95_s", "prefill_stall_frac",
-                          "page_util_peak", "page_util_mean",
+                          "ttft_p95_s", "page_util_peak", "page_util_mean",
                           "prefill_tokens", "shared_prefix_tokens",
                           "prefix_hits", "prefix_hit_rate"}
 
@@ -132,14 +118,11 @@ def make_trace(n_requests, rng):
     """Straggler-heavy, queue-pressured, mostly-short prompts with a long
     one mixed in: one long-gen straggler per SLOTS requests pins its slot
     (every static batch contains exactly one; the continuous pool always
-    has long-lived decodes for admission to stall), arrivals land 0-1
-    steps apart so requests queue behind the stragglers, and most prompts
-    are far shorter than PROMPT_PAD — the shape stop-the-world admission
-    is worst at: nearly every arriving prompt triggers its own admission
-    round, each one a full (slots x PROMPT_PAD) *padded* prefill that
-    freezes the stragglers' decode, while chunked admission prefills only
-    the actual prompt tokens, in-band, with decode never pausing. Queue
-    waits — the p95 TTFT driver — then track sustained throughput."""
+    has long-lived decodes), arrivals land 0-1 steps apart so requests
+    queue behind the stragglers, and most prompts are far shorter than
+    PROMPT_PAD: chunked admission prefills only the actual prompt tokens,
+    in-band, with decode never pausing. Queue waits — the p95 TTFT
+    driver — then track sustained throughput."""
     reqs = []
     step = 0
     for i in range(n_requests):
@@ -192,11 +175,9 @@ def make_overload_trace(n_requests, rng):
     return reqs
 
 
-def run_serve_once(params, reqs, admission, prefix_sharing=False,
-                   journal_dir=None):
+def run_serve_once(params, reqs, prefix_sharing=False, journal_dir=None):
     res = serve_continuous(params, CFG, reqs, slots=SLOTS, segment=SEGMENT,
-                           max_len=MAX_LEN, page_size=PAGE,
-                           admission=admission, chunk_size=CHUNK,
+                           max_len=MAX_LEN, page_size=PAGE, chunk_size=CHUNK,
                            prefix_sharing=prefix_sharing,
                            journal_dir=journal_dir)
     assert len(res.completed) == len(reqs), "trace not fully served"
@@ -217,7 +198,6 @@ def summarize_serve(best):
         "latency_p95_s": round(best.latency_quantile(0.95), 6),
         "ttft_p50_s": round(best.ttft_quantile(0.5), 6),
         "ttft_p95_s": round(best.ttft_quantile(0.95), 6),
-        "prefill_stall_frac": round(best.prefill_stall_frac, 4),
         "page_util_peak": round(max(util, default=0.0), 4),
         "page_util_mean": round(float(np.mean(util)) if util else 0.0, 4),
         "prefill_tokens": best.prefill_tokens,
@@ -269,12 +249,11 @@ def run_static_once(params, reqs):
 
 def _validate_schema(payload):
     assert SCHEMA_KEYS <= set(payload), set(payload)
-    assert payload["schema_version"] == 5
-    for mode in ("chunked", "stall", "prefix", "prefix_off"):
+    assert payload["schema_version"] == 6
+    for mode in ("chunked", "prefix", "prefix_off"):
         missing = SERVE_KEYS - set(payload[mode])
         assert not missing, f"{mode} missing {missing}"
         assert payload[mode]["tok_s"] > 0, payload[mode]
-    assert payload["chunked"]["prefill_stall_frac"] == 0.0
     # ISSUE 8: the overload trace preempts, bounds the high class's
     # admission delay by the SLO, and starves nobody
     over = payload["overload"]
@@ -318,18 +297,16 @@ def main():
     reqs = make_trace(20 if smoke else 36, rng)
     shared_reqs = make_shared_trace(8 if smoke else 14, rng)
 
-    # warm the compile caches (chunked + stall segments, admission
-    # dispatches, the static fused loop) so every mode times steady state
-    run_serve_once(params, reqs, "chunked")
-    run_serve_once(params, reqs, "stall")
+    # warm the compile caches (serve segments, admission dispatches, the
+    # static fused loop) so every mode times steady state
+    run_serve_once(params, reqs)
     run_static_once(params, reqs)
 
     # prefix sharing on the shared-system-prompt trace: counters and
     # tokens are deterministic for a fixed trace, so one pass per mode
     # settles the ISSUE-6 claims; tok_s still takes the interleaved best
-    pfx_on = run_serve_once(params, shared_reqs, "chunked",
-                            prefix_sharing=True)
-    pfx_off = run_serve_once(params, shared_reqs, "chunked")
+    pfx_on = run_serve_once(params, shared_reqs, prefix_sharing=True)
+    pfx_off = run_serve_once(params, shared_reqs)
     toks_on = {c.index: np.asarray(c.tokens) for c in pfx_on.completed}
     toks_off = {c.index: np.asarray(c.tokens) for c in pfx_off.completed}
     for i in toks_off:
@@ -345,10 +322,9 @@ def main():
     over = serve_continuous(
         params, CFG, over_reqs, slots=SLOTS, segment=SEGMENT,
         max_len=MAX_LEN, page_size=PAGE, num_pages=OVERLOAD_POOL,
-        admission="chunked", chunk_size=CHUNK, preemption=True,
-        debug_invariants=True)
+        chunk_size=CHUNK, preemption=True, debug_invariants=True)
     assert len(over.completed) == len(over_reqs), "overload starved"
-    calm = run_serve_once(params, over_reqs, "chunked")
+    calm = run_serve_once(params, over_reqs)
     toks_over = {c.index: np.asarray(c.tokens) for c in over.completed}
     for c in calm.completed:
         np.testing.assert_array_equal(
@@ -426,17 +402,14 @@ def main():
     # to be on the clock; step/segment/round counts and page util are
     # deterministic for a fixed trace, so mixing iterations is sound
     iters = 4
-    runs = {"chunked": [], "stall": [], "prefix": [], "prefix_off": []}
+    runs = {"chunked": [], "prefix": [], "prefix_off": []}
     best_static, static_tokens = None, 0
     for _ in range(iters):
-        for mode in ("chunked", "stall"):
-            runs[mode].append(summarize_serve(
-                run_serve_once(params, reqs, mode)))
+        runs["chunked"].append(summarize_serve(run_serve_once(params, reqs)))
         runs["prefix"].append(summarize_serve(
-            run_serve_once(params, shared_reqs, "chunked",
-                           prefix_sharing=True)))
+            run_serve_once(params, shared_reqs, prefix_sharing=True)))
         runs["prefix_off"].append(summarize_serve(
-            run_serve_once(params, shared_reqs, "chunked")))
+            run_serve_once(params, shared_reqs)))
         wall, static_tokens = run_static_once(params, reqs)
         if best_static is None or wall < best_static:
             best_static = wall
@@ -456,13 +429,13 @@ def main():
     try:
         for i in range(5 if smoke else 7):
             if i % 2 == 0:
-                off = summarize_serve(run_serve_once(params, reqs, "chunked"))
-                on = summarize_serve(run_serve_once(
-                    params, reqs, "chunked", journal_dir=jdir))
+                off = summarize_serve(run_serve_once(params, reqs))
+                on = summarize_serve(run_serve_once(params, reqs,
+                                                    journal_dir=jdir))
             else:
-                on = summarize_serve(run_serve_once(
-                    params, reqs, "chunked", journal_dir=jdir))
-                off = summarize_serve(run_serve_once(params, reqs, "chunked"))
+                on = summarize_serve(run_serve_once(params, reqs,
+                                                    journal_dir=jdir))
+                off = summarize_serve(run_serve_once(params, reqs))
             j_pairs.append((off["tok_s"], on["tok_s"]))
             if journaled is None or on["tok_s"] > journaled["tok_s"]:
                 journaled = on
@@ -474,13 +447,12 @@ def main():
     def best_of(summaries):
         out = dict(summaries[0])
         for key in ("wall_s", "latency_p50_s", "latency_p95_s",
-                    "ttft_p50_s", "ttft_p95_s", "prefill_stall_frac"):
+                    "ttft_p50_s", "ttft_p95_s"):
             out[key] = min(r[key] for r in summaries)
         out["tok_s"] = max(r["tok_s"] for r in summaries)
         return out
 
     chunked = best_of(runs["chunked"])
-    stall = best_of(runs["stall"])
     prefix = best_of(runs["prefix"])
     prefix_off = best_of(runs["prefix_off"])
     tokens_saved = prefix_off["prefill_tokens"] - prefix["prefill_tokens"]
@@ -502,17 +474,12 @@ def main():
         "tokens": static_tokens,
         "requests": len(reqs),
     }
-    vs_stall = chunked["tok_s"] / max(stall["tok_s"], 1e-9)
     vs_static = chunked["tok_s"] / max(stat["tok_s"], 1e-9)
 
     print(f"serve/chunked_tok_s,0,{chunked['tok_s']:.6g}")
-    print(f"serve/stall_tok_s,0,{stall['tok_s']:.6g}")
     print(f"serve/static_tok_s,0,{stat['tok_s']:.6g}")
-    print(f"serve/chunked_vs_stall,0,{vs_stall:.6g}")
     print(f"serve/continuous_vs_static,0,{vs_static:.6g}")
     print(f"serve/chunked_ttft_p95_ms,0,{chunked['ttft_p95_s'] * 1e3:.6g}")
-    print(f"serve/stall_ttft_p95_ms,0,{stall['ttft_p95_s'] * 1e3:.6g}")
-    print(f"serve/stall_prefill_frac,0,{stall['prefill_stall_frac']:.6g}")
     print(f"serve/latency_p95_ms,0,{chunked['latency_p95_s'] * 1e3:.6g}")
     print(f"serve/page_util_peak,0,{chunked['page_util_peak']:.6g}")
     print(f"serve/prefix_hit_rate,0,{prefix['prefix_hit_rate']:.6g}")
@@ -542,14 +509,6 @@ def main():
     assert vs_static > 1.0, (
         f"continuous batching ({chunked['tok_s']} tok/s) did not beat "
         f"static ragged batching ({stat['tok_s']} tok/s) on the trace")
-    # ISSUE 5 acceptance: chunked admission >= stall admission on
-    # sustained tok/s, strictly better p95 TTFT on the straggler trace
-    assert vs_stall >= 1.0, (
-        f"chunked admission ({chunked['tok_s']} tok/s) fell behind stall "
-        f"admission ({stall['tok_s']} tok/s)")
-    assert chunked["ttft_p95_s"] < stall["ttft_p95_s"], (
-        f"chunked admission p95 TTFT {chunked['ttft_p95_s']} s not "
-        f"better than stall {stall['ttft_p95_s']} s")
     # ISSUE 6 acceptance: sharing strictly reduces prefilled tokens on
     # the shared-system-prompt trace (outputs already asserted
     # bit-identical above) and never inflates peak pool occupancy —
@@ -585,7 +544,7 @@ def main():
         "corrupt snapshot did not fall back to cold start"
 
     payload = {
-        "schema_version": 5,
+        "schema_version": 6,
         "config": {"arch": CFG.name, "slots": SLOTS, "segment": SEGMENT,
                    "page_size": PAGE, "max_len": MAX_LEN,
                    "prompt_pad": PROMPT_PAD, "chunk_size": CHUNK,
@@ -596,14 +555,12 @@ def main():
                    "overload_pool": OVERLOAD_POOL,
                    "backend": jax.default_backend(), "smoke": smoke},
         "chunked": chunked,
-        "stall": stall,
         "static": stat,
         "prefix": prefix,
         "prefix_off": prefix_off,
         "overload": overload,
         "recovery": recovery,
         "prefill_tokens_saved": tokens_saved,
-        "speedup_chunked_vs_stall": round(vs_stall, 3),
         "speedup_continuous_vs_static": round(vs_static, 3),
     }
     out_path = os.environ.get("ITA_BENCH_OUT_SERVE", "BENCH_serve.json")

@@ -140,7 +140,7 @@ def trace(cfg, rng):
 def serve_phase(params, cfg, reqs):
     res = serve_continuous(params, cfg, reqs, slots=SLOTS, segment=SEGMENT,
                            max_len=MAX_LEN, page_size=PAGE,
-                           admission="chunked", chunk_size=CHUNK)
+                           chunk_size=CHUNK)
     check(len(res.completed) == len(reqs),
           f"{len(res.completed)}/{len(reqs)} requests completed")
     served = {c.index: np.asarray(c.tokens) for c in res.completed}

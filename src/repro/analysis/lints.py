@@ -124,8 +124,7 @@ def _run_instrumented_serve(n_requests: int):
             warnings.simplefilter("always")
             GEN.serve_continuous(params, cfg, reqs, slots=2,
                                  segment=SERVE_SEGMENT, max_len=128,
-                                 page_size=128, admission="chunked",
-                                 chunk_size=5)
+                                 page_size=128, chunk_size=5)
     finally:
         GEN._serve_segment_fn = orig
         GEN._serve_segment_fn.cache_clear()
@@ -160,7 +159,7 @@ def _run_overload_serve():
         res = GEN.serve_continuous(
             params, cfg, reqs, slots=2, segment=SERVE_SEGMENT,
             max_len=256, page_size=128, num_pages=4,
-            admission="chunked", chunk_size=64, preemption=True)
+            chunk_size=64, preemption=True)
         preempts.append(res.preemptions)
     return preempts, STEPS.preempt_rows._cache_size()
 

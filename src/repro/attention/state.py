@@ -614,10 +614,7 @@ class PagedKVState:
                       slots: jax.Array | None = None) -> "PagedKVState":
         """``prefill_write`` generalized to target batch ``slots``: row
         ``i`` of ``k_q``/``v_q`` (n, S, G, hd) lands in batch slot
-        ``slots[i]`` (negative = dummy row, dropped entirely) — the
-        admission path that prefills newly arrived requests into slots
-        another sequence just released, with a fixed-width dispatch shape
-        regardless of how many requests actually arrived."""
+        ``slots[i]`` (negative = dummy row, dropped entirely)."""
         n, s = k_q.shape[:2]
         b = self.batch
         ps = self.page_size
@@ -629,9 +626,9 @@ class PagedKVState:
             new_pos = jnp.full((n,), s, jnp.int32)
         else:
             # Ragged: only the *valid* lengths must fit the window — the
-            # source may be wider than the pool's capacity (e.g. a
-            # block-aligned admission scratch); every column beyond a
-            # row's length scatters into the parking page regardless.
+            # source may be wider than the pool's capacity; every column
+            # beyond a row's length scatters into the parking page
+            # regardless.
             # Lengths are clamped so a misdeclared over-window row can
             # never push pos past capacity (callers validate upstream).
             new_pos = jnp.minimum(jnp.asarray(lengths, jnp.int32).reshape(n),

@@ -86,25 +86,17 @@ def main():
                          "granularity) for --continuous")
     ap.add_argument("--page-size", type=int, default=128,
                     help="KV pool page size (tokens per page)")
-    ap.add_argument("--admission", default="chunked",
-                    choices=["chunked", "stall"],
-                    help="chunked = prompts prefill in chunks inside the "
-                         "fused segments, interleaved with decode (page-"
-                         "native writes, no stop-the-world); stall = "
-                         "PR-4 stop-the-world padded prefill + adopt "
-                         "(A/B reference)")
     ap.add_argument("--chunk-size", type=int, default=32,
                     help="prompt tokens prefilling per slot per step "
-                         "under --admission chunked")
+                         "for --continuous")
     ap.add_argument("--token-budget", type=int, default=None,
                     help="per-step token budget of the decode-maximal "
                          "scheduler (default slots - 1 + chunk_size)")
     ap.add_argument("--prefix-sharing", action="store_true",
                     help="share identical prompt prefixes across requests "
-                         "through the paged pool (copy-on-write, chunked "
-                         "admission only): matching page-aligned prefix "
-                         "chunks adopt existing pages instead of "
-                         "re-prefilling")
+                         "through the paged pool (copy-on-write): "
+                         "matching page-aligned prefix chunks adopt "
+                         "existing pages instead of re-prefilling")
     ap.add_argument("--system-prompt-len", type=int, default=0,
                     help="prepend a common system prefix of this many "
                          "tokens to every --continuous request (makes "
@@ -203,8 +195,7 @@ def main():
                 max_len=args.system_prompt_len + args.prompt_len + args.gen,
                 page_size=args.page_size, temperature=args.temperature,
                 key=key if args.temperature > 0 else None,
-                eos_id=args.eos_id, admission=args.admission,
-                chunk_size=args.chunk_size, token_budget=args.token_budget,
+                eos_id=args.eos_id, chunk_size=args.chunk_size, token_budget=args.token_budget,
                 prefix_sharing=args.prefix_sharing,
                 preemption=args.preemption,
                 journal_dir=args.journal_dir,
@@ -214,9 +205,7 @@ def main():
         util = max((u for _, u in res.page_util), default=0.0)
         print(f"[serve] arch={cfg.name} continuous slots={args.batch} "
               f"segment={args.segment} page_size={args.page_size} "
-              f"admission={args.admission}"
-              + (f" chunk={args.chunk_size}"
-                 if args.admission == "chunked" else ""))
+              f"chunk={args.chunk_size}")
         print(f"[serve] {len(res.completed)}/{args.requests} requests, "
               f"{res.steps} steps / {res.segments} segments / "
               f"{res.admission_rounds} admission rounds")
@@ -225,8 +214,8 @@ def main():
               f"{res.latency_quantile(0.5)*1e3:.0f} ms p95 "
               f"{res.latency_quantile(0.95)*1e3:.0f} ms; TTFT p50 "
               f"{res.ttft_quantile(0.5)*1e3:.0f} ms p95 "
-              f"{res.ttft_quantile(0.95)*1e3:.0f} ms; prefill-stall "
-              f"{res.prefill_stall_frac:.0%}; peak page util {util:.0%}")
+              f"{res.ttft_quantile(0.95)*1e3:.0f} ms; peak page util "
+              f"{util:.0%}")
         if args.prefix_sharing:
             print(f"[serve] prefix sharing: {res.prefix_hits}/"
                   f"{len(res.completed)} hits "

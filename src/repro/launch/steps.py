@@ -213,8 +213,8 @@ class ServeSlotState:
     admission dispatch updates — admission is *just* this state write
     plus the host's page reservation: prompt token ids are enqueued here
     and prefilled chunk-by-chunk inside the segments (``cursor`` <
-    ``plen`` marks the prefill phase), so there is no stop-the-world
-    prompt dispatch and no ring-scratch bytes-copy on the chunked path.
+    ``plen`` marks the prefill phase), so no prompt runs in a dispatch
+    of its own.
     A prefix-sharing admit starts ``cursor``/``pos`` at the shared token
     count instead of 0 (the leading prompt pages were adopted from the
     pool, never re-prefilled); the mixed segment body needs no change —
@@ -330,28 +330,6 @@ def admit_chunked(state, slot_ids, prompts, lengths, gens, req_keys,
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 @functools.partial(jax.named_call, name="pool")
-def admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
-                req_keys, prios=None):
-    """Stall-mode admission state write, after the stop-the-world prefill
-    sampled ``tok0``: the slot enters directly in the decode phase
-    (``cursor == plen``)."""
-    rows = admit_rows(state, slot_ids)
-    prio = jnp.zeros_like(lengths) if prios is None \
-        else jnp.asarray(prios, jnp.int32)
-    return dataclasses.replace(
-        state,
-        tok=state.tok.at[rows].set(tok0, mode="drop"),
-        pos=state.pos.at[rows].set(lengths, mode="drop"),
-        plen=state.plen.at[rows].set(lengths, mode="drop"),
-        cursor=state.cursor.at[rows].set(lengths, mode="drop"),
-        done=state.done.at[rows].set(new_done, mode="drop"),
-        rem=state.rem.at[rows].set(new_rem, mode="drop"),
-        keys=state.keys.at[rows].set(req_keys, mode="drop"),
-        prio=state.prio.at[rows].set(prio, mode="drop"))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-@functools.partial(jax.named_call, name="pool")
 def preempt_rows(state, mask):
     """One-dispatch victim release: evict every slot in ``mask`` (B,)
     bool from the batch. The victims' phase state zeroes and ``done``
@@ -385,8 +363,8 @@ def advance_step_rows(logits, keys, temperature, done, rem, n, active, *,
     ``active`` row from its own stream, pad everything else, count active
     emissions into ``n``, charge them against ``rem`` and fold budget
     exhaustion / EOS into ``done``. Both bodies calling this one function
-    keeps their emission bookkeeping structurally identical (the
-    chunked ≡ stall bit-parity guarantee), not merely test-caught.
+    keeps their emission bookkeeping structurally identical, not merely
+    test-caught.
     Returns ``(tok (B, 1), keys, done, rem, n)``."""
     nxt, keys = sample_token_rows(logits, keys, temperature, sample=sample,
                                   advance=active)

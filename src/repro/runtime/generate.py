@@ -26,10 +26,9 @@ per-sequence rings for shared paged KV pools (bit-identical tokens).
 ``serve_continuous`` is the continuous-batching server on top: a fixed-
 slot batch over the paged pool, fused ``lax.scan`` segments with host
 admission between them — finished sequences release their pages, and
-arrived prompts enter via **chunked prefill** (default): admission only
-enqueues token ids, the segments prefill them chunk-by-chunk straight
-into pool pages, interleaved with decode under a decode-maximal token
-budget. The stop-the-world PR-4 path survives as ``admission="stall"``.
+arrived prompts enter via **chunked prefill**: admission only enqueues
+token ids, the segments prefill them chunk-by-chunk straight into pool
+pages, interleaved with decode under a decode-maximal token budget.
 ``prefix_sharing=True`` adds copy-on-write KV prefix sharing: a host
 ``PrefixIndex`` maps page-aligned prompt chunks to the physical pages
 already holding their bytes, admission adopts matching pages (+1
@@ -361,8 +360,6 @@ class ServeResult:
     segments: int                    # fused segments dispatched
     admission_rounds: int            # admission dispatches
     page_util: list                  # (step, fraction of pool pages held)
-    prefill_stall_s: float = 0.0     # wall spent in stop-the-world prefill
-                                     # dispatches (0 under chunked admission)
     prefill_tokens: int = 0          # prompt tokens actually prefilled
     shared_prefix_tokens: int = 0    # prompt tokens skipped via adoption
     prefix_hits: int = 0             # admissions that adopted >= 1 page
@@ -396,10 +393,6 @@ class ServeResult:
     @property
     def tok_s(self) -> float:
         return self.total_tokens / max(self.wall_s, 1e-9)
-
-    @property
-    def prefill_stall_frac(self) -> float:
-        return self.prefill_stall_s / max(self.wall_s, 1e-9)
 
     def _quantile(self, values, q: float) -> float:
         vals = sorted(values)
@@ -471,88 +464,23 @@ def _is_kv_state(x):
     return isinstance(x, (KVCacheState, PagedKVState))
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("method",),
+                   donate_argnums=(0,))
 @functools.partial(jax.named_call, name="pool")
-def _release_slots(caches, finished):
-    """Return every finished slot's pages (all layers) to the free
-    stacks."""
-    from repro.attention import PagedKVState
-
-    def rel(node):
-        if isinstance(node, PagedKVState):
-            return jax.vmap(lambda p: p.release(finished))(node)
-        return node
-
-    return jax.tree.map(rel, caches, is_leaf=_is_kv_state)
-
-
-def _admit_chunked(state, slot_ids, prompts, lengths, gens, req_keys,
-                   shared=None, prios=None):
-    """Chunked admission state write — lives in ``launch.steps`` next to
-    ``ServeSlotState``; kept callable from here for the serve loop and
-    its tests."""
-    from repro.launch.steps import admit_chunked
-    return admit_chunked(state, slot_ids, prompts, lengths, gens, req_keys,
-                         shared, prios)
-
-
-def _preempt_rows(state, mask):
-    """One-dispatch victim eviction of every slot in ``mask`` — see
-    ``launch.steps.preempt_rows``."""
-    from repro.launch.steps import preempt_rows
-    return preempt_rows(state, mask)
-
-
-def _admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
-                 req_keys, prios=None):
-    from repro.launch.steps import admit_stall
-    return admit_stall(state, slot_ids, lengths, tok0, new_done, new_rem,
-                       req_keys, prios)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-@functools.partial(jax.named_call, name="pool")
-def _adopt_prefix_slots(caches, slot_ids, pages, n_pages, n_tokens):
-    """Point freshly admitted slots' leading page-table entries at the
-    shared prefix pages (every layer's pool — the allocators run in
-    lockstep, so one page id is valid for all of them). Rows with
-    ``slot_ids[i] < 0`` or ``n_pages[i] == 0`` are no-ops."""
+def _map_pools(caches, method, *args):
+    """Run ``PagedKVState.<method>(*args)`` on every layer of every paged
+    pool in the cache tree, in one donated dispatch (one compile per
+    method). The layers' allocators run in lockstep, so one page id or
+    slot index means the same thing in all of them. The serve loop uses
+    ``release`` (finished slots' pages back to the free stacks),
+    ``adopt_prefix`` (new slots' leading table entries onto shared prefix
+    pages, +1 refcount) and ``incref_pages`` / ``decref_pages`` (the
+    prefix index's pins; flat page ids, -1 padded)."""
     from repro.attention import PagedKVState
 
     def one(node):
         if isinstance(node, PagedKVState):
-            return jax.vmap(lambda p: p.adopt_prefix(slot_ids, pages,
-                                                     n_pages, n_tokens))(node)
-        return node
-
-    return jax.tree.map(one, caches, is_leaf=_is_kv_state)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-@functools.partial(jax.named_call, name="pool")
-def _pin_pages(caches, pages):
-    """+1 refcount on ``pages`` (flat, -1 padded) in every layer's pool —
-    the prefix index's registration pin."""
-    from repro.attention import PagedKVState
-
-    def one(node):
-        if isinstance(node, PagedKVState):
-            return jax.vmap(lambda p: p.incref_pages(pages))(node)
-        return node
-
-    return jax.tree.map(one, caches, is_leaf=_is_kv_state)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-@functools.partial(jax.named_call, name="pool")
-def _unpin_pages(caches, pages):
-    """Drop the index pin on ``pages`` (flat, -1 padded); pages reaching
-    refcount zero return to every layer's free stack."""
-    from repro.attention import PagedKVState
-
-    def one(node):
-        if isinstance(node, PagedKVState):
-            return jax.vmap(lambda p: p.decref_pages(pages))(node)
+            return jax.vmap(lambda p: getattr(p, method)(*args))(node)
         return node
 
     return jax.tree.map(one, caches, is_leaf=_is_kv_state)
@@ -579,27 +507,7 @@ def _check_paged_invariants(caches, pins=None):
             layer.check_invariants(pins=pins)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _adopt_prompts(pool, temp, slot_ids, lengths):
-    """Copy freshly prefilled (ring) K/V bytes into pool pages at the
-    assigned slots — the admission hand-off. ``slot_ids`` (n,) int32,
-    negative entries are padding rows of the fixed-width admission batch
-    and are dropped. The ring holds exactly the quantized bytes decode
-    will read, so adopted pages are bit-identical to having prefilled
-    into the pool directly."""
-    from repro.attention import PagedKVState
-
-    def one(p, t):
-        if isinstance(p, PagedKVState):
-            for i in range(p.k.shape[0]):
-                p = p.put_layer(p.at_layer(i).write_prompts(
-                    t.k[i], t.v[i], lengths=lengths, slots=slot_ids), i)
-        return p
-
-    return jax.tree.map(one, pool, temp, is_leaf=_is_kv_state)
-
-
-def _validate_serve_cfg(cfg, admission: str = "stall", chunk: int = 1):
+def _validate_serve_cfg(cfg, chunk: int):
     from repro import attention as ATT
     from repro.models.attention import make_spec
     kinds = {k for pat, _ in cfg.layer_groups for k in pat}
@@ -609,11 +517,11 @@ def _validate_serve_cfg(cfg, admission: str = "stall", chunk: int = 1):
             f"(got block kinds {sorted(kinds)})")
     if not cfg.causal:
         raise ValueError("continuous batching needs causal attention")
-    specs = [("paged decode", dict(q_len=1))]
-    if admission == "chunked":
-        # the mixed segment's ragged chunked-prefill call must be servable
-        specs.append(("ragged chunked-prefill paged decode",
-                      dict(q_len=chunk, ragged_q=True)))
+    # the decode segment's call and the mixed segment's ragged
+    # chunked-prefill call must both be servable
+    specs = [("paged decode", dict(q_len=1)),
+             ("ragged chunked-prefill paged decode",
+              dict(q_len=chunk, ragged_q=True))]
     for kind in kinds:
         window = {"attn": 0, "local": cfg.local_window,
                   "swa": cfg.window}[kind]
@@ -626,9 +534,6 @@ def _validate_serve_cfg(cfg, admission: str = "stall", chunk: int = 1):
                 raise ValueError(
                     f"no attention backend serves the {what} spec for "
                     f"{kind!r} blocks of {cfg.name!r} — {reasons}")
-
-
-ADMISSIONS = ("chunked", "stall")
 
 
 def _next_pow2(n: int) -> int:
@@ -663,23 +568,15 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     ``arrival`` is in steps); throughput is **sustained**: total
     generated tokens over the whole trace wall clock.
 
-    ``admission`` selects how prompts enter the batch:
-
-    - ``"chunked"`` (default) — admission only *enqueues* the prompt's
-      token ids into the slot's ``ServeSlotState`` (one tiny state
-      dispatch) and reserves pages; the prompt is then prefilled in
-      ``chunk_size``-token chunks *inside* the fused segments, written
-      page-native via ``append_chunk``, interleaved with decode steps
-      under a decode-maximal per-step ``token_budget`` (default
-      ``slots - 1 + chunk_size``: every decoding slot advances every
-      step, the leftover budget feeds prompt chunks). Decode throughput
-      never stops for a long prompt and the ring scratch + bytes-copy
-      adoption of the stall path never runs.
-    - ``"stall"`` — the PR-4 stop-the-world path, kept for A/B parity:
-      admission runs one fixed-shape ragged prefill over a ring scratch,
-      bytes-copies the K/V into pool pages (``_adopt_prompts``), and all
-      decode slots wait. Its stop time is reported as
-      ``ServeResult.prefill_stall_s``.
+    Admission only *enqueues* a prompt's token ids into the slot's
+    ``ServeSlotState`` (one tiny state dispatch) and reserves pages; the
+    prompt is then prefilled in ``chunk_size``-token chunks *inside* the
+    fused segments, written page-native via ``append_chunk``, interleaved
+    with decode steps under a decode-maximal per-step ``token_budget``
+    (default ``slots - 1 + chunk_size``: every decoding slot advances
+    every step, the leftover budget feeds prompt chunks), so decode
+    throughput never stops for a long prompt. ``admission`` accepts only
+    ``"chunked"``: stop-the-world admission was removed.
 
     Admission reserves each request's worst-case page need
     (``ceil((len + gen) / page_size)``, capped at the per-slot window) up
@@ -688,7 +585,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     (testing hook) is called after every admission round with the live
     cache pytree, the slot→request map and the host pin ledger.
 
-    ``prefix_sharing=True`` (chunked admission only) shares identical
+    ``prefix_sharing=True`` shares identical
     prompt prefixes across requests through the paged pool: as prompts
     prefill, every *full* page of prompt tokens is registered in a host
     ``PrefixIndex`` (chain hash of page-aligned token chunks → physical
@@ -706,10 +603,9 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     protected) before stalling the head of the queue.
 
     **Overload survival** (DESIGN.md §Overload survival):
-    ``preemption=True`` (chunked admission only) lets admission make
-    room for a higher-priority arrival when the pool or the slots are
-    exhausted: victim slots — strictly lower ``ServeRequest.priority``
-    only, lowest class first, then most reserved pages — are evicted in
+    ``preemption=True`` lets admission make room for a higher-priority
+    arrival when the pool or the slots are exhausted: victim slots —
+    strictly lower ``ServeRequest.priority`` only, lowest class first, then most reserved pages — are evicted in
     one ``preempt_rows`` dispatch, their pages return to the pool
     (pinned prefix pages decref, never free), and their requests
     re-enqueue carrying the prompt *plus every token generated so far*.
@@ -765,10 +661,10 @@ def serve_continuous(params, cfg, requests, *, slots: int,
 
     Requests decode greedily (or with temperature sampling when ``key``
     is given) until ``gen`` tokens or ``eos_id``. Greedy serving is
-    bit-identical to generating each request alone under **both**
-    admission modes (chunked-prefill bit-exactness needs the solo prefill
-    on the same KV tile schedule: ``page_size`` equal to the fused
-    prefill ``block_kv``, 128, and a fused-kernel prefill backend).
+    bit-identical to generating each request alone (chunked-prefill
+    bit-exactness needs the solo prefill on the same KV tile schedule:
+    ``page_size`` equal to the fused prefill ``block_kv``, 128, and a
+    fused-kernel prefill backend).
     Sampled serving draws each request's tokens from its own PRNG stream
     (``fold_in(key, request_index)``), so outputs are independent of
     admission interleaving and co-scheduled traffic.
@@ -810,19 +706,15 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     spans are events of the ``/host:`` planes, their args the events'
     stats; the ops are the ``XLA Ops`` line of each device plane.
     """
-    from repro.launch.steps import ServeSlotState, aged_priority, \
-        fold_keys, sample_token_rows
+    from repro.launch.steps import ServeSlotState, admit_chunked, \
+        aged_priority, fold_keys, preempt_rows
     from repro.models import init_caches
 
-    if admission not in ADMISSIONS:
-        raise ValueError(f"admission={admission!r} not in {ADMISSIONS}")
-    if (preemption or faults is not None) and admission != "chunked":
+    if admission != "chunked":
         raise ValueError(
-            "preemption / fault injection require admission='chunked' "
-            "(victims resume through chunked re-prefill of their "
-            "prompt + generated prefix)")
-    _validate_serve_cfg(cfg, admission=admission,
-                        chunk=max(1, chunk_size))
+            f"admission={admission!r}: only 'chunked' is served — "
+            f"stop-the-world admission was removed")
+    _validate_serve_cfg(cfg, chunk=max(1, chunk_size))
     requests = list(requests)
     if not requests:
         return ServeResult([], 0.0, 0, 0, 0, [])
@@ -983,10 +875,6 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     index = None
     if prefix_sharing:
         from repro.attention import PagedKVState, PrefixIndex
-        if admission != "chunked":
-            raise ValueError(
-                "prefix_sharing requires admission='chunked' (stall-mode "
-                "prefill bypasses the page-native write path)")
         geos = {(n.k.shape[1], n.k.shape[3], n.page_table.shape[2])
                 for n in jax.tree.leaves(caches, is_leaf=_is_kv_state)
                 if isinstance(n, PagedKVState)}
@@ -1003,12 +891,11 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     chunk = max(1, min(chunk_size, capacity))
     budget = token_budget if token_budget is not None \
         else slots - 1 + chunk
-    if admission == "chunked" and budget < slots:
+    if budget < slots:
         raise ValueError(
             f"token_budget={budget} < slots={slots}: a decode-maximal "
             f"step must cover every decoding slot plus at least one "
             f"prefill token")
-    prefill, _ = _steps(cfg)
     seg_decode = _serve_segment_fn(cfg, segment, sample, eos_id, pad_id)
 
     def seg_mixed(k):
@@ -1033,11 +920,6 @@ def serve_continuous(params, cfg, requests, *, slots: int,
             raise ValueError(
                 f"request {idx} needs {pages_for(r)} pages but the pool "
                 f"has {pool_pages}; raise num_pages")
-
-    # stall mode: reusable ring scratch for admission prefills (fully
-    # overwritten by every ragged prefill — allocated once, not per round)
-    scratch = init_caches(cfg, slots, max_len=prompt_pad) \
-        if admission == "stall" else None
 
     # scheduler state (host)
     order = sorted(range(len(requests)), key=lambda i: requests[i].arrival)
@@ -1108,7 +990,8 @@ def serve_continuous(params, cfg, requests, *, slots: int,
             # none of those rows survive the crash, so release every row
             # — refcounts drop to exactly the index pins — then host-
             # check the allocator invariants before trusting any of it
-            loaded = _release_slots(loaded, jnp.ones((slots,), bool))
+            loaded = _map_pools(loaded, "release",
+                                jnp.ones((slots,), bool))
             _check_paged_invariants(loaded, pins=dict(new_pins))
             caches = loaded
             pins = new_pins
@@ -1144,7 +1027,6 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     step = 0
     segments = 0
     rounds = 0
-    stall_s = 0.0
     straggler_segs = 0
     t0 = time.perf_counter()
 
@@ -1423,7 +1305,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                     # the next segment, so the scan never touches freed pages
                     vmask = np.zeros((slots,), bool)
                     vmask[victims_round] = True
-                    state = _preempt_rows(state, jnp.asarray(vmask))
+                    state = preempt_rows(state, jnp.asarray(vmask))
                 if adm and to_release:
                     # deferred page hand-back: freed slots accumulate across
                     # segment boundaries and release in one dispatch right
@@ -1431,7 +1313,8 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                     # accounting keeps the budget exact in between)
                     mask = np.zeros((slots,), bool)
                     mask[to_release] = True
-                    caches = _release_slots(caches, jnp.asarray(mask))
+                    caches = _map_pools(caches, "release",
+                                        jnp.asarray(mask))
                     to_release = []
                 if evict_batch:
                     # unpin evicted index entries (dispatched even when the
@@ -1440,7 +1323,8 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                     # zero are free the moment this lands
                     pad = np.full((slots * pages_per_seq,), -1, np.int32)
                     pad[:len(evict_batch)] = evict_batch
-                    caches = _unpin_pages(caches, jnp.asarray(pad))
+                    caches = _map_pools(caches, "decref_pages",
+                                        jnp.asarray(pad))
                 if adm:
                     rounds += 1
                     prompts = np.zeros((slots, prompt_pad), np.int32)
@@ -1468,83 +1352,41 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                             if i in resume_keys:
                                 rk[row] = resume_keys.pop(i)
                         req_keys = jnp.asarray(rk)
-                    lengths_d = jnp.asarray(lengths)
                     slot_ids_d = jnp.asarray(slot_ids)
-                    if admission == "chunked":
-                        shared_rows = np.zeros((slots,), np.int32)
-                        if index is not None:
-                            adopt_pages = np.zeros((slots, pages_per_seq),
-                                                   np.int32)
-                            adopt_n = np.zeros((slots,), np.int32)
-                            for row, (slot, i) in enumerate(adm):
-                                sh = adm_shared.get(slot, [])
-                                adopt_pages[row, :len(sh)] = sh
-                                adopt_n[row] = len(sh)
-                                shared_rows[row] = len(sh) * page_size
-                            if adopt_n.any():
-                                # point the new slots' leading table entries at
-                                # the shared pages (+1 refcount, every layer)
-                                caches = _adopt_prefix_slots(
-                                    caches, slot_ids_d,
-                                    jnp.asarray(adopt_pages),
-                                    jnp.asarray(adopt_n),
-                                    jnp.asarray(shared_rows))
-                        # enqueue-only admission: prompt ids + phase state; the
-                        # segments do the prefill, page-native, starting at the
-                        # first unshared token
-                        state = _admit_chunked(state, slot_ids_d,
-                                               jnp.asarray(prompts), lengths_d,
-                                               jnp.asarray(gens), req_keys,
-                                               jnp.asarray(shared_rows),
-                                               jnp.asarray(prios))
+                    shared_rows = np.zeros((slots,), np.int32)
+                    if index is not None:
+                        adopt_pages = np.zeros((slots, pages_per_seq),
+                                               np.int32)
+                        adopt_n = np.zeros((slots,), np.int32)
                         for row, (slot, i) in enumerate(adm):
-                            prefilling[slot] = True
-                            cursor_host[slot] = int(shared_rows[row])
-                    else:
-                        # stall admission: stop-the-world ragged prefill over
-                        # the ring scratch, bytes-copied into pool pages (no
-                        # sharing: every prompt token forwards)
-                        t_stall = time.perf_counter()
-                        logits, scratch = prefill(params, jnp.asarray(prompts),
-                                                  scratch, None, lengths_d)
-                        tok0, req_keys = sample_token_rows(
-                            logits, req_keys, temp_arr, sample=sample)
-                        caches = _adopt_prompts(caches, scratch, slot_ids_d,
-                                                lengths_d)
-                        tok0_np = np.asarray(tok0)
-                        new_done = np.zeros((slots,), bool)
-                        new_rem = np.zeros((slots,), np.int32)
-                        now_s = time.perf_counter() - t0
-                        for row, (slot, i) in enumerate(adm):
-                            t0_tok = int(tok0_np[row, 0])
-                            emitted[i].append(t0_tok)
-                            first_tok.setdefault(i, now_s)
-                            new_rem[row] = requests[i].gen - 1
-                            new_done[row] = (requests[i].gen <= 1
-                                             or (eos_id is not None
-                                                 and t0_tok == eos_id))
-                        state = _admit_stall(
-                            state, slot_ids_d, lengths_d, tok0,
-                            jnp.asarray(new_done), jnp.asarray(new_rem),
-                            req_keys, jnp.asarray(prios))
-                        jax.block_until_ready(state.tok)
-                        stall_s += time.perf_counter() - t_stall
+                            sh = adm_shared.get(slot, [])
+                            adopt_pages[row, :len(sh)] = sh
+                            adopt_n[row] = len(sh)
+                            shared_rows[row] = len(sh) * page_size
+                        if adopt_n.any():
+                            # point the new slots' leading table entries at
+                            # the shared pages (+1 refcount, every layer)
+                            caches = _map_pools(
+                                caches, "adopt_prefix", slot_ids_d,
+                                jnp.asarray(adopt_pages),
+                                jnp.asarray(adopt_n),
+                                jnp.asarray(shared_rows))
+                    # enqueue-only admission: prompt ids + phase state; the
+                    # segments do the prefill, page-native, starting at the
+                    # first unshared token
+                    state = admit_chunked(state, slot_ids_d,
+                                          jnp.asarray(prompts),
+                                          jnp.asarray(lengths),
+                                          jnp.asarray(gens), req_keys,
+                                          jnp.asarray(shared_rows),
+                                          jnp.asarray(prios))
+                    for row, (slot, i) in enumerate(adm):
+                        prefilling[slot] = True
+                        cursor_host[slot] = int(shared_rows[row])
                     if audit is not None:
                         audit(caches, list(slot_req), dict(pins))
                     if debug:
                         _check_paged_invariants(caches, pins=dict(pins))
-                if admission == "stall" and adm:
-                    # freshly admitted gen-1/EOS requests finish without
-                    # decoding
-                    just_done = np.asarray(state.done)
-                    fin = [s for s in range(slots)
-                           if slot_req[s] is not None and just_done[s]]
-                    if fin:
-                        now_s = time.perf_counter() - t0
-                        for s in fin:
-                            finish(s, now_s)
-                        to_release.extend(fin)
-                        continue
             if all(s is None for s in slot_req):
                 if not queue:
                     break
@@ -1559,7 +1401,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                 pause = injector.straggle(step)
                 if pause > 0.0:
                     time.sleep(pause)                  # injected straggler
-            if admission == "chunked" and any(prefilling):
+            if any(prefilling):
                 # steps of mixed phase: bounded below by the largest single
                 # prompt (one chunk per slot per step) and by total prefill
                 # work over the per-step prefill token capacity (budget minus
@@ -1660,7 +1502,8 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                             pad = np.full((slots * pages_per_seq,), -1,
                                           np.int32)
                             pad[:len(new_pins)] = new_pins
-                            caches = _pin_pages(caches, jnp.asarray(pad))
+                            caches = _map_pools(caches, "incref_pages",
+                                                jnp.asarray(pad))
             fin = [s for s in range(slots)
                    if slot_req[s] is not None and done_np[s]]
             for s in fin:
@@ -1693,8 +1536,7 @@ def serve_continuous(params, cfg, requests, *, slots: int,
     wall = time.perf_counter() - t0
     return ServeResult(completed=completed, wall_s=wall, steps=step,
                        segments=segments, admission_rounds=rounds,
-                       page_util=page_util, prefill_stall_s=stall_s,
-                       prefill_tokens=prefill_tokens,
+                       page_util=page_util, prefill_tokens=prefill_tokens,
                        shared_prefix_tokens=shared_tokens,
                        prefix_hits=prefix_hits, preemptions=n_preempts,
                        straggler_segments=straggler_segs,

@@ -1028,8 +1028,8 @@ def test_paged_kernels_read_stacked_pool_at_layer(ragged):
         ATT.dispatch(q, k_stack, v_stack, **kw)
 
 
-@pytest.mark.parametrize("admission", ["chunked", "stall"])
-def test_serve_on_lane_padded_pool_matches_solo_generate(admission):
+@pytest.mark.parametrize("chunk_size", [16, 64])   # 64: a whole prompt
+def test_serve_on_lane_padded_pool_matches_solo_generate(chunk_size):
     """Served through the in-place pool writes, at a head_dim the pool
     pads to whole lanes (24 of 128), every request's greedy tokens equal
     generating it alone: the writes and the layer-indexed kernels change
@@ -1050,8 +1050,8 @@ def test_serve_on_lane_padded_pool_matches_solo_generate(admission):
         gen=int(g), arrival=int(a))
         for n, g, a in ((9, 6, 0), (40, 3, 0), (3, 8, 1), (21, 5, 2))]
     res = serve_continuous(params, cfg, reqs, slots=2, segment=4,
-                           max_len=128, page_size=128, admission=admission,
-                           chunk_size=16)
+                           max_len=128, page_size=128,
+                           chunk_size=chunk_size)
     assert len(res.completed) == len(reqs)
     for c in res.completed:
         r = reqs[c.index]

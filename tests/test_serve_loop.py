@@ -1,13 +1,12 @@
 """Continuous-batching serve loop: correctness vs solo generation,
 scheduler safety properties, and the paged generate() path.
 
-Acceptance properties (ISSUE 4 + ISSUE 5):
+Acceptance properties:
 - every request served through the continuous loop gets **bit-identical**
-  tokens to generating it alone, under BOTH admission modes — the
-  chunked-prefill default (prompts prefilled in chunks inside the fused
-  segments, page-native) and the stop-the-world ``admission="stall"``
-  reference (slot reuse, page realloc, chunking and admission order
-  change nothing about a sequence's arithmetic);
+  tokens to generating it alone: prompts prefill in chunks inside the
+  fused segments, page-native, and slot reuse, page realloc, chunk size
+  (down to a whole prompt in one chunk, and chunks that straddle pages)
+  and admission order change nothing about a sequence's arithmetic;
 - the decode-maximal mixed scheduler never exceeds its per-step token
   budget and never starves a prefilling slot (seeded property test on
   the segment's ``grants`` output);
@@ -20,11 +19,10 @@ Acceptance properties (ISSUE 4 + ISSUE 5):
 - reused ``caches=`` of the wrong paged geometry fail validation with
   the mismatched field named.
 
-Bit-parity across chunked ≡ stall ≡ solo requires the three paths to
-stream the same KV tile schedule: ``page_size`` equal to the fused
-prefill ``block_kv`` (128) and the solo/stall prefill pinned to the
-fused one-pass kernel (``attention_backend``) rather than the streaming
-XLA family.
+Bit-parity of served ≡ solo requires both paths to stream the same KV
+tile schedule: ``page_size`` equal to the fused prefill ``block_kv``
+(128) and the solo prefill pinned to the fused one-pass kernel
+(``attention_backend``) rather than the streaming XLA family.
 """
 
 import dataclasses
@@ -77,20 +75,16 @@ def _trace(n, prng, max_prompt=12, max_gen=9, spread=3):
 # Correctness: continuous serving == solo generation, token for token
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("admission", ["chunked", "stall"])
-def test_serve_continuous_matches_solo_generate(admission):
+@pytest.mark.parametrize("chunk_size", [5, 16])   # 16: a whole prompt
+def test_serve_continuous_matches_solo_generate(chunk_size):
     params = _params()
     prng = np.random.default_rng(3)
     reqs = _trace(7, prng)
     res = serve_continuous(params, CFG, reqs, slots=3, segment=4,
                            max_len=MAX_LEN, page_size=128,
-                           admission=admission, chunk_size=5)
+                           chunk_size=chunk_size)
     assert len(res.completed) == len(reqs)
     assert res.steps > 0 and res.total_tokens == sum(r.gen for r in reqs)
-    if admission == "chunked":
-        assert res.prefill_stall_s == 0.0   # no stop-the-world dispatch
-    else:
-        assert res.prefill_stall_s > 0.0
     for c in res.completed:
         r = reqs[c.index]
         assert c.first_token_s >= c.arrived_s
@@ -98,8 +92,8 @@ def test_serve_continuous_matches_solo_generate(admission):
                         max_len=MAX_LEN)
         np.testing.assert_array_equal(
             np.asarray(c.tokens), np.asarray(solo.tokens)[0],
-            err_msg=f"request {c.index} (gen={r.gen}, {admission}) "
-                    f"diverged from solo generation")
+            err_msg=f"request {c.index} (gen={r.gen}, chunk "
+                    f"{chunk_size}) diverged from solo generation")
 
 
 @pytest.mark.parametrize("cfg,prompt_lens,gen,chunk", [
@@ -108,33 +102,25 @@ def test_serve_continuous_matches_solo_generate(admission):
                                         # straddle the 128-token page
                                         # boundary
 ])
-def test_chunked_equals_stall_equals_solo_across_specs(cfg, prompt_lens,
-                                                       gen, chunk):
-    """The ISSUE-5 parity sweep: chunked ≡ stall ≡ solo `generate()` for
-    causal / sliding-window / GQA paged specs, including prompt chunks
-    that straddle page boundaries and window masks that cut keys."""
+def test_chunked_equals_solo_across_specs(cfg, prompt_lens, gen, chunk):
+    """The parity sweep: chunked serving ≡ solo `generate()` for causal /
+    sliding-window / GQA paged specs, including prompt chunks that
+    straddle page boundaries and window masks that cut keys."""
     params = _params(cfg)
     prng = np.random.default_rng(11)
     max_len = 256
     reqs = [ServeRequest(
         prompt=prng.integers(0, cfg.vocab_size, n).astype(np.int32),
         gen=gen, arrival=2 * i) for i, n in enumerate(prompt_lens)]
-    outs = {}
-    for admission in ("chunked", "stall"):
-        res = serve_continuous(params, cfg, reqs, slots=2, segment=5,
-                               max_len=max_len, page_size=128,
-                               admission=admission, chunk_size=chunk)
-        assert len(res.completed) == len(reqs)
-        outs[admission] = {c.index: np.asarray(c.tokens)
-                           for c in res.completed}
+    res = serve_continuous(params, cfg, reqs, slots=2, segment=5,
+                           max_len=max_len, page_size=128, chunk_size=chunk)
+    assert len(res.completed) == len(reqs)
+    got = {c.index: np.asarray(c.tokens) for c in res.completed}
     for i, r in enumerate(reqs):
         solo = generate(params, cfg, jnp.asarray(r.prompt)[None], r.gen,
                         max_len=max_len)
-        want = np.asarray(solo.tokens)[0]
-        np.testing.assert_array_equal(outs["chunked"][i], want,
-                                      err_msg=f"chunked req {i}")
-        np.testing.assert_array_equal(outs["stall"][i], want,
-                                      err_msg=f"stall req {i}")
+        np.testing.assert_array_equal(got[i], np.asarray(solo.tokens)[0],
+                                      err_msg=f"req {i}")
 
 
 def test_serve_continuous_eos_cuts_sequences():
@@ -166,8 +152,8 @@ def test_serve_continuous_eos_cuts_sequences():
 # Sampled serving: per-request PRNG streams (fold_in by request id)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("admission", ["chunked", "stall"])
-def test_sampled_serving_independent_of_arrival_order(admission):
+@pytest.mark.parametrize("chunk_size", [6, 16])   # 16: a whole prompt
+def test_sampled_serving_independent_of_arrival_order(chunk_size):
     """Same seed, two arrival orders -> identical per-request tokens, and
     each request's draws equal solo generation with the fold_in key."""
     params = _params()
@@ -183,8 +169,8 @@ def test_sampled_serving_independent_of_arrival_order(admission):
                              arrival=arrivals[i]) for i in range(5)]
         res = serve_continuous(params, CFG, reqs, slots=2, segment=4,
                                max_len=MAX_LEN, page_size=128,
-                               admission=admission, chunk_size=6,
-                               temperature=0.8, key=key)
+                               chunk_size=chunk_size, temperature=0.8,
+                               key=key)
         return {c.index: np.asarray(c.tokens) for c in res.completed}
 
     a = run([0, 0, 1, 5, 9])
@@ -192,7 +178,7 @@ def test_sampled_serving_independent_of_arrival_order(admission):
     for i in range(5):
         np.testing.assert_array_equal(
             a[i], b[i], err_msg=f"request {i} draws depended on arrival "
-                                f"order ({admission})")
+                                f"order (chunk {chunk_size})")
         solo = generate(params, CFG, jnp.asarray(prompts[i])[None], gens[i],
                         max_len=MAX_LEN, temperature=0.8,
                         key=jax.random.fold_in(key, i))
@@ -211,8 +197,8 @@ def test_mixed_scheduler_budget_and_progress(seed):
     budget, (2) give every decoding live slot exactly one token, (3)
     always advance at least one prefilling slot while any is live, and
     (4) never push a cursor past its prompt length."""
-    from repro.runtime.generate import _admit_chunked, _serve_segment_fn
-    from repro.launch.steps import ServeSlotState, fold_keys
+    from repro.launch.steps import ServeSlotState, admit_chunked, fold_keys
+    from repro.runtime.generate import _serve_segment_fn
 
     prng = np.random.default_rng(seed)
     slots, chunk, segment = 4, 5, 6
@@ -227,7 +213,7 @@ def test_mixed_scheduler_budget_and_progress(seed):
     caches = init_caches(CFG, slots, max_len=MAX_LEN, paged=True,
                          page_size=128)
     state = ServeSlotState.init(slots, prompt_pad, KEY)
-    state = _admit_chunked(
+    state = admit_chunked(
         state, jnp.arange(slots, dtype=jnp.int32), jnp.asarray(prompts),
         jnp.asarray(plens), jnp.asarray(gens),
         fold_keys(KEY, jnp.arange(slots)))
@@ -295,9 +281,8 @@ def _audit_partition(caches, slot_req, pins, shared=False):
     assert len(set(live)) == len(live), f"request in two slots: {slot_req}"
 
 
-@pytest.mark.parametrize("seed,admission", [(0, "chunked"), (1, "chunked"),
-                                            (2, "stall")])
-def test_scheduler_never_double_books_page_or_slot(seed, admission):
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_scheduler_never_double_books_page_or_slot(seed):
     params = _params()
     prng = np.random.default_rng(seed)
     reqs = _trace(8, prng, max_gen=7, spread=4)
@@ -311,8 +296,7 @@ def test_scheduler_never_double_books_page_or_slot(seed, admission):
     # 3 slots' worth + 1 so admission actually gates on pages
     res = serve_continuous(params, CFG, reqs, slots=3, segment=4,
                            max_len=MAX_LEN, page_size=32,
-                           num_pages=3 * 4 + 2, admission=admission,
-                           chunk_size=8, audit=audit)
+                           num_pages=3 * 4 + 2, chunk_size=8, audit=audit)
     assert audits, "audit hook never ran"
     assert len(res.completed) == len(reqs)
 
@@ -343,8 +327,7 @@ def test_prefix_sharing_bit_exact_and_saves_prefill():
         audits.append(1)
         _audit_partition(caches, slot_req, pins, shared=True)
 
-    kw = dict(slots=3, segment=4, max_len=256, page_size=128,
-              admission="chunked", chunk_size=48)
+    kw = dict(slots=3, segment=4, max_len=256, page_size=128, chunk_size=48)
     off = serve_continuous(params, CFG, reqs, **kw)
     on = serve_continuous(params, CFG, reqs, prefix_sharing=True,
                           debug_invariants=True, audit=audit, **kw)
@@ -386,8 +369,8 @@ def test_prefix_sharing_eviction_under_page_pressure():
             t += 8
     res = serve_continuous(params, CFG, reqs, slots=1, segment=4,
                            max_len=256, page_size=128, num_pages=4,
-                           admission="chunked", chunk_size=48,
-                           prefix_sharing=True, debug_invariants=True)
+                           chunk_size=48, prefix_sharing=True,
+                           debug_invariants=True)
     assert len(res.completed) == len(reqs)
     assert res.prefix_hits >= 3          # each family's second request
     for c in res.completed:
@@ -400,16 +383,10 @@ def test_prefix_sharing_eviction_under_page_pressure():
 
 
 def test_prefix_sharing_rejects_incompatible_modes():
-    """Sharing requires chunked admission (stall's scratch-ring adopt
-    bypasses the index) and uniform paged geometry across layer groups
-    (the page-id-per-layer lockstep argument breaks when a window caps
-    one group's pool)."""
-    params = _params()
+    """Sharing requires uniform paged geometry across layer groups (the
+    page-id-per-layer lockstep argument breaks when a window caps one
+    group's pool)."""
     reqs = [ServeRequest(prompt=np.zeros(4, np.int32), gen=2)]
-    with pytest.raises(ValueError, match="prefix_sharing"):
-        serve_continuous(params, CFG, reqs, slots=2, segment=4,
-                         max_len=MAX_LEN, admission="stall",
-                         prefix_sharing=True)
     mixed = dataclasses.replace(
         CFG, layer_groups=((("attn",), 1), (("swa",), 1)), window=128)
     params_mixed = _params(mixed)
@@ -420,19 +397,21 @@ def test_prefix_sharing_rejects_incompatible_modes():
                          max_len=256, prefix_sharing=True)
 
 
-def test_serve_small_pages_wide_scratch():
-    """Stall admission with page_size < the ring block: the admission
-    scratch ring is block-aligned wider than the prompt pad, and adopt
-    must bound the *lengths* against the window, not the padded scratch
-    width — long prompts spanning several small pages still serve
-    bit-exactly (vs solo paged generation on the same page size)."""
+@pytest.mark.parametrize("chunk_size", [32, 48])
+def test_serve_small_pages_chunked(chunk_size):
+    """Pages smaller than the fused prefill's KV block (64 < 128), prompts
+    spanning three of them, and chunks that straddle page boundaries (48
+    does not divide 64): every request still serves bit-exactly against
+    solo paged generation on the same page size, with the allocator
+    invariants host-checked after every admission round."""
     params = _params()
     prng = np.random.default_rng(9)
     reqs = [ServeRequest(prompt=prng.integers(0, CFG.vocab_size,
                                               130 + 8 * i).astype(np.int32),
                          gen=3, arrival=0) for i in range(3)]
     res = serve_continuous(params, CFG, reqs, slots=2, segment=4,
-                           max_len=192, page_size=64, admission="stall")
+                           max_len=192, page_size=64, chunk_size=chunk_size,
+                           debug_invariants=True)
     assert len(res.completed) == len(reqs)
     for c in res.completed:
         r = reqs[c.index]
@@ -479,6 +458,11 @@ def test_serve_rejects_unservable_requests_and_configs():
                          [ServeRequest(prompt=np.zeros(4, np.int32), gen=2)],
                          slots=2, segment=4, max_len=MAX_LEN,
                          admission="bogus")
+    with pytest.raises(ValueError, match="stop-the-world"):
+        serve_continuous(params, CFG,
+                         [ServeRequest(prompt=np.zeros(4, np.int32), gen=2)],
+                         slots=2, segment=4, max_len=MAX_LEN,
+                         admission="stall")
     softcap_cfg = dataclasses.replace(CFG, attn_softcap=30.0,
                                       attention_backend="")
     with pytest.raises(ValueError, match="paged decode"):
@@ -512,8 +496,8 @@ def _overload_trace(cfg, prng, n=6, plen_lo=110, plen_hi=141, gen_lo=10,
 
 
 OVERLOAD_KW = dict(slots=3, segment=4, max_len=256, page_size=128,
-                   num_pages=5, admission="chunked", chunk_size=48,
-                   preemption=True, debug_invariants=True)
+                   num_pages=5, chunk_size=48, preemption=True,
+                   debug_invariants=True)
 
 
 @pytest.mark.parametrize("cfg,plen_hi,gen_hi", [
@@ -638,8 +622,7 @@ def test_fault_injection_kill_mid_prompt_and_straggler():
                           pressure_pages=4, straggle_steps=(40,),
                           straggle_s=0.25)
     res = serve_continuous(params, CFG, reqs, slots=2, segment=4,
-                           max_len=256, page_size=128,
-                           admission="chunked", chunk_size=16,
+                           max_len=256, page_size=128, chunk_size=16,
                            faults=plan, debug_invariants=True)
     assert len(res.completed) == len(reqs)
     assert res.preemptions >= 1
@@ -654,23 +637,6 @@ def test_fault_injection_kill_mid_prompt_and_straggler():
         np.testing.assert_array_equal(
             np.asarray(c.tokens), np.asarray(solo.tokens)[0],
             err_msg=f"request {c.index}")
-
-
-def test_preemption_and_faults_require_chunked_admission():
-    """Victims resume through chunked re-prefill of prompt + generated
-    prefix; the stall path has no such seam."""
-    from repro.runtime.fault_tolerance import ServeFaultPlan
-
-    params = _params()
-    reqs = [ServeRequest(prompt=np.zeros(4, np.int32), gen=2)]
-    with pytest.raises(ValueError, match="chunked"):
-        serve_continuous(params, CFG, reqs, slots=2, segment=4,
-                         max_len=MAX_LEN, admission="stall",
-                         preemption=True)
-    with pytest.raises(ValueError, match="chunked"):
-        serve_continuous(params, CFG, reqs, slots=2, segment=4,
-                         max_len=MAX_LEN, admission="stall",
-                         faults=ServeFaultPlan(kill_steps=(1,)))
 
 
 # ---------------------------------------------------------------------------
