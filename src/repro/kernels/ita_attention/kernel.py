@@ -113,7 +113,11 @@ x 16 slots a layer of chunked prefill for qwen2-7b at 16k. Paged decode
 is the case of one q tile of ``sq <= 8`` rows. The page table both read
 stops at each sequence's last live page (``_live_page_table``), so pages
 past it cost neither DMA nor work, and an empty slot costs one step per
-page.
+page. A q tile wider than ``NARROW`` (8) rows takes its width from the
+sequence's live query rows in it: a mixed serve step's decoding rows (one
+query each) stream each head's first 8 rows, the decode call's tile and
+head layout, instead of the chunk-wide tile, and a sequence that carries
+no query (a finished row) does no work and reads no page.
 """
 
 from __future__ import annotations
@@ -188,10 +192,11 @@ def _emit(o_ref, y, float_out, h=0):
 
 
 def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
-                      float_out, at=..., h=0):
+                      float_out, at=..., h=0, pad=0):
     """DI and the output requant of a row's stats: ``at`` picks a head's
     rows of the scratch, ``h`` its tile of the output block (one row's
-    whole scratch and tile by default)."""
+    whole scratch and tile by default); ``pad`` zero rows follow them in
+    the tile."""
     if adaptive:
         inv, e_r = adaptive_inverse(sigma_ref[at])
     else:
@@ -201,7 +206,10 @@ def _finalize_onepass(o_ref, sigma_ref, acc_ref, omult, adaptive,
     # float output); the 2 restores the halved numerator unit (u = 128>>k
     # vs the paper's 256>>k).
     scale = 2.0 * inv.astype(jnp.float32) * pow2_neg(e_r + 8) * omult
-    _emit(o_ref, acc_ref[at].astype(jnp.float32) * scale, float_out, h)
+    y = acc_ref[at].astype(jnp.float32) * scale
+    if pad:
+        y = jnp.concatenate([y, jnp.zeros((pad, y.shape[1]), y.dtype)])
+    _emit(o_ref, y, float_out, h)
 
 
 def onepass_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
@@ -323,6 +331,11 @@ def decode_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
 #   14 9.35, 1 16.7; one step per head 28.5.
 HEAD_ROWS = 128
 
+# The decode call's query tile: a q tile whose sequence carries at most
+# this many live query rows streams each head's first NARROW rows, as
+# the decode-paged call does, instead of the whole tile.
+NARROW = 8
+
 
 def _head_group(g: int, rows: int) -> int:
     """Kv heads laid out together, of ``g``, each ``rows`` stacked rows."""
@@ -330,11 +343,10 @@ def _head_group(g: int, rows: int) -> int:
                if g % n == 0 and (n == 1 or n * rows <= HEAD_ROWS))
 
 
-def paged_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
-                       o_ref, b_ref, sigma_ref, acc_ref,
-                       *, causal: bool, window: int, adaptive: bool,
-                       hq: int, kv_rep: int, group: int, bq: int, bkv: int,
-                       q_tiles: int, float_out: bool = False):
+def paged_heads_kernel(*refs, causal: bool, window: int, adaptive: bool,
+                       hq: int, kv_rep: int, group: int, narrow_group: int,
+                       bq: int, bkv: int, q_tiles: int,
+                       float_out: bool = False):
     """The onepass dataflow with one grid step per (sequence, q tile, KV
     page): the block holds the page of every kv head and the body streams
     each of the sequence's ``hq`` query heads through it, as
@@ -350,7 +362,23 @@ def paged_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     streams fully masked rows, which change none of its (bucket, Σ,
     accumulator), so the kv heads' tiles run with no branch between them
     and ``group`` of them are laid out together for the scheduler to
-    overlap. The scratch holds a ``(kv_rep * bq, ·)`` slot per kv head."""
+    overlap. The scratch holds a ``(kv_rep * bq, ·)`` slot per kv head.
+
+    With ``narrow_group`` (a tile wider than ``NARROW`` rows) the refs
+    hold a second view of the q block, each head's first ``NARROW`` rows
+    of the tile, and the tile's width follows the sequence's live query
+    rows in it (the most of its heads'): more than ``NARROW`` streams the
+    whole tile; 1 to ``NARROW`` streams the narrow view, ``(kv_rep *
+    NARROW, d)`` stacks at the head of each kv head's slot,
+    ``narrow_group`` kv heads together (the decode call's tile and
+    layout), and pads the output tile with zero rows, which is what the
+    wide tile gives rows with no query; none does no work on any page."""
+    if narrow_group:
+        q_ref, q8_ref, *refs = refs
+    else:
+        q_ref, *refs = refs
+    (k_ref, v_ref, lmult_ref, omult_ref, meta_ref, o_ref, b_ref, sigma_ref,
+     acc_ref) = refs
     s, j = pl.program_id(0), pl.program_id(2)
     i = pl.program_id(1) if q_tiles > 1 else 0
     rows = s * hq
@@ -360,41 +388,63 @@ def paged_heads_kernel(q_ref, k_ref, v_ref, lmult_ref, omult_ref, meta_ref,
     def _init():
         _init_stream(b_ref, sigma_ref, acc_ref)
 
-    seq_len = jax.lax.fori_loop(
-        0, hq, lambda h, m: jnp.maximum(m, meta_ref[3 * (rows + h)]),
-        jnp.int32(0))
+    def lengths(h, carry):  # the longest prefix (and most live q rows)
+        r = 3 * (rows + h)
+        kv_len = jnp.maximum(carry[0], meta_ref[r])
+        if not narrow_group:
+            return (kv_len,)
+        return kv_len, jnp.maximum(
+            carry[1], jnp.clip(meta_ref[r + 2] - i * bq, 0, bq))
+    seq_len, *live = jax.lax.fori_loop(
+        0, hq, lengths, (jnp.int32(0),) * (2 if narrow_group else 1))
 
-    def kv_head(kv):
+    def kv_head(kv, q_tile, tile):
         h0 = kv * kv_rep
         heads = [_row_scalars(lmult_ref, omult_ref, meta_ref, rows + h0 + r)
                  for r in range(kv_rep)]
-        masks = [tile_mask(i, j, bq, bkv, causal, window, kv_len, q_off,
-                           q_len) for _, _, kv_len, q_off, q_len in heads]
+        # the tile's rows, counted in tiles of its own width
+        masks = [tile_mask(i * (bq // tile), j, tile, bkv, causal, window,
+                           kv_len, q_off, q_len)
+                 for _, _, kv_len, q_off, q_len in heads]
         if kv_rep == 1:
             lmult, valid = heads[0][0], masks[0]
         else:        # one logit multiplier per row: each head's own
-            lmult = jnp.concatenate([jnp.full((bq, 1), m) for m, *_ in heads])
+            lmult = jnp.concatenate([jnp.full((tile, 1), m)
+                                     for m, *_ in heads])
             valid = jnp.concatenate(masks)
-        q = q_ref[pl.ds(h0, kv_rep)].reshape(kv_rep * bq, d)
+        q = q_tile[pl.ds(h0, kv_rep)].reshape(kv_rep * tile, d)
+        at = kv if tile == bq else (kv, pl.ds(0, kv_rep * tile))
         _stream_tile(q, k_ref[0, 0, kv], v_ref[0, 0, kv], lmult, b_ref,
-                     sigma_ref, acc_ref, valid, kv)
+                     sigma_ref, acc_ref, valid, at)
 
-    @pl.when(j * bkv < seq_len)
-    def _page():
+    def stream(q_tile, tile, n):
         def kv_heads(t, carry):
-            for u in range(group):
-                kv_head(t * group + u)
+            for u in range(n):
+                kv_head(t * n + u, q_tile, tile)
             return carry
-        jax.lax.fori_loop(0, hq // kv_rep // group, kv_heads, 0)
+        jax.lax.fori_loop(0, hq // kv_rep // n, kv_heads, 0)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
+    def finalize(tile):
         def head(h, carry):
-            at = (h // kv_rep, pl.ds(pl.multiple_of(h % kv_rep * bq, bq), bq))
+            at = (h // kv_rep,
+                  pl.ds(pl.multiple_of(h % kv_rep * tile, tile), tile))
             _finalize_onepass(o_ref, sigma_ref, acc_ref, omult_ref[rows + h],
-                              adaptive, float_out, at, h)
+                              adaptive, float_out, at, h, bq - tile)
             return carry
         jax.lax.fori_loop(0, hq, head, 0)
+
+    page = j * bkv < seq_len
+    if not narrow_group:
+        pl.when(page)(lambda: stream(q_ref, bq, group))
+        pl.when(j == pl.num_programs(2) - 1)(lambda: finalize(bq))
+        return
+    live, = live
+    wide, last = live > NARROW, j == pl.num_programs(2) - 1
+    pl.when(page & wide)(lambda: stream(q_ref, bq, group))
+    pl.when(page & (live > 0) & ~wide)(
+        lambda: stream(q8_ref, NARROW, narrow_group))
+    pl.when(last & wide)(lambda: finalize(bq))
+    pl.when(last & ~wide)(lambda: finalize(NARROW))
 
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -588,14 +638,17 @@ def _swallow_pt(kern):
     return wrapped
 
 
-def _live_page_table(page_table, kv_len, hq, page_size):
+def _live_page_table(page_table, kv_len, hq, page_size, q_len=None):
     """The page table with every entry past a sequence's last live page
     (the most of its heads' ``kv_len``) replaced by that page: the kernel
     skips those tiles' work, and a block index that repeats the previous
     step's costs no DMA, so a call reads each sequence's live pages and
-    nothing past them."""
+    nothing past them. With ``q_len``, a sequence none of whose heads
+    carries a query has no live page."""
     b, n_pages = page_table.shape
     live = kv_len.reshape(b, hq).max(1)
+    if q_len is not None:
+        live = jnp.where(q_len.reshape(b, hq).max(1) > 0, live, 0)
     last = jnp.clip(-(-live // page_size) - 1, 0, n_pages - 1)
     tiles = jnp.minimum(jnp.arange(n_pages, dtype=jnp.int32)[None, :],
                         last[:, None])
@@ -614,31 +667,41 @@ def _paged_heads_call(name, q_q, k_pool, v_pool, page_table, logit_mult,
     scalar-prefetch operands), after the table is cut at each sequence's
     last live page. The q and output blocks are the sequence's ``hq``
     rows of a q tile; the (max bucket, Σ, accumulator) scratch has a slot
-    of ``kv_rep * bq`` rows per kv head."""
+    of ``kv_rep * bq`` rows per kv head. A tile wider than ``NARROW``
+    rows (a multiple of it) also reads the q operand as a narrow block,
+    each head's first ``NARROW`` rows of the tile, and the table cut
+    drops the sequences that carry no query."""
     bh, sq, d = q_q.shape
     page, g = k_pool.shape[3], k_pool.shape[2]
     b, n_pages = page_table.shape
     assert sq % bq == 0, (sq, bq)
     assert b * hq == bh and hq == g * kv_rep, \
         (bh, hq, g, kv_rep, page_table.shape)
-    kern = functools.partial(paged_heads_kernel, causal=causal,
-                             window=window, adaptive=adaptive, hq=hq,
-                             kv_rep=kv_rep, group=_head_group(g, kv_rep * bq),
-                             bq=bq, bkv=page, q_tiles=sq // bq,
-                             float_out=float_out)
+    narrow = bq > NARROW and bq % NARROW == 0
+    kern = functools.partial(
+        paged_heads_kernel, causal=causal, window=window, adaptive=adaptive,
+        hq=hq, kv_rep=kv_rep, group=_head_group(g, kv_rep * bq),
+        narrow_group=_head_group(g, kv_rep * NARROW) if narrow else 0,
+        bq=bq, bkv=page, q_tiles=sq // bq, float_out=float_out)
     lmult, omult = _row_mults(logit_mult, out_mult, bh)
-    meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len, bh)
-    page_table = _live_page_table(page_table, meta.reshape(bh, 3)[:, 0], hq,
-                                  page)
+    meta = _row_meta(kv_len, q_offset, sq if q_len is None else q_len,
+                     bh).reshape(bh, 3)
+    page_table = _live_page_table(page_table, meta[:, 0], hq, page,
+                                  meta[:, 2] if narrow else None)
 
     def kv_block(s, i, j, pt, lyr):
         return (lyr[0], pt[s * n_pages + j], 0, 0, 0)
     q_spec = pl.BlockSpec((hq, bq, d), lambda s, i, j, *_: (s, i, 0))
     kv_spec = pl.BlockSpec((1, 1, g, page, d), kv_block)
+    q_specs, q_args = [q_spec], [q_q]
+    if narrow:
+        q_specs.append(pl.BlockSpec(
+            (hq, NARROW, d), lambda s, i, j, *_: (s, i * (bq // NARROW), 0)))
+        q_args.append(q_q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, sq // bq, n_pages),
-        in_specs=[q_spec, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
+        in_specs=[*q_specs, kv_spec, kv_spec, _SMEM, _SMEM, _SMEM],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((g, kv_rep * bq, 1), jnp.int32),
                         pltpu.VMEM((g, kv_rep * bq, 1), jnp.int32),
@@ -649,8 +712,8 @@ def _paged_heads_call(name, q_q, k_pool, v_pool, page_table, logit_mult,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), _out_dtype(float_out)),
         interpret=interpret,
         name=name,
-    )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1), q_q,
-      k_pool, v_pool, lmult, omult, meta)
+    )(page_table.reshape(-1), jnp.asarray(layer, jnp.int32).reshape(1),
+      *q_args, k_pool, v_pool, lmult, omult, meta.reshape(-1))
 
 
 def ita_attention_decode_paged(q_q, k_pool, v_pool, page_table, logit_mult,

@@ -688,8 +688,10 @@ def serve_continuous(params, cfg, requests, *, slots: int,
       ledger, also in ``ServeResult.pool_rounds``): the round's preempt,
       release, unpin, adopt and admit dispatches;
     - ``serve.dispatch`` (args ``segment``, ``step``, ``mixed``: the
-      segment's chunk-wide steps, ``steps``: its length): the segment
-      call;
+      segment's chunk-wide steps, ``steps``: its length; a mixed segment
+      also ``rows_narrow``: the slots decoding at its first step, whose
+      one query row streams the kernels' narrow tile, and ``rows_wide``:
+      the slots prefilling): the segment call;
     - ``serve.wait``: the host waiting for the device to finish it;
     - ``serve.readback``: the one ``device_get`` and the per-slot token
       bookkeeping;
@@ -1416,11 +1418,13 @@ def serve_continuous(params, cfg, requests, *, slots: int,
                 # rounded up to a power of two to bound compilation count
                 k = min(segment, _next_pow2(max(need, 1)))
                 fn = seg_mixed(k)
+                rows = dict(rows_narrow=n_dec, rows_wide=len(left))
             else:
-                k, fn = 0, seg_decode
+                k, fn, rows = 0, seg_decode, {}
             with jax.profiler.TraceAnnotation("serve.dispatch",
                                               segment=segments, step=step,
-                                              mixed=k, steps=segment):
+                                              mixed=k, steps=segment,
+                                              **rows):
                 toks, emits, _, state, caches, _ = fn(params, state, caches,
                                                       temp_arr)
             segments += 1

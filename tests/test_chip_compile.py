@@ -75,7 +75,14 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache, kind,
     compiled = jax.jit(call).lower(
         sds((SLOTS, hq, sq, d), jnp.int8), pool, pool,
         sds((SLOTS, PAGES_PER_SEQ), jnp.int32), rows, rows, rows).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    line, = [c for c in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in c]
+    # the ragged call's rows take the wide or the narrow branch by their
+    # q_lens, which the compile does not know: both lower, and the q
+    # operand is read twice, as the chunk-wide tile and the 8-row view
+    operands = line.split("operand_layout_constraints={", 1)[1]
+    q_in = f"s8[{SLOTS * hq},{max(sq, 8)},{d}]"
+    assert operands.count(q_in + "{") == (2 if ragged else 1), operands
     # the device layout pads head_dim to the 128-lane tile
     out_bytes = compiled.memory_analysis().output_size_in_bytes
     assert out_bytes == SLOTS * hq * sq * max(d, 128)

@@ -1305,3 +1305,68 @@ def test_serve_over_many_small_pages_matches_solo_generate():
         np.testing.assert_array_equal(np.asarray(c.tokens),
                                       np.asarray(solo.tokens)[0],
                                       err_msg=f"request {c.index}")
+
+
+@pytest.mark.parametrize("float_out", [False, True], ids=["int8", "f32"])
+@pytest.mark.parametrize("hq,g", [(4, 4), (4, 2)], ids=["mha", "gqa"])
+def test_onepass_paged_tile_width_follows_live_query_rows(hq, g, float_out):
+    """A q tile wider than 8 rows streams the 8-row tile when its
+    sequence carries 1 to 8 live query rows, the whole tile when more,
+    and nothing when none: sequences with ``q_len`` 0, 1, 7, 8, 9, a
+    tile, a tile and 4 (a second q tile of 4 live rows) and the whole
+    chunk in one call, at lengths past their queries, each equal the
+    one-shot aligned arithmetic bit for bit; pad rows and the rows of
+    the sequence with no query (its pages pointing at noise) emit 0."""
+    from repro.core.quant import EPS_MAX
+    from repro.kernels.ita_attention.kernel import ita_attention_onepass_paged
+    from repro.kernels.ita_attention.ref import ita_attention_fused_ref
+
+    gen = np.random.default_rng(18)
+    i8 = lambda *s: gen.integers(-128, 128, s, dtype=np.int8)  # noqa: E731
+    d, page, npps, chunk, bq = 16, 16, 8, 32, 16
+    cap = page * npps
+    seq_q = np.array([0, 1, 7, 8, 9, bq, bq + 4, chunk], np.int32)
+    seq_kv = np.array([50, 1, 40, 77, 100, 16, 128, 90], np.int32)
+    b = len(seq_q)
+    k_log, v_log = i8(b, cap, g, d), i8(b, cap, g, d)
+    k_pool, v_pool, pt = _paged_from_logical(k_log, v_log, page)
+    noise = k_pool.shape[0]
+    k_pool = np.concatenate([k_pool, i8(1, g, page, d)])
+    v_pool = np.concatenate([v_pool, i8(1, g, page, d)])
+    pt[0] = noise                                # the row with no query
+    rows, q_lens = np.repeat(seq_kv, hq), np.repeat(seq_q, hq)
+    offs = rows - q_lens
+    q = i8(b * hq, chunk, d)
+    lmult = np.float32(S_Q * S_Q / (np.sqrt(d) * EPS_MAX))
+    omult = np.float32(S_Q / S_OUT)
+    out = np.asarray(ita_attention_onepass_paged(
+        jnp.asarray(q), jnp.asarray(k_pool)[None], jnp.asarray(v_pool)[None],
+        jnp.asarray(pt), lmult, omult, jnp.asarray(rows),
+        q_offset=jnp.asarray(offs), q_len=jnp.asarray(q_lens), causal=True,
+        block_q=bq, kv_rep=hq // g, hq=hq, float_out=float_out))
+    kr = np.repeat(k_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    vr = np.repeat(v_log.transpose(0, 2, 1, 3), hq // g, axis=1)
+    ref = np.asarray(ita_attention_fused_ref(
+        jnp.asarray(q), jnp.asarray(kr.reshape(b * hq, cap, d)),
+        jnp.asarray(vr.reshape(b * hq, cap, d)), lmult, omult,
+        jnp.asarray(rows), causal=True, q_offset=jnp.asarray(offs),
+        q_len=jnp.asarray(q_lens), float_out=float_out))
+    assert out.dtype == (np.float32 if float_out else np.int8)
+    np.testing.assert_array_equal(out, ref)
+    for r in range(b * hq):
+        assert out[r, :q_lens[r]].any() or not q_lens[r], r
+        assert not out[r, q_lens[r]:].any(), r
+
+
+def test_live_page_table_drops_sequences_with_no_query():
+    """With the heads' query counts, a sequence none of whose heads
+    carries a query keeps only its first entry: its pages cost no DMA."""
+    from repro.kernels.ita_attention.kernel import _live_page_table
+
+    pt = np.arange(1, 1 + 3 * 6, dtype=np.int32).reshape(3, 6)
+    kv_len = jnp.asarray([33, 64, 90, 90, 1, 96], jnp.int32)
+    q_len = jnp.asarray([1, 0, 0, 0, 0, 32], jnp.int32)
+    got = np.asarray(_live_page_table(jnp.asarray(pt), kv_len, 2, 16, q_len))
+    for b, last in enumerate([3, 0, 5]):
+        want = pt[b, np.minimum(np.arange(6), last)]
+        np.testing.assert_array_equal(got[b], want, err_msg=f"sequence {b}")

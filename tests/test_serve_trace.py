@@ -65,9 +65,11 @@ def _rounds(spans):
                  and r[1] <= s[1] and s[2] <= r[2]]) for r in rounds]
 
 
-def _recording_builder(ks):
+def _recording_builder(ks, phases=None):
     """Wraps the segment builder so that each dispatched segment appends
-    the ``k`` (mixed steps) the builder gave it."""
+    the ``k`` (mixed steps) the builder gave it, and to ``phases`` its
+    slots decoding and prefilling, counted from the slot state it is
+    handed."""
     orig = gen._serve_segment_fn
 
     def builder(cfg, segment, sample, eos_id, pad_id, chunk=None,
@@ -77,9 +79,14 @@ def _recording_builder(ks):
         k = 0 if chunk is None else \
             (segment if mixed_steps is None else min(mixed_steps, segment))
 
-        def call(*args, **kw):
+        def call(params, state, *args, **kw):
             ks.append(k)
-            return fn(*args, **kw)
+            if phases is not None:
+                live = ~np.asarray(state.done)
+                pre = live & (np.asarray(state.cursor)
+                              < np.asarray(state.plen))
+                phases.append((int((live & ~pre).sum()), int(pre.sum())))
+            return fn(params, state, *args, **kw)
         return call
     return builder
 
@@ -89,13 +96,13 @@ def served(tmp_path_factory):
     params = init_model(jax.random.PRNGKey(0), CFG)
     reqs = _requests()
     plain = serve_continuous(params, CFG, reqs, **SERVE)
-    ks = []
+    ks, phases = [], []
     trace_dir = tmp_path_factory.mktemp("trace")
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(gen, "_serve_segment_fn", _recording_builder(ks))
+        mp.setattr(gen, "_serve_segment_fn", _recording_builder(ks, phases))
         with jax.profiler.trace(str(trace_dir)):
             traced = serve_continuous(params, CFG, reqs, **SERVE)
-    return plain, traced, _serve_spans(trace_dir), ks
+    return plain, traced, _serve_spans(trace_dir), (ks, phases)
 
 
 def test_one_round_per_segment_with_its_children_in_order(served):
@@ -110,7 +117,7 @@ def test_one_round_per_segment_with_its_children_in_order(served):
 
 
 def test_dispatch_args_count_the_segments_steps(served):
-    _, res, spans, ks = served
+    _, res, spans, (ks, _) = served
     dispatch = [s[3] for s in spans if s[0] == "serve.dispatch"]
     assert sum(a["steps"] for a in dispatch) == res.steps
     assert [a["mixed"] for a in dispatch] == ks
@@ -120,6 +127,23 @@ def test_dispatch_args_count_the_segments_steps(served):
     rounds = [s[3] for s in spans if s[0] == "serve.round"]
     assert [(a["segment"], a["step"]) for a in rounds] \
         == [(a["segment"], a["step"]) for a in dispatch]
+
+
+def test_mixed_dispatch_args_count_decoding_and_prefilling_slots(served):
+    """A mixed segment's ``serve.dispatch`` carries ``rows_narrow`` and
+    ``rows_wide``: the slots decoding and prefilling as it starts; a
+    decode-only segment carries neither."""
+    _, _, spans, (ks, phases) = served
+    dispatch = [s[3] for s in spans if s[0] == "serve.dispatch"]
+    assert len(dispatch) == len(phases) and 0 in ks and any(ks)
+    for args, k, (dec, pre) in zip(dispatch, ks, phases):
+        if k == 0:
+            assert "rows_narrow" not in args and "rows_wide" not in args
+            continue
+        assert (args["rows_narrow"], args["rows_wide"]) == (dec, pre)
+        assert args["rows_wide"] > 0
+        assert args["rows_narrow"] + args["rows_wide"] <= SERVE["slots"]
+    assert any(a.get("rows_narrow") for a in dispatch)
 
 
 def test_tracing_serves_the_same_tokens(served):
